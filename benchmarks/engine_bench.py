@@ -103,17 +103,12 @@ def main(emit, batch: int = 32, timesteps: int = 20, sweep: bool = True) -> dict
     counts_r, reports_r = ref.run_batch(trains)
     reference_s = time.perf_counter() - t0
 
-    if jax.default_backend() == "cpu":
-        # on CPU the engines share XLA's reduction order -> bit-identical
-        assert np.array_equal(np.asarray(counts_c), np.asarray(counts_r)), \
-            "compiled/reference spike mismatch"
-        assert np.array_equal(np.asarray(counts_f), np.asarray(counts_r)), \
-            "fused/reference spike mismatch"
-    else:          # accelerator matmul accumulation order may differ by ulps
-        np.testing.assert_allclose(np.asarray(counts_c), np.asarray(counts_r),
-                                   atol=1)
-        np.testing.assert_allclose(np.asarray(counts_f), np.asarray(counts_r),
-                                   atol=1)
+    # spikes must agree exactly on every backend; the current matmuls run
+    # at zspe.CURRENT_PRECISION, so a TPU keeps the f32 weights too
+    assert np.array_equal(np.asarray(counts_c), np.asarray(counts_r)), \
+        "compiled/reference spike mismatch"
+    assert np.array_equal(np.asarray(counts_f), np.asarray(counts_r)), \
+        "fused/reference spike mismatch"
 
     fe = fused.fused_engine()
     # HBM accounting at the canonical batch (32) so the trajectory metric
@@ -170,8 +165,7 @@ def main(emit, batch: int = 32, timesteps: int = 20, sweep: bool = True) -> dict
             ct, cc, _ = _time_batch(comp, tr, reps=3)
             ft_, cf, frep = _time_batch(fused, tr, reps=3)
             cs, fs = ct.median_s, ft_.median_s
-            assert np.array_equal(np.asarray(cc), np.asarray(cf)) or \
-                jax.default_backend() != "cpu"
+            assert np.array_equal(np.asarray(cc), np.asarray(cf))
             rows.append({
                 "batch": b, "timesteps": t, "sparsity": round(1 - dens, 3),
                 "compiled_s": round(cs, 4), "fused_s": round(fs, 4),

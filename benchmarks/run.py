@@ -180,6 +180,9 @@ def main(argv=None) -> None:
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, os.path.join(root, "src"))
     sys.path.insert(0, root)                    # `python benchmarks/run.py`
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (compiler_bench, contention_bench, deploy_bench,
                             engine_bench, fault_bench, fig3_core_efficiency,
                             fig5_noc, fig6_riscv_power, fleet_bench,

@@ -41,9 +41,13 @@ the XLA program (`donate_argnums`), so v/elapsed are updated in place.
 
 The bit-identical-spikes contract is validated on the CPU backend,
 where XLA's reduction order for the (B, n) @ (n, m) batched matmul
-matches the reference's per-sample product.  On GPU/TPU backends the
-accumulation order may differ, so currents can differ by ~1 ulp and
-a threshold tie could flip a spike — compare with a tolerance there.
+matches the reference's per-sample product.  Every current matmul runs
+at `zspe.CURRENT_PRECISION` (HIGHEST), so a TPU keeps the f32 codebook
+weights instead of rounding them to bf16.  Its MXU still sums in its own
+order and its `leak ** n` differs in the last bit, so TPU currents and
+decays can differ from the CPU's by a few ulps: spikes agree unless a
+membrane value lands within that distance of the threshold.
+`chip_smoke.py` holds the TPU to exact agreement on its workload.
 
 Differential testing lives in tests/test_engine_equiv.py (both engines
 vs the reference, fused vs compiled bit-exact, skip counters vs a numpy
@@ -244,28 +248,43 @@ def lower_plasticity_tables(sim: "ChipSimulator"):
     return tuple(out)
 
 
-def _pick_engine_block(m: int, k: int, n: int,
-                       interpret: bool) -> tuple[int, int] | None:
-    """Kernel tile for one engine layer-step.
+def _pick_engine_block(m: int, k: int, n: int, interpret: bool, *,
+                       codebook: bool = True, n_levels: int = 16,
+                       all_nonzero: bool = False) -> tuple[int, int] | None:
+    """Kernel tile for one engine layer-step ((m, k) spikes, (k, n) weights).
 
     Interpret mode runs one exact (m, n) tile — that is what makes the
-    fused path bit-exact against the compiled engine.  Compiled (real
-    TPU) mode must respect VMEM: cap the in-flight dequantized weight
-    slab at ~4 MB (k * bn f32) and the batch rows at 8, choosing the
-    largest *divisors* so no padding plumbing is needed in the scan.
+    fused path bit-exact against the compiled engine.  A compiled (TPU)
+    tile must be one Mosaic accepts and must fit VMEM:
+
+    * `bm` divides m and is m itself or a multiple of 16 (the uint16
+      spike-word block packs two rows per sublane), at most 128 rows
+      unless no such divisor exists;
+    * `bn` divides n and is n itself or a multiple of 128 lanes;
+    * `fused_timestep.vmem_bytes` — every operand and temporary of the
+      kernel — stays within `VMEM_BUDGET_BYTES`.
+
+    The largest `bm` comes first, so the weight slab is fetched once per
+    row tile, then the widest `bn` that fits.
     """
     if interpret:
         return None
+    from repro.kernels import fused_timestep as F
 
-    def largest_divisor(d: int, cap: int) -> int:
-        for c in range(min(d, max(cap, 1)), 0, -1):
-            if d % c == 0:
-                return c
-        return 1
-
-    bm = largest_divisor(m, 8)
-    bn = largest_divisor(n, max(1, (1 << 20) // max(k, 1)))
-    return (bm, bn)
+    kw = Z.spike_word_count(k)
+    bms = [d for d in range(min(m, 128), 0, -1)
+           if m % d == 0 and (d == m or d % 16 == 0)] or [m]
+    bns = [d for d in range(n, 0, -1)
+           if n % d == 0 and (d == n or d % 128 == 0)]
+    for bm in bms:
+        for bn in bns:
+            if F.vmem_bytes(bm, bn, kw, codebook=codebook, n_levels=n_levels,
+                            all_nonzero=all_nonzero) <= F.VMEM_BUDGET_BYTES:
+                return bm, bn
+    raise ValueError(
+        f"no fused-kernel tile of a ({m}, {k}) x ({k}, {n}) layer-step "
+        f"fits the {F.VMEM_BUDGET_BYTES >> 20} MiB VMEM budget — use "
+        f"engine='compiled'")
 
 
 def lower_fused_weights(sim: "ChipSimulator") -> tuple[FusedLayerWeights, ...]:
@@ -292,6 +311,14 @@ def lower_fused_weights(sim: "ChipSimulator") -> tuple[FusedLayerWeights, ...]:
                 idx=None, cbw=None, dense=jnp.asarray(dense),
                 all_nonzero=nz))
     return tuple(out)
+
+
+def _shard_map(fn, mesh, in_specs, out_specs):
+    """`jax.shard_map` as every engine mesh uses it.  The varying-axes
+    check is off: bodies close over replicated tables and return
+    per-shard counters the check cannot type."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -337,16 +364,11 @@ class _EngineBase:
     def _shard_wrap(self, fn, n_args: int = 1):
         """Wrap a batched-run function in a shard_map over the batch axis
         (weights/tables are closure constants -> replicated)."""
-        try:                         # jax >= 0.4.35 promotes it to core
-            from jax import shard_map
-        except ImportError:          # older releases: experimental module
-            from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()), ("batch",))
         spec = P("batch")
-        return shard_map(fn, mesh=mesh, in_specs=(spec,) * n_args,
-                         out_specs=spec, check_rep=False)
+        return _shard_map(fn, mesh, (spec,) * n_args, spec)
 
     # -- plasticity state plumbing ------------------------------------------
 
@@ -596,7 +618,7 @@ class CompiledEngine(_EngineBase):
                     # empty-word counter, so the two agree bit-for-bit
                     skips.append(Z.empty_spike_words(
                         Z.pack_spike_words(spikes)).astype(jnp.float32))
-                current = spikes @ w
+                current = jnp.matmul(spikes, w, precision=Z.CURRENT_PRECISION)
                 st, out, touched = lif_step(
                     states[li], current, lif,
                     touched=touch_mask(spikes, nonzero_w[li]))
@@ -685,7 +707,7 @@ class CompiledEngine(_EngineBase):
                 if trace_skips:
                     skips.append(Z.empty_spike_words(
                         Z.pack_spike_words(spikes)).astype(jnp.float32))
-                current = spikes @ w
+                current = jnp.matmul(spikes, w, precision=Z.CURRENT_PRECISION)
                 st, out, touched = lif_step(
                     states[li], current, lif,
                     touched=touch_mask(spikes, nzw))
@@ -961,7 +983,8 @@ class ShardedEngine(_EngineBase):
                         skips.append(Z.empty_spike_words(
                             Z.pack_spike_words(spikes))
                             .astype(jnp.float32))
-                    current = spikes @ w_l[li]          # (width,) local
+                    current = jnp.matmul(             # (width,) local
+                        spikes, w_l[li], precision=Z.CURRENT_PRECISION)
                     st, out_l, touched_l = lif_step(
                         states[li], current, lif,
                         touched=touch_mask(spikes, nzw_l[li]))
@@ -1070,7 +1093,8 @@ class ShardedEngine(_EngineBase):
                         skips.append(Z.empty_spike_words(
                             Z.pack_spike_words(spikes))
                             .astype(jnp.float32))
-                    current = spikes @ w            # (width,) local
+                    current = jnp.matmul(           # (width,) local
+                        spikes, w, precision=Z.CURRENT_PRECISION)
                     st, out_l, touched_l = lif_step(
                         states[li], current, lif,
                         touched=touch_mask(spikes, nzw))
@@ -1181,10 +1205,6 @@ class ShardedEngine(_EngineBase):
         return body_plast
 
     def _make_executable(self, nb: int):
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         S = self.n_shards
@@ -1195,19 +1215,16 @@ class ShardedEngine(_EngineBase):
             stacks.extend((sl.w, sl.nzw, sl.onehot))
         body = self._build_body()
         if not self.plast.enabled:
-            fn = shard_map(
-                body, mesh=mesh,
-                in_specs=(P("batch"),) + (P("cores"),) * len(stacks),
-                out_specs=P("batch"), check_rep=False)
+            fn = _shard_map(
+                body, mesh, (P("batch"),) + (P("cores"),) * len(stacks),
+                P("batch"))
             jfn = jax.jit(fn)
             return lambda trains: jfn(trains, *stacks)
         plast_stacks = [ps[0] for ps in self._plast_shards
                         if ps is not None]
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(P("batch"), P("cores", "batch"))
-            + (P("cores"),) * (len(stacks) + len(plast_stacks)),
-            out_specs=P("batch"), check_rep=False)
+        fn = _shard_map(
+            body, mesh, (P("batch"), P("cores", "batch"))
+            + (P("cores"),) * (len(stacks) + len(plast_stacks)), P("batch"))
         jfn = jax.jit(fn)
         return lambda trains, idx0: jfn(
             trains, self._shard_learned(idx0), *stacks, *plast_stacks)
@@ -1296,9 +1313,11 @@ class FusedEngine(_EngineBase):
 
         def layer_apply(li, packed, state):
             lw = fused_w[li]
-            block = _pick_engine_block(int(packed.shape[0]),
-                                       lw.kw * Z.SPIKE_WORD_BITS,
-                                       lw.n_post, interp)
+            block = _pick_engine_block(
+                int(packed.shape[0]), lw.kw * Z.SPIKE_WORD_BITS, lw.n_post,
+                interp, codebook=lw.codebook_mode,
+                n_levels=int(lw.cbw.shape[0]) if lw.codebook_mode else 0,
+                all_nonzero=lw.all_nonzero)
             if lw.codebook_mode:
                 return fused_timestep_codebook(
                     packed, lw.idx, lw.cbw, state.v, state.elapsed,
@@ -1417,7 +1436,8 @@ class FusedEngine(_EngineBase):
                 else:
                     s = Z.unpack_spike_words(packed)           # (B, kp)
                     w = PLC.dequant_indices(pidx[li], cbws[li])
-                    current = jnp.einsum("bk,bkn->bn", s, w)
+                    current = jnp.einsum("bk,bkn->bn", s, w,
+                                         precision=Z.CURRENT_PRECISION)
                     nzw = (w != 0).astype(jnp.float32)
                     tm = jnp.einsum("bk,bkn->bn", s, nzw) > 0
                     st, out, tc = lif_step(states[li], current, lif,

@@ -22,6 +22,7 @@ import numpy as np
 from repro.core import energy as E
 from repro.core import noc as NOC
 from repro.core.quant import CodebookConfig
+from repro.core import zspe as Z
 from repro.core.zspe import CoreGeometry, CycleModel
 
 
@@ -801,10 +802,9 @@ class ChipSimulator:
                 if traced:
                     rec_nnz[-1].append(nnz)
                     if trace_skips:
-                        from repro.core import zspe as Z
                         rec_skip[-1].append(float(Z.empty_spike_words(
                             Z.pack_spike_words(spikes))))
-                current = spikes @ w
+                current = jnp.matmul(spikes, w, precision=Z.CURRENT_PRECISION)
                 st, out, touched = lif_step(
                     states[li], current, self.lif,
                     touched=touch_mask(spikes, nzw))

@@ -26,6 +26,13 @@ import jax.numpy as jnp
 from repro.core.quant import QuantizedTensor, dequantize
 
 
+# Precision of every synaptic-current matmul (`spikes @ w`) in the engines,
+# the reference loop and the fused kernel.  A TPU's default f32 matmul
+# rounds its operands to bf16; HIGHEST keeps the f32 codebook weights, so
+# the simulated chip integrates the same currents as on the CPU.
+CURRENT_PRECISION = jax.lax.Precision.HIGHEST
+
+
 def zspe_matmul(spikes: jax.Array, weights: jax.Array) -> jax.Array:
     """Spike-driven synaptic integration: (B, n_pre) {0,1} x (n_pre, n_post).
 

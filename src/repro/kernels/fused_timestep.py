@@ -11,7 +11,8 @@ Stage map (chip -> kernel):
   ping-pong cache   spikes arrive **bitpacked**: uint16 words of 16
                     spikes each (`core.zspe.pack_spike_words`), 32x fewer
                     HBM bytes than f32 lanes.  The kernel unpacks a
-                    (bm, Kw) word tile in-register (VPU shifts).
+                    (bm, Kw) word tile in VMEM (an exact 0/1 expand
+                    matmul, then lane shifts).
   ZSPE word scan    the word tile is popcounted; an all-empty spike tile
                     takes the `pl.when` skip branch — no dequant, no MXU
                     work, just the partial-update bookkeeping (elapsed+1).
@@ -48,20 +49,84 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# the chip's spike-word width — single source of truth with the packing
-# side (core.zspe has no kernels dependency, so no import cycle)
-from repro.core.zspe import SPIKE_WORD_BITS
+# the chip's spike-word width and the synaptic-current matmul precision —
+# single source of truth with the packing side and the array engines
+# (core.zspe has no kernels dependency, so no import cycle)
+from repro.core.zspe import CURRENT_PRECISION, SPIKE_WORD_BITS
+
+_WORD_SHIFT = SPIKE_WORD_BITS.bit_length() - 1       # lane k -> word k >> 4
+_CHUNK_WORDS = 128                 # words per unpack matmul (2048 lanes)
+
+# scoped VMEM the compiled kernel may use (a v5e core has 128 MiB); the
+# engine's tile picker keeps `vmem_bytes` under VMEM_BUDGET_BYTES
+VMEM_LIMIT_BYTES = 64 << 20
+VMEM_BUDGET_BYTES = 48 << 20
 
 
 def _unpack_words(pk: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """(bm, kw) uint16 -> ((bm, kw*16) f32 {0,1}, (bm,) int32 popcounts)."""
+    """(bm, kw) uint16 -> ((bm, kw*16) f32 {0,1}, (bm, 1) int32 popcounts).
+
+    2-D and 32-bit throughout, because Mosaic has neither a uint16 -> f32
+    cast nor a reshape that splits the lane axis.  The words widen to
+    int32 and split into bytes; per run of up to `_CHUNK_WORDS` words an
+    exact 0/1 expand matmul copies word k >> 4 into lane k (bytes and 0/1
+    are exact in bf16, and each lane sums exactly one nonzero term); a
+    lane-iota shift then selects bit k & 15.
+    """
     bm, kw = pk.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint16, (1, 1, SPIKE_WORD_BITS), 2)
-    bits = (pk[:, :, None] >> shifts) & jnp.uint16(1)
-    s = bits.reshape(bm, kw * SPIKE_WORD_BITS).astype(jnp.float32)
-    nnz = jnp.sum(bits.astype(jnp.int32), axis=(1, 2))
-    return s, nnz
+    words = pk.astype(jnp.int32)
+    lo = (words & 0xFF).astype(jnp.float32).astype(jnp.bfloat16)
+    hi = (words >> 8).astype(jnp.float32).astype(jnp.bfloat16)
+    cw = min(kw, _CHUNK_WORDS)
+    shape = (cw, cw * SPIKE_WORD_BITS)
+    expand = ((jax.lax.broadcasted_iota(jnp.int32, shape, 1) >> _WORD_SHIFT)
+              == jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+              ).astype(jnp.float32).astype(jnp.bfloat16)
+    pieces = []
+    for c0 in range(0, kw, cw):
+        n = min(cw, kw - c0)
+        e = expand[:n, :n * SPIKE_WORD_BITS]
+        pieces.append(
+            jnp.dot(lo[:, c0:c0 + n], e, preferred_element_type=jnp.float32)
+            + 256.0 * jnp.dot(hi[:, c0:c0 + n], e,
+                              preferred_element_type=jnp.float32))
+    lanes = (pieces[0] if len(pieces) == 1
+             else jnp.concatenate(pieces, axis=1)).astype(jnp.int32)
+    shift = jax.lax.broadcasted_iota(jnp.int32, lanes.shape, 1) & (
+        SPIKE_WORD_BITS - 1)
+    bits = (lanes >> shift) & 1
+    return bits.astype(jnp.float32), jnp.sum(bits, axis=1, keepdims=True)
+
+
+def vmem_bytes(bm: int, bn: int, kw: int, *, codebook: bool,
+               n_levels: int = 16, all_nonzero: bool = False) -> int:
+    """Conservative VMEM footprint of one grid step of the compiled kernel.
+
+    Counts every operand block double-buffered (spike words, the weight
+    slab, the level table, v/elapsed in; four state tiles and two row
+    counters out), each padded to its (sublane, 128-lane) tile, plus the
+    in-kernel temporaries: the unpack's expand matrix and its iotas, the
+    unpacked spike lanes, the dequantized f32 slab with its int32 indexes and
+    select temporary, the nonzero mask unless `all_nonzero`, and the LIF
+    tile arithmetic.
+    """
+    k = kw * SPIKE_WORD_BITS
+
+    def tile(rows, cols, itemsize):
+        sub = 8 * (4 // itemsize)
+        return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
+
+    blocks = (tile(bm, kw, 2) + tile(k, bn, 1 if codebook else 4)
+              + (tile(n_levels, bn, 4) if codebook else 0)
+              + 6 * tile(bm, bn, 4) + 2 * tile(bm, 1, 4))
+    cw = min(kw, _CHUNK_WORDS)
+    temps = (3 * tile(cw, cw * SPIKE_WORD_BITS, 4) + 6 * tile(bm, k, 4)
+             + (4 if codebook else 1) * tile(k, bn, 4)
+             + (0 if all_nonzero else tile(k, bn, 4))
+             + 8 * tile(bm, bn, 4))
+    return 2 * blocks + temps
 
 
 def _dequant_columns(idx: jax.Array, cbw: jax.Array,
@@ -78,7 +143,7 @@ def _dequant_columns(idx: jax.Array, cbw: jax.Array,
         return cbw.reshape(-1)[idx * bn + cols]
     w = jnp.zeros(idx.shape, jnp.float32)
     for l in range(cbw.shape[0]):
-        w = w + jnp.where(idx == l, cbw[l][None, :], 0.0)
+        w = w + jnp.where(idx == l, cbw[l:l + 1, :], 0.0)
     return w
 
 
@@ -114,13 +179,13 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
             reset: float, partial_update: bool, all_nonzero: bool):
     j = pl.program_id(1)
     pk = pk_ref[...]                                   # (bm, kw) uint16
-    s, nnz_rows = _unpack_words(pk)
+    s, nnz_rows = _unpack_words(pk)                    # (bm, K), (bm, 1)
 
     @pl.when(j == 0)
     def _spike_stats():                                # once per m-tile
-        nnz_ref[...] = nnz_rows[:, None]
-        ew_ref[...] = jnp.sum((pk == 0).astype(jnp.int32),
-                              axis=1)[:, None]
+        nnz_ref[...] = nnz_rows
+        ew_ref[...] = jnp.sum((pk.astype(jnp.int32) == 0).astype(jnp.int32),
+                              axis=1, keepdims=True)
 
     v = v_ref[...]
     el = el_ref[...]
@@ -148,18 +213,19 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
             w = _dequant_columns(idx, w1_ref[...], gather)
         else:
             w = w0_ref[...]                            # (K, bn) dense f32
-        cur = jnp.dot(s, w, preferred_element_type=jnp.float32)
+        cur = jnp.dot(s, w, precision=CURRENT_PRECISION,
+                      preferred_element_type=jnp.float32)
         # integer-exact touch counts: valid spikes through nonzero
         # synapses.  With a fully-nonzero weight slab (the static
         # `all_nonzero` flag, decided at lowering time) the nonzero mask
         # is all-ones and the count matmul collapses to the per-row
         # popcount — the identical integers, one MXU pass cheaper.
         if all_nonzero:
-            tcnt = jnp.broadcast_to(
-                nnz_rows[:, None].astype(jnp.float32), v.shape)
+            tcnt = jnp.broadcast_to(nnz_rows.astype(jnp.float32), v.shape)
         else:
             nz = (w != 0.0).astype(jnp.float32)
-            tcnt = jnp.dot(s, nz, preferred_element_type=jnp.float32)
+            tcnt = jnp.dot(s, nz, precision=CURRENT_PRECISION,
+                           preferred_element_type=jnp.float32)
         vo, elo, sp, tc = _lif_tile(
             v, el, cur, tcnt, threshold=threshold, leak=leak, reset=reset,
             partial_update=partial_update)
@@ -213,6 +279,8 @@ def _call(pk, w0, w1, v, elapsed, *, codebook, gather, threshold, leak,
         ],
         # membrane state is read-modify-write: donate the input buffers
         input_output_aliases={n_in - 2: 0, n_in - 1: 1},
+        compiler_params=(None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES)),
         interpret=interpret,
     )(*operands)
 

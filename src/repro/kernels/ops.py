@@ -1,13 +1,12 @@
 """Public jit'd entry points for the Pallas kernels.
 
-Handle padding to block multiples, interpret-mode selection (CPU container
-runs interpret=True; on a real TPU set REPRO_PALLAS_INTERPRET=0), and
-custom VJPs where the kernels appear in training graphs.
+Handle padding to block multiples, interpret-mode selection (interpret
+exactly when the backend is not a TPU), and custom VJPs where the kernels
+appear in training graphs.
 """
 from __future__ import annotations
 
 import functools
-import os
 from functools import partial
 
 import jax
@@ -22,24 +21,13 @@ from repro.kernels import ref as _ref
 
 @functools.lru_cache(maxsize=1)
 def interpret_default() -> bool:
-    """Whether Pallas kernels run in interpret mode by default.
-
-    Resolved ONCE per process (cached): the env var and backend cannot
-    change under a running program, and re-reading `os.environ` on every
-    kernel dispatch showed up in the fused-engine hot path.  Controlled by
-    ``REPRO_PALLAS_INTERPRET`` (documented in the README): unset -> True
-    unless the backend is a real TPU; "0"/"false" forces compiled Mosaic
-    kernels; anything else forces interpret mode.  Tests that mutate the
-    env must call ``interpret_default.cache_clear()``.
+    """Whether Pallas kernels run in interpret mode: exactly when the
+    backend is not a TPU, so a chip always runs the compiled Mosaic
+    kernels.  Resolved once per process (cached): the backend cannot
+    change under a running program, and asking on every kernel dispatch
+    showed up in the fused-engine hot path.
     """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
-
-
-# Backwards-compatible alias (pre-PR4 private name).
-_interpret_default = interpret_default
 
 
 def _pad_to(x: jax.Array, mults: tuple[int, ...], value=0) -> jax.Array:

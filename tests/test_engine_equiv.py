@@ -364,15 +364,23 @@ def test_fused_shard_map_multi_device():
 
 def test_fused_engine_block_selection():
     """Interpret mode runs one exact tile (the bit-exact config); the
-    real-TPU path tiles to divisors that cap the VMEM weight slab."""
+    real-TPU path picks tiles Mosaic accepts (bm = m or a multiple of 16
+    rows, bn = n or a multiple of 128 lanes) whose whole kernel footprint
+    fits the VMEM budget, and refuses a layer no tile fits."""
     from repro.core.engine import _pick_engine_block
+    from repro.kernels.fused_timestep import VMEM_BUDGET_BYTES, vmem_bytes
 
     assert _pick_engine_block(32, 2320, 512, interpret=True) is None
-    bm, bn = _pick_engine_block(32, 8192, 8192, interpret=False)
-    assert 32 % bm == 0 and 8192 % bn == 0
-    assert bm <= 8 and 8192 * bn <= 1 << 20        # <= 4 MB f32 slab
-    bm, bn = _pick_engine_block(3, 16, 509, interpret=False)   # prime N
-    assert bm in (1, 3) and 509 % bn == 0
+    for m, k, n in [(32, 8192, 8192), (12, 2320, 4096), (3, 16, 509),
+                    (8, 1024, 10), (256, 4096, 1024)]:
+        bm, bn = _pick_engine_block(m, k, n, interpret=False)
+        assert m % bm == 0 and (bm == m or bm % 16 == 0)
+        assert n % bn == 0 and (bn == n or bn % 128 == 0)
+        assert vmem_bytes(bm, bn, k // 16, codebook=True) <= VMEM_BUDGET_BYTES
+    assert _pick_engine_block(12, 2320, 4096, interpret=False)[0] == 12
+    assert _pick_engine_block(3, 16, 509, interpret=False) == (3, 509)
+    with pytest.raises(ValueError, match="VMEM"):
+        _pick_engine_block(8, 1 << 17, 128, interpret=False)
 
 
 def test_fused_rejects_soft_reset():

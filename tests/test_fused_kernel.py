@@ -259,7 +259,7 @@ def test_lif_update_padding_matches_ref():
 
 
 def test_interpret_default_cached():
-    """The env resolution is cached (one os.environ read per process)."""
+    """The backend resolution is cached (one lookup per process)."""
     from repro.kernels.ops import interpret_default
 
     assert interpret_default() is interpret_default()
