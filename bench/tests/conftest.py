@@ -1,0 +1,8 @@
+"""The benchmark's own tests run on the CPU: `pytest bench/tests`."""
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (ROOT / "src", ROOT):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
