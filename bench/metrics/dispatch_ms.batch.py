@@ -1,0 +1,9 @@
+"""dispatch_ms.batch: enqueue of the engine program (pack and scan), in ms
+per `snn.run_batch` call: the self time of the program's `snn.dispatch`
+spans in the traced window / the calls in it (`spans.per_call`)."""
+from bench import spans
+
+
+def read(run):
+    s = spans.per_call(run.trace, "snn.dispatch")
+    return None if s is None else 1e3 * s

@@ -1,0 +1,9 @@
+"""price_ms.batch: the host's energy pricing and ChipReports, in ms per
+`snn.run_batch` call: the self time of the program's `snn.price` spans
+in the traced window / the calls in it (`spans.per_call`)."""
+from bench import spans
+
+
+def read(run):
+    s = spans.per_call(run.trace, "snn.price")
+    return None if s is None else 1e3 * s

@@ -355,6 +355,7 @@ class _EngineBase:
                              else tuple(None for _ in sim.weights))
         self.last_learned = None     # per-layer learned indexes (B leading)
         self.last_elig = None        # per-layer eligibility (reward mode)
+        self.calls = 0               # run_batch calls so far (span `call`)
 
     # -- trace construction (subclass hooks) --------------------------------
 
@@ -424,24 +425,40 @@ class _EngineBase:
 
     # -- execution ----------------------------------------------------------
 
-    def run_raw(self, spike_trains: jax.Array, learned=None) -> dict:
-        """Run the XLA program; returns the per-step counter arrays."""
-        trains = jnp.asarray(spike_trains, jnp.float32)
+    def _upload(self, spike_trains) -> jax.Array:
+        """(B, T, n_in) spike trains as an f32 device array, under the
+        `snn.upload` span; its `bytes` stat counts what crosses from the
+        host (0 for an array already on the device)."""
+        moved = (0 if isinstance(spike_trains, jax.Array)
+                 else 4 * int(np.size(spike_trains)))
+        with jax.profiler.TraceAnnotation("snn.upload", bytes=moved):
+            trains = jnp.asarray(spike_trains, jnp.float32)
         if trains.ndim != 3:
             raise ValueError(f"expected (batch, T, n_in), got {trains.shape}")
+        return trains
+
+    def _dispatch(self, key, trains: jax.Array, learned) -> dict:
+        """Enqueue the executable `key` on `trains`, under the
+        `snn.dispatch` span (pack and scan; the device may still run)."""
+        with jax.profiler.TraceAnnotation("snn.dispatch"):
+            if not self.plast.enabled:
+                if learned is not None:
+                    raise ValueError("learned indexes passed but plasticity "
+                                     "is off")
+                return self._exec[key](trains)
+            return self._exec[key](
+                trains, self._initial_learned(int(trains.shape[0]), learned))
+
+    def run_raw(self, spike_trains: jax.Array, learned=None) -> dict:
+        """Run the XLA program; returns the per-step counter arrays."""
+        trains = self._upload(spike_trains)
         ndev = len(jax.devices())
         sharded = bool(self.shard and ndev > 1
                        and int(trains.shape[0]) % ndev == 0)
         if sharded not in self._exec:
             self._exec[sharded] = self._make_executable(sharded)
         self.last_run_sharded = sharded
-        if not self.plast.enabled:
-            if learned is not None:
-                raise ValueError("learned indexes passed but plasticity "
-                                 "is off")
-            return self._exec[sharded](trains)
-        return self._exec[sharded](
-            trains, self._initial_learned(int(trains.shape[0]), learned))
+        return self._dispatch(sharded, trains, learned)
 
     def run_batch(self, spike_trains: jax.Array, learned=None
                   ) -> tuple[jax.Array, list["ChipReport"]]:
@@ -453,7 +470,22 @@ class _EngineBase:
         per-flow replay (`noc.replay_flows_exact`) + the M/M/1 contention
         term (`noc.contention_cycles`) run the same f64 arithmetic the
         interpretive reference does, so the engines cannot drift from it.
+
+        Each phase runs under a `jax.profiler.TraceAnnotation` span, on
+        the profiler's clock beside the device ops: `snn.run_batch`
+        (stats `call`, `batch`, `steps`) holds `snn.upload` (`bytes`),
+        `snn.dispatch`, `snn.device_wait`, `snn.readback` (`transfers`,
+        `bytes`), `snn.noc_replay` (`flows`) and `snn.price`.  With no
+        profiler running a span costs about a microsecond.
         """
+        self.calls += 1
+        batch, steps = (tuple(np.shape(spike_trains)) + (0, 0))[:2]
+        with jax.profiler.TraceAnnotation(
+                "snn.run_batch", call=self.calls, batch=int(batch),
+                steps=int(steps)):
+            return self._run_batch(spike_trains, learned)
+
+    def _run_batch(self, spike_trains, learned):
         from repro.core.soc import ChipReport, StepStats
 
         sim = self.sim
@@ -464,8 +496,9 @@ class _EngineBase:
         sim._consume_transient_fault()
         B, T = int(spike_trains.shape[0]), int(spike_trains.shape[1])
         out_counts = jnp.sum(ys["out"], axis=1)
+        with jax.profiler.TraceAnnotation("snn.device_wait"):
+            jax.block_until_ready((ys, out_counts))
 
-        writes = None
         if self.plast.enabled:
             # learned state is stashed per engine (B leading, global
             # neuron layout) for warm-starting the next run / the reward
@@ -477,40 +510,54 @@ class _EngineBase:
                 self.last_elig = [
                     ys.pop(f"elig_{li}") if pt is not None else None
                     for li, pt in enumerate(self.plast_tables)]
-            writes = np.asarray(ys.pop("writes"), np.float64)  # (B, T, L)
+
+        # every counter the host prices, one array at a time
+        L = len(tbl.layers)
+        read = [k for k in ("writes", "nnz", "touched", "wall", "skip_words")
+                if k in ys]
+        read += [f"fired_core_{li}" for li, ft in enumerate(tbl.flows)
+                 if ft is not None or self.trace.enabled]
+        if self.trace.enabled:
+            read += [f"touched_core_{li}" for li in range(L)]
+        with jax.profiler.TraceAnnotation(
+                "snn.readback", transfers=len(read),
+                bytes=sum(int(ys[k].nbytes) for k in read)):
+            host = {k: np.asarray(ys[k], np.float64) for k in read}
+
+        writes = host.get("writes")                      # (B, T, L)
         writes_total = (writes.sum(axis=(1, 2)) if writes is not None
                         else np.zeros(B))
-
         n_posts = np.array([lt.n_post for lt in tbl.layers], np.float64)
-        nnz = np.asarray(ys["nnz"], np.float64)          # (B, T, L)
-        touched = np.asarray(ys["touched"], np.float64)
+        nnz = host["nnz"]                                # (B, T, L)
         spikes_in = nnz.sum(axis=(1, 2))
         performed = (nnz * n_posts).sum(axis=(1, 2))
-        neurons_touched = touched.sum(axis=(1, 2))
-        core_wall = np.asarray(ys["wall"], np.float64)   # (B, T) core-only
-        skipped_words = (np.asarray(ys["skip_words"], np.float64)
-                         .sum(axis=(1, 2)) if "skip_words" in ys
-                         else np.zeros(B))
+        neurons_touched = host["touched"].sum(axis=(1, 2))
+        core_wall = host["wall"]                         # (B, T) core-only
+        skipped_words = (host["skip_words"].sum(axis=(1, 2))
+                         if "skip_words" in host else np.zeros(B))
         nominal = float(tbl.nominal_sops_per_step) * T
 
         # exact per-flow NoC replay: counts are integers, pricing is f64
-        noc_hops = np.zeros(B)
-        noc_pj = np.zeros(B)
-        routed = np.zeros(B)
-        load = np.zeros((B, T, sim.adj.shape[0]))
-        for li, ft in enumerate(tbl.flows):
-            if ft is None:
-                continue
-            fired_core = np.asarray(ys[f"fired_core_{li}"], np.float64)
-            h, e, ld = NOC.replay_flows_exact(ft, fired_core)  # (B, T, ...)
-            noc_hops += h.sum(axis=1)
-            noc_pj += e.sum(axis=1)
-            load += ld
-            routed += fired_core.sum(axis=(1, 2))
-        contention = NOC.contention_cycles(
-            load.max(axis=2), core_wall, sim.router)     # (B, T)
-        wall = (core_wall + contention).sum(axis=1)
-        noc_contention = contention.sum(axis=1)
+        with jax.profiler.TraceAnnotation(
+                "snn.noc_replay",
+                flows=sum(ft.n_flows for ft in tbl.flows if ft is not None)):
+            noc_hops = np.zeros(B)
+            noc_pj = np.zeros(B)
+            routed = np.zeros(B)
+            load = np.zeros((B, T, sim.adj.shape[0]))
+            for li, ft in enumerate(tbl.flows):
+                if ft is None:
+                    continue
+                fired_core = host[f"fired_core_{li}"]
+                h, e, ld = NOC.replay_flows_exact(ft, fired_core)
+                noc_hops += h.sum(axis=1)
+                noc_pj += e.sum(axis=1)
+                load += ld
+                routed += fired_core.sum(axis=(1, 2))
+            contention = NOC.contention_cycles(
+                load.max(axis=2), core_wall, sim.router)  # (B, T)
+            wall = (core_wall + contention).sum(axis=1)
+            noc_contention = contention.sum(axis=1)
 
         if self.trace.enabled:
             # every derived series (cycles, router load, contention) is
@@ -518,50 +565,48 @@ class _EngineBase:
             # counters — one implementation for all three engines
             from repro.telemetry.trace import build_trace
 
-            L = len(tbl.layers)
             self.last_trace = build_trace(
                 sim,
-                np.concatenate([np.asarray(ys[f"fired_core_{li}"],
-                                           np.float64)
+                np.concatenate([host[f"fired_core_{li}"]
                                 for li in range(L)], axis=-1),
-                np.concatenate([np.asarray(ys[f"touched_core_{li}"],
-                                           np.float64)
+                np.concatenate([host[f"touched_core_{li}"]
                                 for li in range(L)], axis=-1),
                 nnz,
-                (np.asarray(ys["skip_words"], np.float64)
-                 if self.trace.skip_words and "skip_words" in ys else None),
+                host["skip_words"] if self.trace.skip_words
+                and "skip_words" in host else None,
                 weight_writes=writes)
 
-        priced = E.price_batched(
-            sim.core_model, sim.riscv,
-            nominal_sops=np.full(B, nominal), performed_sops=performed,
-            noc_energy_pj=noc_pj, wall_cycles=wall, steps=T,
-            freq_hz=sim.freq_hz, zero_skip=sim.zero_skip,
-            partial_update=sim.partial_update,
-            weight_writes=writes_total, write_model=sim.write_model)
+        with jax.profiler.TraceAnnotation("snn.price"):
+            priced = E.price_batched(
+                sim.core_model, sim.riscv,
+                nominal_sops=np.full(B, nominal), performed_sops=performed,
+                noc_energy_pj=noc_pj, wall_cycles=wall, steps=T,
+                freq_hz=sim.freq_hz, zero_skip=sim.zero_skip,
+                partial_update=sim.partial_update,
+                weight_writes=writes_total, write_model=sim.write_model)
 
-        reports = []
-        for b in range(B):
-            acc = StepStats(
-                nominal_sops=nominal,
-                performed_sops=float(performed[b]),
-                spikes_in=float(spikes_in[b]),
-                spikes_routed=float(routed[b]),
-                neurons_touched=float(neurons_touched[b]),
-                noc_hops=float(noc_hops[b]),
-                noc_energy_pj=float(noc_pj[b]),
-                noc_contention_cycles=float(noc_contention[b]),
-                spike_words_skipped=float(skipped_words[b]),
-                weight_writes=float(writes_total[b]),
-            )
-            reports.append(ChipReport(
-                steps=T, stats=acc,
-                energy_pj=float(priced["total_pj"][b]),
-                core_energy_pj=float(priced["core_pj"][b]),
-                noc_energy_pj=float(noc_pj[b]),
-                riscv_energy_pj=float(priced["riscv_pj"][b]),
-                wall_cycles=float(wall[b]), freq_hz=sim.freq_hz,
-                write_energy_pj=float(priced["write_pj"][b])))
+            reports = []
+            for b in range(B):
+                acc = StepStats(
+                    nominal_sops=nominal,
+                    performed_sops=float(performed[b]),
+                    spikes_in=float(spikes_in[b]),
+                    spikes_routed=float(routed[b]),
+                    neurons_touched=float(neurons_touched[b]),
+                    noc_hops=float(noc_hops[b]),
+                    noc_energy_pj=float(noc_pj[b]),
+                    noc_contention_cycles=float(noc_contention[b]),
+                    spike_words_skipped=float(skipped_words[b]),
+                    weight_writes=float(writes_total[b]),
+                )
+                reports.append(ChipReport(
+                    steps=T, stats=acc,
+                    energy_pj=float(priced["total_pj"][b]),
+                    core_energy_pj=float(priced["core_pj"][b]),
+                    noc_energy_pj=float(noc_pj[b]),
+                    riscv_energy_pj=float(priced["riscv_pj"][b]),
+                    wall_cycles=float(wall[b]), freq_hz=sim.freq_hz,
+                    write_energy_pj=float(priced["write_pj"][b])))
         return out_counts, reports
 
     def run(self, spike_train: jax.Array,
@@ -610,46 +655,48 @@ class CompiledEngine(_EngineBase):
             fired_cores = {}
             new_states = []
             for li, w in enumerate(weights):
-                lt, slices, core_idx, onehot = layer_consts[li]
-                nnz = jnp.sum(spikes != 0).astype(jnp.float32)
-                if trace_skips:
-                    # ZSPE skip telemetry on the layer's input spikes —
-                    # packs exactly like the fused engine's native
-                    # empty-word counter, so the two agree bit-for-bit
-                    skips.append(Z.empty_spike_words(
-                        Z.pack_spike_words(spikes)).astype(jnp.float32))
-                current = jnp.matmul(spikes, w, precision=Z.CURRENT_PRECISION)
-                st, out, touched = lif_step(
-                    states[li], current, lif,
-                    touched=touch_mask(spikes, nonzero_w[li]))
-                new_states.append(st)
-                tsum = jnp.sum(touched).astype(jnp.float32)
-                # integer-exact per-core-slice touched counts: the cycle
-                # model ceils them, and exact ints cannot straddle a ceil
-                # boundary between f32 (here) and f64 (reference)
-                core_touched = touched.astype(jnp.float32) @ onehot
-                core_cyc = cyc.timestep_cycles_array(
-                    lt.n_pre, slices, nnz, core_touched,
-                    sim.zero_skip, sim.partial_update)
-                wall = wall + jax.ops.segment_sum(
-                    core_cyc, core_idx, num_segments=n_active)
-                fired = jnp.sum(out).astype(jnp.float32)
-                if has_flow[li] or traced:
-                    # per-source-core fired counts, row-aligned with the
-                    # layer's FlowTable; priced exactly on the host
-                    fired_cores[f"fired_core_{li}"] = out @ onehot
-                if traced:
-                    fired_cores[f"touched_core_{li}"] = core_touched
-                nnzs.append(nnz)
-                toucheds.append(tsum)
-                fireds.append(fired)
-                # fired counters above are pre-drop (the source fired and
-                # committed the energy); the next layer integrates what
-                # survived the hops
-                if drop is not None and drop.keep_p[li] is not None:
-                    spikes = out * drop.mask(li, t)
-                else:
-                    spikes = out
+                with jax.named_scope(f"snn_compiled_l{li + 1}"):
+                    lt, slices, core_idx, onehot = layer_consts[li]
+                    nnz = jnp.sum(spikes != 0).astype(jnp.float32)
+                    if trace_skips:
+                        # ZSPE skip telemetry on the layer's input spikes —
+                        # packs exactly like the fused engine's native
+                        # empty-word counter, so the two agree bit-for-bit
+                        skips.append(Z.empty_spike_words(
+                            Z.pack_spike_words(spikes)).astype(jnp.float32))
+                    current = jnp.matmul(
+                        spikes, w, precision=Z.CURRENT_PRECISION)
+                    st, out, touched = lif_step(
+                        states[li], current, lif,
+                        touched=touch_mask(spikes, nonzero_w[li]))
+                    new_states.append(st)
+                    tsum = jnp.sum(touched).astype(jnp.float32)
+                    # integer-exact per-core-slice touched counts: the cycle
+                    # model ceils them, and exact ints cannot straddle a ceil
+                    # boundary between f32 (here) and f64 (reference)
+                    core_touched = touched.astype(jnp.float32) @ onehot
+                    core_cyc = cyc.timestep_cycles_array(
+                        lt.n_pre, slices, nnz, core_touched,
+                        sim.zero_skip, sim.partial_update)
+                    wall = wall + jax.ops.segment_sum(
+                        core_cyc, core_idx, num_segments=n_active)
+                    fired = jnp.sum(out).astype(jnp.float32)
+                    if has_flow[li] or traced:
+                        # per-source-core fired counts, row-aligned with the
+                        # layer's FlowTable; priced exactly on the host
+                        fired_cores[f"fired_core_{li}"] = out @ onehot
+                    if traced:
+                        fired_cores[f"touched_core_{li}"] = core_touched
+                    nnzs.append(nnz)
+                    toucheds.append(tsum)
+                    fireds.append(fired)
+                    # fired counters above are pre-drop (the source fired and
+                    # committed the energy); the next layer integrates what
+                    # survived the hops
+                    if drop is not None and drop.keep_p[li] is not None:
+                        spikes = out * drop.mask(li, t)
+                    else:
+                        spikes = out
             ys = {
                 "nnz": jnp.stack(nnzs),
                 "touched": jnp.stack(toucheds),
@@ -1230,22 +1277,14 @@ class ShardedEngine(_EngineBase):
             trains, self._shard_learned(idx0), *stacks, *plast_stacks)
 
     def run_raw(self, spike_trains: jax.Array, learned=None) -> dict:
-        trains = jnp.asarray(spike_trains, jnp.float32)
-        if trains.ndim != 3:
-            raise ValueError(f"expected (batch, T, n_in), got {trains.shape}")
+        trains = self._upload(spike_trains)
         nb_max = len(jax.devices()) // self.n_shards
         nb = (nb_max if self.shard and nb_max > 1
               and int(trains.shape[0]) % nb_max == 0 else 1)
         if nb not in self._exec:
             self._exec[nb] = self._make_executable(nb)
         self.last_run_sharded = self.n_shards > 1 or nb > 1
-        if not self.plast.enabled:
-            if learned is not None:
-                raise ValueError("learned indexes passed but plasticity "
-                                 "is off")
-            return self._exec[nb](trains)
-        return self._exec[nb](
-            trains, self._initial_learned(int(trains.shape[0]), learned))
+        return self._dispatch(nb, trains, learned)
 
 
 class FusedEngine(_EngineBase):
@@ -1318,15 +1357,17 @@ class FusedEngine(_EngineBase):
                 interp, codebook=lw.codebook_mode,
                 n_levels=int(lw.cbw.shape[0]) if lw.codebook_mode else 0,
                 all_nonzero=lw.all_nonzero)
+            # a stable kernel name per layer, for the profile's op line
+            name = f"snn_fused_l{li + 1}"
             if lw.codebook_mode:
                 return fused_timestep_codebook(
                     packed, lw.idx, lw.cbw, state.v, state.elapsed,
                     gather=interp, all_nonzero=lw.all_nonzero,
-                    block=block, interpret=interp, **lif_kw)
+                    block=block, interpret=interp, name=name, **lif_kw)
             return fused_timestep_dense(
                 packed, lw.dense, state.v, state.elapsed,
                 all_nonzero=lw.all_nonzero, block=block, interpret=interp,
-                **lif_kw)
+                name=name, **lif_kw)
 
         def step(states, xs):                # xs: (B, kw0) uint16 [+ t]
             from repro.core.neuron import LIFState

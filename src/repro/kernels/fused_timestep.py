@@ -236,7 +236,7 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
 
 
 def _call(pk, w0, w1, v, elapsed, *, codebook, gather, threshold, leak,
-          reset, partial_update, all_nonzero, block, interpret):
+          reset, partial_update, all_nonzero, block, interpret, name):
     m, kw = pk.shape
     k = kw * SPIKE_WORD_BITS
     n = v.shape[-1]
@@ -282,6 +282,7 @@ def _call(pk, w0, w1, v, elapsed, *, codebook, gather, threshold, leak,
         compiler_params=(None if interpret else pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES)),
         interpret=interpret,
+        name=name,
     )(*operands)
 
 
@@ -295,7 +296,7 @@ def _drop_w1(kern):
 
 @functools.partial(jax.jit, static_argnames=(
     "threshold", "leak", "reset", "partial_update", "gather",
-    "all_nonzero", "block", "interpret"))
+    "all_nonzero", "block", "interpret", "name"))
 def fused_timestep_codebook(
     packed: jax.Array,        # (M, Kw) uint16 spike words
     idx: jax.Array,           # (Kw*16, N) int8 codebook indexes
@@ -311,6 +312,7 @@ def fused_timestep_codebook(
     all_nonzero: bool = False,
     block: tuple[int, int] | None = None,
     interpret: bool = True,
+    name: str | None = None,
 ):
     """One fused layer-timestep, codebook-compressed weights.
 
@@ -320,17 +322,18 @@ def fused_timestep_codebook(
 
     Returns (v', elapsed', spikes, touched, nnz_rows, empty_words).
     `block=None` runs a single (M, N) tile — the engine's bit-exact
-    configuration; pass (bm, bn) divisors to tile for TPU VMEM.
+    configuration; pass (bm, bn) divisors to tile for TPU VMEM.  `name`
+    names the kernel in the compiled program and its profile.
     """
     return _call(packed, idx, cbw, v, elapsed, codebook=True, gather=gather,
                  threshold=threshold, leak=leak, reset=reset,
                  partial_update=partial_update, all_nonzero=all_nonzero,
-                 block=block, interpret=interpret)
+                 block=block, interpret=interpret, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "threshold", "leak", "reset", "partial_update", "all_nonzero", "block",
-    "interpret"))
+    "interpret", "name"))
 def fused_timestep_dense(
     packed: jax.Array,        # (M, Kw) uint16 spike words
     weights: jax.Array,       # (Kw*16, N) f32 dense weights
@@ -344,6 +347,7 @@ def fused_timestep_dense(
     all_nonzero: bool = False,
     block: tuple[int, int] | None = None,
     interpret: bool = True,
+    name: str | None = None,
 ):
     """Dense-weight variant (float simulators): same ZSPE/LIF fusion.
 
@@ -353,4 +357,4 @@ def fused_timestep_dense(
     return _call(packed, weights, None, v, elapsed, codebook=False,
                  gather=False, threshold=threshold, leak=leak, reset=reset,
                  partial_update=partial_update, all_nonzero=all_nonzero,
-                 block=block, interpret=interpret)
+                 block=block, interpret=interpret, name=name)

@@ -47,12 +47,15 @@ Metrics: the server maintains a `telemetry.MetricsRegistry` with global
 series (latency/queue-wait/occupancy histograms, queue-depth gauge,
 request/shed/deadline counters) plus per-tenant labelled series
 (`snn_request_latency_ms{tenant="..."}` etc.) — the scrape surface the
-CI serve-smoke job gates on.
+CI serve-smoke job gates on.  A slot group's assembly and upload run
+under a `snn.upload` profiler span (`bytes` stat), beside the engine's
+own `snn.*` spans.
 """
 from __future__ import annotations
 
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -288,11 +291,16 @@ class SnnServer:
             r.t_dequeue = t_dequeue
         try:
             T = group[0].timesteps
-            batch = np.zeros((self.slots, T, tenant.n_in), np.float32)
-            for i, r in enumerate(group):
-                batch[i] = r.events
-            counts, reports, degraded = self._dispatch(tenant,
-                                                       jnp.asarray(batch))
+            shape = (self.slots, T, tenant.n_in)
+            # the group's upload: run_batch then finds its trains on the
+            # device, and its own `snn.upload` moves nothing
+            with jax.profiler.TraceAnnotation(
+                    "snn.upload", bytes=4 * int(np.prod(shape))):
+                batch = np.zeros(shape, np.float32)
+                for i, r in enumerate(group):
+                    batch[i] = r.events
+                batch = jnp.asarray(batch)
+            counts, reports, degraded = self._dispatch(tenant, batch)
             counts = np.asarray(counts)
         except Exception:
             for r in group:

@@ -244,16 +244,3 @@ class MetricsRegistry:
             for m in members:
                 lines.extend(m.sample_lines())
         return "\n".join(lines) + ("\n" if lines else "")
-
-    def to_dict(self) -> dict:
-        out: dict[str, object] = {}
-        for key, m in self._metrics.items():
-            if isinstance(m, Histogram):
-                out[key] = {
-                    "count": m.count, "sum": m.sum,
-                    **{f"p{int(q * 100)}": m.percentile(q)
-                       for q in m.QUANTILES},
-                }
-            else:
-                out[key] = m.value
-        return out
