@@ -36,8 +36,9 @@ arithmetic to the reference loop (DESIGN.md §7).  The engines:
 
 Both engines shard the batch across available devices with
 `shard_map` (batch axis, weights replicated) when the batch divides the
-device count, and the fused engine donates its membrane-state buffers to
-the XLA program (`donate_argnums`), so v/elapsed are updated in place.
+device count.  Each `run_batch` enqueues one XLA program: the fused
+engine builds its zero membrane state and packs the input spikes inside
+it, so nothing but the trains crosses from the host.
 
 The bit-identical-spikes contract is validated on the CPU backend,
 where XLA's reduction order for the (B, n) @ (n, m) batched matmul
@@ -66,7 +67,8 @@ import numpy as np
 from repro.core import energy as E
 from repro.core import noc as NOC
 from repro.core import zspe as Z
-from repro.core.neuron import init_state, lif_step, touch_mask
+from repro.core.neuron import (init_batch_state, init_state, lif_step,
+                                touch_mask)
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard (soc -> engine)
     from repro.core.soc import ChipReport, ChipSimulator
@@ -1295,8 +1297,8 @@ class FusedEngine(_EngineBase):
     re-packed for the next layer — and weights stay codebook-compressed
     (int8 indexes + per-column RegisterTable level values) whenever the
     simulator's register tables reproduce the executed weights exactly.
-    Membrane state is passed in explicitly and donated to the XLA
-    program, so v/elapsed update in place across calls.
+    The zero membrane state is built inside the program, so a call is
+    one launch; the final state is returned (`last_states`).
 
     In interpret mode (CPU) each kernel runs one (B, K, N) tile whose
     float program matches the compiled engine expression-for-expression:
@@ -1421,17 +1423,22 @@ class FusedEngine(_EngineBase):
             }
             return tuple(new_states), ys
 
+        def scan_trains(step_fn, carry, trains):  # (B, T, n_in) f32
+            # packing is the program's first op: (T, B, kw0) uint16 words
+            packed_t = jnp.swapaxes(Z.pack_spike_words(trains), 0, 1)
+            xs = (packed_t if drop is None
+                  else (packed_t, jnp.arange(packed_t.shape[0])))
+            final, ys = jax.lax.scan(step_fn, carry, xs)
+            ys = jax.tree_util.tree_map(lambda a: jnp.swapaxes(a, 0, 1), ys)
+            return ys, final
+
+        def zero_states(batch):
+            return tuple(init_batch_state(batch, lw.n_post) for lw in fused_w)
+
         if not self.plast.enabled:
-            def run(packed_trains, states):  # (B, T, kw0) uint16, LIFStates
-                packed_t = jnp.swapaxes(packed_trains, 0, 1)
-                xs = (packed_t if drop is None
-                      else (packed_t, jnp.arange(packed_t.shape[0])))
-                final, ys = jax.lax.scan(step, states, xs)
-                ys = jax.tree_util.tree_map(
-                    lambda a: jnp.swapaxes(a, 0, 1), ys)
-                # final states are returned so the donated membrane buffers
-                # have same-shaped outputs to alias into (in-place update)
-                return ys, final
+            def run(trains):                 # (B, T, n_in) f32
+                return scan_trains(step, zero_states(trains.shape[0]),
+                                   trains)
 
             return run
 
@@ -1535,14 +1542,21 @@ class FusedEngine(_EngineBase):
             }
             return (tuple(new_states), nidx, nxpre, nxpost, nelig), ys
 
-        def run(packed_trains, carry):
-            packed_t = jnp.swapaxes(packed_trains, 0, 1)
-            xs = (packed_t if drop is None
-                  else (packed_t, jnp.arange(packed_t.shape[0])))
-            final, ys = jax.lax.scan(step_plast, carry, xs)
-            ys = jax.tree_util.tree_map(
-                lambda a: jnp.swapaxes(a, 0, 1), ys)
-            return ys, final
+        kps = [lw.kw * Z.SPIKE_WORD_BITS for lw in fused_w]
+
+        def run(trains, idx0):               # idx0: row-padded, B leading
+            B = trains.shape[0]
+            xpre0 = [None if c is None else
+                     jnp.zeros((B, kps[li]), jnp.float32)
+                     for li, c in enumerate(cbws)]
+            xpost0 = [None if c is None else
+                      jnp.zeros((B, fused_w[li].n_post), jnp.float32)
+                      for li, c in enumerate(cbws)]
+            elig0 = [jnp.zeros((B, kps[li], fused_w[li].n_post), jnp.float32)
+                     if (c is not None and reward) else None
+                     for li, c in enumerate(cbws)]
+            carry = (zero_states(B), list(idx0), xpre0, xpost0, elig0)
+            return scan_trains(step_plast, carry, trains)
 
         return run
 
@@ -1557,49 +1571,26 @@ class FusedEngine(_EngineBase):
         return idx
 
     def _make_executable(self, sharded: bool):
-        from repro.core.neuron import LIFState
-
         fn = self._build_run()
         if sharded:
-            fn = self._shard_wrap(fn, n_args=2)
-        run_jit = jax.jit(fn, donate_argnums=(1,))   # donate membrane state
-        pack = jax.jit(Z.pack_spike_words)
-        fused_w = self.fused_weights
+            fn = self._shard_wrap(fn, n_args=2 if self.plast.enabled else 1)
 
         if not self.plast.enabled:
+            run_jit = jax.jit(fn)
+
             def executable(trains):          # (B, T, n_in) f32
-                B = int(trains.shape[0])
-                states = tuple(
-                    LIFState(v=jnp.zeros((B, lw.n_post), jnp.float32),
-                             elapsed=jnp.zeros((B, lw.n_post), jnp.int32))
-                    for lw in fused_w)
-                ys, self.last_states = run_jit(pack(trains), states)
+                ys, self.last_states = run_jit(trains)
                 return ys
 
             return executable
 
+        run_jit = jax.jit(fn, donate_argnums=(1,))   # donate learned idx
         plast_tables = self.plast_tables
+        fused_w = self.fused_weights
         reward = self.plast.mode == "reward"
 
         def executable(trains, idx0):        # idx0: row-padded, B leading
-            B = int(trains.shape[0])
-            states = tuple(
-                LIFState(v=jnp.zeros((B, lw.n_post), jnp.float32),
-                         elapsed=jnp.zeros((B, lw.n_post), jnp.int32))
-                for lw in fused_w)
-            kps = [lw.kw * Z.SPIKE_WORD_BITS for lw in fused_w]
-            xpre0 = [None if pt is None else
-                     jnp.zeros((B, kps[li]), jnp.float32)
-                     for li, pt in enumerate(plast_tables)]
-            xpost0 = [None if pt is None else
-                      jnp.zeros((B, fused_w[li].n_post), jnp.float32)
-                      for li, pt in enumerate(plast_tables)]
-            elig0 = [jnp.zeros((B, kps[li], fused_w[li].n_post),
-                               jnp.float32)
-                     if (pt is not None and reward) else None
-                     for li, pt in enumerate(plast_tables)]
-            carry = (states, list(idx0), xpre0, xpost0, elig0)
-            ys, final = run_jit(pack(trains), carry)
+            ys, final = run_jit(trains, idx0)
             self.last_states = final[0]
             fidx, felig = final[1], final[4]
             for li, pt in enumerate(plast_tables):

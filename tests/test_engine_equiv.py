@@ -362,6 +362,23 @@ def test_fused_shard_map_multi_device():
                                   np.asarray(counts[:3]))
 
 
+def test_fused_run_batch_makes_no_host_transfer():
+    """A fused `run_batch` on trains already on the device is one program
+    launch: the zero membrane state and the spike packing are built in
+    the program, so nothing crosses from the host to the device, and the
+    answer equals an unguarded run's."""
+    rng = np.random.default_rng(43)
+    w = make_weights(rng, (48, 32, 10))
+    trains = jax.device_put(make_trains(rng, 8, 6, 48))
+    guarded = ChipSimulator(w, engine="fused")
+    free = ChipSimulator(w, engine="fused", mapping=guarded.mapping)
+    with jax.transfer_guard_host_to_device("disallow"):
+        counts_g, reps_g = guarded.run_batch(trains)
+    counts_f, reps_f = free.run_batch(trains)
+    np.testing.assert_array_equal(np.asarray(counts_g), np.asarray(counts_f))
+    assert reps_g == reps_f
+
+
 def test_fused_engine_block_selection():
     """Interpret mode runs one exact tile (the bit-exact config); the
     real-TPU path picks tiles Mosaic accepts (bm = m or a multiple of 16
