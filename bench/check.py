@@ -1,7 +1,8 @@
 """The comparison that decides `correct`.
 
-What the timed path produced is held against `reference.run` on the same
-spike trains, once the window has closed:
+What the timed path produced is held against the network kind's
+`reference` (`bench/networks/<kind>.py`) on the same spike trains, once
+the window has closed:
 
 * batch cells: every train of every `run_batch` call of the window.
   - `differing_trains`: trains whose output spike counts, or any exact

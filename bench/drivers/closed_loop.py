@@ -13,7 +13,7 @@ import time
 
 import numpy as np
 
-from bench import check, reference
+from bench import check, reference, registry
 
 
 def pool_size(traffic: dict) -> int:
@@ -62,18 +62,19 @@ def outcome(record: dict) -> tuple[int, int]:
 
 def correctness(record: dict, batches, layers, plan: dict, config: dict,
                 traffic: dict, control: bool = False) -> dict:
-    """Compare every call of the window with the reference (or, with
-    `control`, the control with the reference), computed once per batch
-    of the pool."""
+    """Compare every call of the window with the network kind's reference
+    (or, with `control`, its control with its reference), computed once
+    per batch of the pool."""
+    net = registry.network(config)
     calls = record["calls"]
     numbers = {"differing_trains": 0, "energy_rel_gap": 0.0,
                "wall_rel_gap": 0.0}
     for k in sorted({c[0] for c in calls}):
-        ref_counts, ref_fields = reference.run(layers, batches[k], config,
+        ref_counts, ref_fields = net.reference(layers, batches[k], config,
                                                plan)
         mine = [c for c in calls if c[0] == k]
         if control:
-            got = reference.run(layers, batches[k], config, plan,
+            got = net.reference(layers, batches[k], config, plan,
                                 control=True)
             mine = [(k, *got)] * len(mine)
         for _, counts, fields in mine:

@@ -23,7 +23,7 @@ import time
 
 import numpy as np
 
-from bench import check, reference
+from bench import check, reference, registry
 
 GRACE_S = 60.0
 
@@ -124,9 +124,9 @@ def outcome(record: dict) -> tuple[int, int]:
 
 def correctness(record: dict, trains, layers, plan: dict, config: dict,
                 traffic: dict, control: bool = False) -> dict:
-    """Compare every served request with the reference (or, with
-    `control`, the control with the reference), computed once per train
-    of the pool."""
+    """Compare every served request with the network kind's reference
+    (or, with `control`, its control with its reference), computed once
+    per train of the pool."""
     reqs = record["requests"]
     never = sum(r.status not in ("served", "shed") for r in reqs)
     served = [r for r in reqs if r.status == "served"]
@@ -137,10 +137,12 @@ def correctness(record: dict, trains, layers, plan: dict, config: dict,
     used = np.unique(rows)
     at = np.searchsorted(used, rows)
     energy = reference.FIELDS.index("energy_pj")
-    ref_counts, ref_fields = reference.run(layers, trains[used], config, plan)
+    net = registry.network(config)
+    ref_counts, ref_fields = net.reference(layers, trains[used], config,
+                                           plan)
     ref_counts, ref_energy = ref_counts[at], ref_fields[at, energy]
     if control:
-        counts, fields = reference.run(layers, trains[used], config, plan,
+        counts, fields = net.reference(layers, trains[used], config, plan,
                                        control=True)
         counts, got_energy = counts[at], fields[at, energy]
         preds = np.argmax(counts, axis=-1)

@@ -1,7 +1,9 @@
 """The plain reference: the chip's SNN semantics and pricing in NumPy.
 
-It imports nothing of the program.  Per layer-step, for a batch of
-spike trains:
+It imports nothing of the program.  What every network kind shares
+lives here; how a kind's layers connect, and so which spikes reach
+which layer, lives in its `bench/networks/<kind>.py`, which composes
+these pieces.  Per layer-step, for a batch of spike trains:
 
     current  = spikes @ W                               (f32)
     touched  = any valid spike reaches a nonzero synapse
@@ -11,9 +13,11 @@ spike trains:
     v        = reset if spike, v_int if touched, else v (lazy leak)
     elapsed  = 0 if touched else pending
 
-(the partial-update LIF with hard reset of the paper, and of
-`core/neuron.py`), and the per-sample `ChipReport` the chip model
-prices, with zero-skip and partial update on, as the paper's chip runs:
+(`touched_neurons`, then `lif_step`: the partial-update LIF with hard
+reset of the paper, and of `core/neuron.py`), and the per-sample
+`ChipReport` the chip model prices, with zero-skip and partial update
+on, as the paper's chip runs (`chip_report`, from each weight layer's
+`Edge` and each firing population's `Flows`):
 
 * counters: input spikes, SOPs performed (spikes x fan-out), neurons
   touched, spikes routed between layers, nominal SOPs, empty 16-spike
@@ -29,19 +33,22 @@ prices, with zero-skip and partial update on, as the paper's chip runs:
   RISC-V over the wall time, and their total.
 
 The placement of neuron slices on cores and the routes between them are
-the mapping compiler's; they enter as plain data (`plan`, see `run`),
-and every price is worked out here from them.
+the mapping compiler's; they enter as plain data (a kind's `plan`), and
+every price is worked out here from them.
 
 `high_precision_weights(w)` gives the weights that a TPU's 3-pass bf16
 matmul (precision HIGH) multiplies when the left operand is 0/1: the
 weight's high bf16 half cut toward zero, plus the remainder rounded to
 bf16 (bit-equal to the TPU's HIGH currents at these configurations'
 weights; one rounding step of 16 of the weight's up to 18 significant
-bits).  Run with them, the reference is the control (`control=True`):
-the same network one precision step below the f32 HIGHEST that the
-program states.
+bits).  Run with them, a kind's reference is the control
+(`control=True`): the same network one precision step below the f32
+HIGHEST that the program states.
 """
+
 from __future__ import annotations
+
+import dataclasses
 
 import numpy as np
 
@@ -103,7 +110,7 @@ def high_precision_weights(w: np.ndarray) -> np.ndarray:
     return hi + _bf16_nearest(w - hi)
 
 
-def _empty_words(s: np.ndarray) -> np.ndarray:
+def empty_words(s: np.ndarray) -> np.ndarray:
     """(B, K) 0/1 -> (B,) count of all-zero 16-spike words (zero-padded)."""
     b, k = s.shape
     kw = -(-k // SPIKE_WORD_BITS)
@@ -111,69 +118,86 @@ def _empty_words(s: np.ndarray) -> np.ndarray:
     return (~padded.reshape(b, kw, SPIKE_WORD_BITS).any(-1)).sum(-1)
 
 
-def _slice_sums(x: np.ndarray, slices) -> np.ndarray:
+def slice_sums(x: np.ndarray, slices) -> np.ndarray:
     """(B, n) -> (B, A): sums over each [lo, hi) neuron slice."""
     return np.stack([x[:, lo:hi].sum(-1) for lo, hi in slices], -1)
 
 
-def simulate(weights, trains, *, leak: float, threshold: float,
-             reset: float = 0.0, slices=None, matmul=np.matmul) -> dict:
-    """(B, T, n_in) 0/1 trains through the network.
+def touched_neurons(s: np.ndarray, nnz: np.ndarray, nonzero_w,
+                    n_post: int, matmul=np.matmul) -> np.ndarray:
+    """(B, n_post) neurons that a valid spike of `s` (B, n_pre) reaches
+    through a nonzero synapse.  `nonzero_w` is the layer's 0/1 f32 mask
+    of nonzero synapses, or None when every synapse is nonzero (then any
+    input spike touches every neuron); `nnz` (B,) counts `s`'s spikes."""
+    if nonzero_w is None:
+        return np.broadcast_to((nnz > 0)[:, None], (len(s), n_post))
+    return matmul(s, nonzero_w) > 0
 
-    Returns `counts` (B, n_out) output spike counts and the per-step
-    per-layer counters `nnz`, `touched`, `fired`, `skip` (B, T, L).  With
-    `slices` (per layer, the [lo, hi) neuron ranges of its core slices)
-    also `touched_slices` and `fired_slices`: per layer (B, T, A).
-    `matmul` computes a layer's currents.
-    """
-    trains = np.asarray(trains, np.float32)
-    B, T, _ = trains.shape
+
+def lif_step(v: np.ndarray, elapsed: np.ndarray, current: np.ndarray,
+             touched: np.ndarray, *, leak: float, threshold: float,
+             reset: float) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """One partial-update LIF step with lazy leak and hard reset, in f32:
+    -> (v, elapsed, spike)."""
     leak32, thr32, reset32 = (np.float32(leak), np.float32(threshold),
                               np.float32(reset))
-    nonzero = [bool(np.all(w != 0)) for w in weights]
-    nzw = [None if nz else (w != 0).astype(np.float32)
-           for w, nz in zip(weights, nonzero)]
-    v = [np.zeros((B, w.shape[1]), np.float32) for w in weights]
-    elapsed = [np.zeros((B, w.shape[1]), np.int32) for w in weights]
-    steps = {k: [] for k in ("nnz", "touched", "fired", "skip")}
-    per_slice = {k: [[] for _ in weights] for k in ("touched_slices",
-                                                    "fired_slices")}
-    counts = np.zeros((B, weights[-1].shape[1]), np.float64)
-    for t in range(T):
-        s = trains[:, t, :]
-        for li, w in enumerate(weights):
-            nnz = (s != 0).sum(-1)
-            steps["nnz"].append(nnz)
-            steps["skip"].append(_empty_words(s))
-            current = matmul(s, w)
-            if nonzero[li]:
-                touched = np.broadcast_to((nnz > 0)[:, None], current.shape)
-            else:
-                touched = matmul(s, nzw[li]) > 0
-            pending = elapsed[li] + 1
-            decay = np.where(touched, leak32 ** pending.astype(np.float32),
-                             np.float32(1.0))
-            v_int = v[li] * decay + current
-            spike = touched & (v_int >= thr32)
-            v[li] = np.where(spike, reset32, np.where(touched, v_int, v[li]))
-            elapsed[li] = np.where(touched, 0, pending).astype(np.int32)
-            steps["touched"].append(touched.sum(-1))
-            steps["fired"].append(spike.sum(-1))
-            if slices is not None:
-                per_slice["touched_slices"][li].append(
-                    _slice_sums(touched, slices[li]))
-                per_slice["fired_slices"][li].append(
-                    _slice_sums(spike, slices[li]))
-            s = spike.astype(np.float32)
-        counts += s
-    L = len(weights)
-    out = {k: np.stack(v_, -1).astype(np.float64).reshape(B, T, L)
-           for k, v_ in steps.items()}
-    if slices is not None:
-        for k, layers in per_slice.items():
-            out[k] = [np.stack(x, 1).astype(np.float64) for x in layers]
-    out["counts"] = counts
-    return out
+    pending = elapsed + 1
+    decay = np.where(touched, leak32 ** pending.astype(np.float32),
+                     np.float32(1.0))
+    v_int = v * decay + current
+    spike = touched & (v_int >= thr32)
+    v = np.where(spike, reset32, np.where(touched, v_int, v))
+    return v, np.where(touched, 0, pending).astype(np.int32), spike
+
+
+@dataclasses.dataclass(frozen=True)
+class Edge:
+    """One weight layer (an edge of the network's graph) as its target
+    cores price it.
+
+    `nnz` and `skip` (B, T): the spikes it takes in per step, and their
+    all-zero 16-spike words; `n_pre`: its input width, which the ZSPE
+    scans in 16-spike words; `fan_out`: the SOPs each input spike
+    performs (the nominal SOPs are `n_pre * fan_out` per step);
+    `slices`: the target's core slices `[core, lo, hi]`, with `touched`
+    (B, T, A) the neurons each slice updates per step.  A core's SPEs
+    take each input spike's `hi - lo` synapses of the slice.
+    """
+
+    nnz: np.ndarray
+    skip: np.ndarray
+    n_pre: int
+    fan_out: int
+    slices: list
+    touched: np.ndarray
+
+
+@dataclasses.dataclass(frozen=True)
+class Flows:
+    """The spikes one population sends over the NoC: `fired` (B, T, A)
+    per source slice, and `routes`, one flow per slice in the same order
+    (`src` core, `dsts`, directed `links` [u, v]); `srcs` are the
+    slices' cores, which the flows' sources must be."""
+
+    fired: np.ndarray
+    routes: list
+    srcs: list
+
+
+def core_wall_cycles(edges) -> np.ndarray:
+    """(B, T): per step the busiest core's pipeline cycles over every
+    slice it holds, in the edges' order."""
+    cores = sorted({c for e in edges for c, _, _ in e.slices})
+    B, T = edges[0].nnz.shape
+    core_cycles = np.zeros((B, T, len(cores)))
+    for e in edges:
+        scan = -(-e.n_pre // SPIKE_WORD_BITS)
+        for a, (core, lo, hi) in enumerate(e.slices):
+            syn = np.ceil(e.nnz * (hi - lo) / _SPE_LANES)
+            upd = e.touched[:, :, a]
+            core_cycles[:, :, cores.index(core)] += np.maximum(
+                np.maximum(scan, syn), upd) + _PIPELINE_DEPTH
+    return core_cycles.max(axis=-1)
 
 
 def _flow_tables(routes, level2_nodes, n_nodes: int):
@@ -193,61 +217,67 @@ def _flow_tables(routes, level2_nodes, n_nodes: int):
     return hops, pj, load
 
 
-def sample_fields(out: dict, config: dict, plan: dict) -> np.ndarray:
-    """Per-sample `ChipReport` (B, len(FIELDS)) from `simulate`'s output
-    (run with the plan's slices)."""
-    sizes = [int(x) for x in config["layer_sizes"]]
-    n_post = np.asarray(sizes[1:], np.float64)
-    B, T, L = out["nnz"].shape
-    performed = (out["nnz"] * n_post).sum(axis=(1, 2))
-    nominal = float(sum(a * b for a, b in zip(sizes[:-1], sizes[1:]))) * T
-
-    # wall cycles of the cores: per step, the busiest core
-    cores = sorted({c for layer in plan["layers"] for c, _, _ in layer})
-    core_cycles = np.zeros((B, T, len(cores)))
-    for li, layer in enumerate(plan["layers"]):
-        scan = -(-sizes[li] // SPIKE_WORD_BITS)
-        nnz = out["nnz"][:, :, li]
-        for a, (core, lo, hi) in enumerate(layer):
-            syn = np.ceil(nnz * (hi - lo) / _SPE_LANES)
-            upd = out["touched_slices"][li][:, :, a]
-            core_cycles[:, :, cores.index(core)] += np.maximum(
-                np.maximum(scan, syn), upd) + _PIPELINE_DEPTH
-    core_wall = core_cycles.max(axis=-1)                      # (B, T)
-
-    # the NoC: every flow replayed with its source slice's spikes
-    nodes = [int(x) for routes in plan["routes"] for f in routes
-             for link in f["links"] for x in link]
-    n_nodes = 1 + max(cores + nodes)
+def noc_traffic(flows, level2_nodes, n_nodes: int, shape: tuple):
+    """Every flow replayed with its source slice's spikes, in the flows'
+    order, over `shape` (B, T) -> (hops (B,), NoC pJ (B,), router
+    occupancy (B, T, n_nodes))."""
+    B, T = shape
     hops = np.zeros(B)
     noc_pj = np.zeros(B)
     router = np.zeros((B, T, n_nodes))
-    for li, routes in enumerate(plan["routes"]):
-        fired = out["fired_slices"][li]                       # (B, T, A)
-        srcs = [c for c, _, _ in plan["layers"][li]]
-        if [int(r["src"]) for r in routes] != srcs:
-            raise ValueError(f"layer {li}: the routes' sources {srcs} are "
-                             f"not its slices' cores")
-        f_hops, f_pj, f_load = _flow_tables(routes, plan["level2_nodes"],
-                                            n_nodes)
-        hops += (fired @ f_hops).sum(axis=1)
-        noc_pj += (fired @ f_pj).sum(axis=1)
-        router += fired @ f_load
-    service = router.max(axis=-1) / _ROUTER_SPIKES_PER_CYCLE
-    contention = service + service * service / np.maximum(core_wall, 1e-9)
-    wall = (core_wall + contention).sum(axis=1)
+    for i, f in enumerate(flows):
+        if [int(r["src"]) for r in f.routes] != list(f.srcs):
+            raise ValueError(f"flow set {i}: the routes' sources are not "
+                             f"its slices' cores {list(f.srcs)}")
+        f_hops, f_pj, f_load = _flow_tables(f.routes, level2_nodes, n_nodes)
+        hops += (f.fired @ f_hops).sum(axis=1)
+        noc_pj += (f.fired @ f_pj).sum(axis=1)
+        router += f.fired @ f_load
+    return hops, noc_pj, router
 
-    core_pj = core_pj_per_nominal_sop(performed / nominal) * nominal
-    duty = np.minimum(1.0, T * _RISCV_CTRL_CYCLES / np.maximum(wall, 1.0))
+
+def contention_cycles(router: np.ndarray, core_wall: np.ndarray
+                      ) -> np.ndarray:
+    """(B, T): the busiest router's M/M/1 queue over each step."""
+    service = router.max(axis=-1) / _ROUTER_SPIKES_PER_CYCLE
+    return service + service * service / np.maximum(core_wall, 1e-9)
+
+
+def riscv_energy_pj(wall: np.ndarray, timesteps: int,
+                    freq_hz: float) -> np.ndarray:
+    """The duty-cycled RISC-V over `wall` cycles of `timesteps` steps."""
+    duty = np.minimum(1.0, timesteps * _RISCV_CTRL_CYCLES
+                      / np.maximum(wall, 1.0))
     riscv_mw = _RISCV_ACTIVE_MW * (duty + _RISCV_SLEEP * (1.0 - duty))
-    riscv_pj = riscv_mw * 1e-3 * (wall / float(config["freq_hz"])) * 1e12
+    return riscv_mw * 1e-3 * (wall / float(freq_hz)) * 1e12
+
+
+def chip_report(edges, flows, *, level2_nodes, freq_hz: float
+                ) -> np.ndarray:
+    """Per-sample `ChipReport` (B, len(FIELDS)) of a network from its
+    weight layers (`Edge`s) and the populations that fire into another
+    (`Flows`)."""
+    B, T = edges[0].nnz.shape
+    performed = sum((e.nnz * e.fan_out).sum(axis=1) for e in edges)
+    nominal = float(sum(e.n_pre * e.fan_out for e in edges)) * T
+    core_wall = core_wall_cycles(edges)                     # (B, T)
+    cores = [c for e in edges for c, _, _ in e.slices]
+    nodes = [int(x) for f in flows for r in f.routes
+             for link in r["links"] for x in link]
+    hops, noc_pj, router = noc_traffic(flows, level2_nodes,
+                                       1 + max(cores + nodes), (B, T))
+    contention = contention_cycles(router, core_wall)
+    wall = (core_wall + contention).sum(axis=1)
+    core_pj = core_pj_per_nominal_sop(performed / nominal) * nominal
+    riscv_pj = riscv_energy_pj(wall, T, freq_hz)
     cols = {
-        "spikes_in": out["nnz"].sum(axis=(1, 2)),
+        "spikes_in": sum(e.nnz.sum(axis=1) for e in edges),
         "performed_sops": performed,
-        "neurons_touched": out["touched"].sum(axis=(1, 2)),
-        "spikes_routed": out["fired"][:, :, :-1].sum(axis=(1, 2)),
+        "neurons_touched": sum(e.touched.sum(axis=(1, 2)) for e in edges),
+        "spikes_routed": sum((f.fired.sum(axis=(1, 2)) for f in flows),
+                             np.zeros(B)),
         "nominal_sops": np.full(B, nominal),
-        "spike_words_skipped": out["skip"].sum(axis=(1, 2)),
+        "spike_words_skipped": sum(e.skip.sum(axis=1) for e in edges),
         "noc_hops": hops,
         "core_energy_pj": core_pj,
         "noc_energy_pj": noc_pj,
@@ -257,29 +287,3 @@ def sample_fields(out: dict, config: dict, plan: dict) -> np.ndarray:
         "noc_contention_cycles": contention.sum(axis=1),
     }
     return np.stack([cols[f] for f in FIELDS], axis=-1)
-
-
-def run(layers, trains: np.ndarray, config: dict, plan: dict, *,
-        control: bool = False, block: int = 32
-        ) -> tuple[np.ndarray, np.ndarray]:
-    """Reference (or control) over `trains` in blocks of rows.
-
-    `layers` are `workload.LayerCodebook`s.  `plan` is the mapping as
-    data: `layers`, per weight layer its slices `[core, lo, hi]` in the
-    compiler's order; `routes`, per layer that fires into another, one
-    flow per slice (`src` core, `dsts`, directed `links` [u, v]); and
-    `level2_nodes`.  Returns (counts (N, n_out), fields (N, len(FIELDS))).
-    """
-    weights = [lc.dense() for lc in layers]
-    if control:
-        weights = [high_precision_weights(w) for w in weights]
-    slices = [[(lo, hi) for _, lo, hi in layer] for layer in plan["layers"]]
-    counts, fields = [], []
-    for lo in range(0, len(trains), block):
-        out = simulate(weights, trains[lo:lo + block],
-                       leak=float(config["leak"]),
-                       threshold=float(config["threshold"]),
-                       reset=float(config.get("reset", 0.0)), slices=slices)
-        counts.append(out["counts"])
-        fields.append(sample_fields(out, config, plan))
-    return np.concatenate(counts), np.concatenate(fields)

@@ -1,20 +1,43 @@
 """Find every part of a cell by its name, as `BENCHMARK.json` gives it.
 
-Each configuration, traffic mix, per-layer metric, traffic driver and
-input generator lives in a file of its own, named after it:
+Each configuration, traffic mix, per-layer metric, traffic driver,
+input generator and network kind lives in a file of its own, named
+after it:
 
     bench/configs/<config>.json       sizes of one network, and its limits
     bench/traffic/<traffic>.json      parameters of one traffic mix
     bench/metrics/<metric>.py         `read(run) -> float | None`
     bench/drivers/<driver>.py         `drive(cell, seconds, ...)`
     bench/inputs/<kind>.py            `make(spec, n, timesteps, seed)`
+    bench/networks/<kind>.py          the network's shape, below
 
-so a cell, a mix or a metric is added by adding files and entries,
-without editing any file that is already here.
+so a cell, a mix, a metric or a network is added by adding files and
+entries, without editing any file that is already here.
+
+A configuration names its network kind under `"network"`
+(`dense_chain` where the key is absent).  Everything that knows the
+network's shape is in that kind's module, which has:
+
+    make(config, seed) -> (program_weights, ref_layers)
+        the weights from the seed, as the simulator takes them (on the
+        device) and as the kind's reference takes them (on the host)
+    simulator(config, traffic, program_weights) -> ChipSimulator
+        the program's simulator on the traffic's `engine`, lowered
+    plan(sim, config) -> dict
+        the mapping compiler's placement and routes, as plain data
+    reference(ref_layers, trains, config, plan, *, control=False,
+              block=32) -> (counts (N, n_out), fields (N, len(FIELDS)))
+        the plain reference (the control with `control`), composed of
+        `bench/reference.py`'s shared LIF step and pricing
+    least_bytes(config, batch) -> float
+        the least bytes one batch needs (`bench/leastwork.py`)
+    n_in(config) -> int
+        the input width the trains must have
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
@@ -42,6 +65,17 @@ def load_module(kind: str, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@functools.cache
+def _network(kind: str):
+    return load_module("networks", kind)
+
+
+def network(config: dict):
+    """The module of the configuration's network kind,
+    `bench/networks/<kind>.py`; loaded once per process."""
+    return _network(config.get("network", "dense_chain"))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -4,19 +4,21 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> \
         --trace <0|1>
 
-Set-up builds the cell from `--seed` (codebook weights on the device,
-spike trains on the host), the `ChipSimulator` with its mapping and the
-cell's engine, and compiles and warms the cell's one shape.  The
-program holds the weights as constants, so its engine programs differ
-from seed to seed: they compile in every run, as for any new network,
-and are kept out of the persistent cache (`cache_writes_off`), so that
-set-up does the same work whether a seed ran before or not.  Then the
-cell's traffic driver runs for `--seconds`, with no compilation inside
-the window.  `--trace 0` reports the cell's end-to-end metrics;
-`--trace 1` runs the window under the profiler and reports its
-per-layer metrics instead.  After the window what the timed path
-produced is compared with the plain reference
-(`bench/reference.py`) on the host: every answer of the window, against
+Set-up builds the cell from `--seed` through its configuration's
+network kind (`bench/networks/<kind>.py`: codebook weights on the
+device, the `ChipSimulator` with its mapping and the cell's engine) and
+its input generator (spike trains on the host), and compiles and warms
+the cell's one shape.  The program holds the weights as constants, so
+its engine programs differ from seed to seed: they compile in every
+run, as for any new network, and are kept out of the persistent cache
+(`cache_writes_off`), so that set-up does the same work whether a seed
+ran before or not.  Then the cell's traffic driver runs for
+`--seconds`, with no compilation inside the window.  `--trace 0`
+reports the cell's end-to-end metrics; `--trace 1` runs the window
+under the profiler and reports its per-layer metrics instead.  After
+the window what the timed path produced is compared with the plain
+reference (the network kind's `reference`, built on
+`bench/reference.py`) on the host: every answer of the window, against
 the reference of its trains; that decides `correct`.
 
 Standard error ends with the numbers compared, each beside its limit;
@@ -110,40 +112,29 @@ class CompileCounter:
 
 
 def build(cell: registry.Cell, seed: int, split: dict):
-    """Weights, trains, the simulator of a cell and its mapping as data
-    (`workload.chip_plan`); `split` collects the seconds of each set-up
+    """Weights, trains, the simulator of a cell and its mapping as data,
+    each through the configuration's network kind
+    (`registry.network`); `split` collects the seconds of each set-up
     phase."""
     import jax
 
-    from repro.core.quant import CodebookConfig
-    from repro.core.soc import ChipSimulator
-
     cfg, traffic = cell.config, cell.traffic
+    net = registry.network(cfg)
     driver = registry.load_module("drivers", traffic["driver"])
     t = time.perf_counter()
-    program_w, layers = workload.make_weights(cfg, seed)
-    jax.block_until_ready([q.idx for q in program_w])
+    program_w, layers = net.make(cfg, seed)
+    jax.block_until_ready(program_w)
     split["weights"] = time.perf_counter() - t
     t = time.perf_counter()
     state = driver.pool(workload.make_trains(cfg, driver.pool_size(traffic),
                                              seed), traffic)
     split["inputs"] = time.perf_counter() - t
     t = time.perf_counter()
-    sim = ChipSimulator(
-        program_w, quant_cfg=CodebookConfig(
-            n_levels=int(cfg["weight_levels"]),
-            bit_width=int(cfg["weight_bits"])),
-        engine=traffic["engine"], leak=float(cfg["leak"]),
-        threshold=float(cfg["threshold"]), freq_hz=float(cfg["freq_hz"]))
-    plan = workload.chip_plan(sim)
-    split["simulator_and_mapping"] = time.perf_counter() - t
+    sim = net.simulator(cfg, traffic, program_w)
+    split["simulator_and_lowering"] = time.perf_counter() - t
     t = time.perf_counter()
-    engine = sim.array_engine()
-    if traffic["engine"] == "fused" and \
-            engine.codebook_layers != len(program_w):
-        raise RuntimeError(f"fused engine runs {engine.codebook_layers} of "
-                           f"{len(program_w)} layers from the codebook")
-    split["lowering"] = time.perf_counter() - t
+    plan = net.plan(sim, cfg)
+    split["mapping_plan"] = time.perf_counter() - t
     del program_w
     return driver, sim, state, layers, plan
 
@@ -202,10 +193,11 @@ def run_cell(cell: registry.Cell, seed: int, seconds: float, trace: bool,
         n = len(rec["calls"])
         lt, bound = leastwork.least_time(cell.config, rec["batch"],
                                          rec["performed_sops"] / n, peak)
+        least_bytes = registry.network(cell.config).least_bytes(
+            cell.config, rec["batch"])
         log(f"roofline: least time per batch {lt * 1e6:.3f} us, "
             f"{bound}-bound ({rec['performed_sops'] / n:.0f} SOPs, "
-            f"{leastwork.least_bytes(cell.config, rec['batch']):.0f} bytes "
-            f"per batch)")
+            f"{least_bytes:.0f} bytes per batch)")
 
     run = RunRecord(config=cell.config, traffic=cell.traffic, seed=seed,
                     setup_s=setup_s, drive=rec, trace=summary, peak=peak)

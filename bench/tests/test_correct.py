@@ -76,19 +76,20 @@ def test_currents_are_exact_f32_sums():
         inexact.append(int(np.sum(a != s.astype(np.float64) @ w)))
         return a
 
+    net = registry.network(cfg)
     for seed in SEEDS:
-        _, layers = workload.make_weights(cfg, seed)
-        reference.simulate([lc.dense() for lc in layers],
-                           workload.make_trains(cfg, 32, seed),
-                           leak=cfg["leak"], threshold=cfg["threshold"],
-                           matmul=matmul)
+        _, layers = net.make(cfg, seed)
+        net.simulate([lc.dense() for lc in layers],
+                     workload.make_trains(cfg, 32, seed),
+                     leak=cfg["leak"], threshold=cfg["threshold"],
+                     matmul=matmul)
     assert sum(inexact) == 0
 
 
 def test_control_weights_hold_every_level_differently():
     cfg = registry.load_json(registry.BENCH_DIR / "configs"
                              / "nmnist_mlp.json")
-    _, layers = workload.make_weights(cfg, SEEDS[0])
+    _, layers = registry.network(cfg).make(cfg, SEEDS[0])
     for lc in layers:
         assert np.all(reference.high_precision_weights(lc.levels)
                       != lc.levels)
@@ -211,9 +212,10 @@ def hand_network():
             "routes": [[{"src": 12, "dsts": [13],
                          "links": [[12, 0], [0, 13]]}]],
             "level2_nodes": []}
-    out = reference.simulate(weights, trains, leak=0.9, threshold=1.0,
-                             slices=[[(0, 4)], [(0, 3)]])
-    return dict(zip(reference.FIELDS, reference.sample_fields(
+    net = registry.network(config)
+    out = net.simulate(weights, trains, leak=0.9, threshold=1.0,
+                       slices=[[(0, 4)], [(0, 3)]])
+    return dict(zip(reference.FIELDS, net.sample_fields(
         out, config, plan)[0])), out
 
 

@@ -27,14 +27,16 @@ def test_unknown_device_kind_is_an_error():
      + 8 * 20 * 2312 / 8 + 8 * 10 * 4),
 ])
 def test_least_bytes_by_hand(batch, by_hand):
-    assert leastwork.least_bytes(config("nmnist_mlp"), batch) == by_hand
+    cfg = config("nmnist_mlp")
+    assert registry.network(cfg).least_bytes(cfg, batch) == by_hand
 
 
 def test_least_time_picks_the_larger_bound():
     cfg = config("nmnist_mlp")
     t, bound = leastwork.least_time(cfg, 32, 32 * 8.66e6, V5E)
     assert bound == "bytes"
-    assert t == pytest.approx(leastwork.least_bytes(cfg, 32) / 819e9)
+    assert t == pytest.approx(
+        registry.network(cfg).least_bytes(cfg, 32) / 819e9)
     t, bound = leastwork.least_time(cfg, 32, 1e12, V5E)
     assert bound == "ops" and t == pytest.approx(2e12 / 197e12)
 

@@ -1,11 +1,14 @@
-"""What a cell runs, made from `--seed`: codebook weights and spike trains.
+"""What a cell runs, made from `--seed`: the pieces of codebook weights
+that every network kind draws its layers from, and spike trains.
 
-Weights are built directly in the chip's codebook form, with no k-means:
-per layer, 16 levels that are signed 8-bit words times one scale, and a
-4-bit index per synapse.  The levels come in +/- pairs, so every weight
-is nonzero and the layer's mean weight is 0; the scale sets the weight
-spread near `weight_gain / sqrt(fan_in)`, which makes the hidden layers
-and the output layer fire.
+A kind (`bench/networks/<kind>.py`, its `make`) builds its weights
+directly in the chip's codebook form from these, with no k-means: per
+layer, 16 levels that are signed 8-bit words times one scale
+(`layer_levels`), and a 4-bit index per synapse (`device_indices`).
+The levels come in +/- pairs, so every weight is nonzero and the
+layer's mean weight is 0; the scale sets the weight spread near
+`weight_gain / sqrt(fan_in)`, which makes the hidden layers and the
+output layer fire.
 
 The scale is rounded to `scale_mantissa_bits` (11) significant bits.  A
 weight then has at most 18 significant bits, so a synaptic current's
@@ -19,7 +22,8 @@ below f32 HIGHEST differs.
 
 The indices (the bulk: 13.7M for the NMNIST network) are drawn on the
 device in one jitted call; the levels are 16 numbers per layer, drawn on
-the host.
+the host.  The trains come from the configuration's input generator
+(`bench/inputs/<kind>.py`), at the width the network kind takes in.
 """
 from __future__ import annotations
 
@@ -84,49 +88,13 @@ def _draw_indices(key, shapes: tuple, n_levels: int):
         for i, s in enumerate(shapes))
 
 
-def _device_indices(seed: int, shapes: tuple, n_levels: int):
+def device_indices(seed: int, shapes: tuple, n_levels: int):
+    """Uniform int8 indices in [0, n_levels) of each shape, on the device,
+    in one jitted call: a pure function of `seed`."""
     # seeds may exceed 32 bits: fold the high word in
     key = jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                              seed >> 32)
     return _draw_indices(key, shapes, n_levels)
-
-
-def make_weights(config: dict, seed: int):
-    """-> (program weights: list of `quant.QuantizedTensor` on the device,
-    reference weights: list of `LayerCodebook` on the host)."""
-    from repro.core.quant import QuantizedTensor
-
-    sizes = [int(s) for s in config["layer_sizes"]]
-    shapes = tuple(zip(sizes[:-1], sizes[1:]))
-    idx_dev = _device_indices(seed, shapes, int(config["weight_levels"]))
-    program, reference = [], []
-    for li, ((n_pre, _), idx) in enumerate(zip(shapes, idx_dev)):
-        words, scale = layer_levels(config, seed, li, n_pre)
-        levels = words.astype(np.float32) * scale
-        program.append(QuantizedTensor(
-            idx=idx, codebook=jnp.asarray(levels[None, :]),
-            scale=jnp.asarray([scale], jnp.float32), group_axis_size=0))
-        reference.append(LayerCodebook(idx=np.asarray(idx), words=words,
-                                       scale=scale, levels=levels))
-    return program, reference
-
-
-def chip_plan(sim) -> dict:
-    """The mapping compiler's placement and routes of `sim`, as the plain
-    data `reference.run` prices from: per weight layer its core slices
-    `[core, lo, hi]`, per layer that fires into another one flow per
-    slice (`src`, `dsts`, `links`), and the level-2 router nodes."""
-    n_layers = len(sim.mapping.layer_sizes) - 1
-    return {
-        "layers": [[[a.core_id, a.neuron_lo, a.neuron_hi]
-                    for a in sim.mapping.cores_of_layer(li + 1)]
-                   for li in range(n_layers)],
-        "routes": [[{"src": int(r.src), "dsts": [int(d) for d in r.dsts],
-                     "links": [[int(u), int(v)] for u, v in r.links]}
-                    for r in sim._layer_routes[li + 1]]
-                   for li in range(n_layers - 1)],
-        "level2_nodes": sorted(int(x) for x in sim._level2),
-    }
 
 
 def make_trains(config: dict, n: int, seed: int) -> np.ndarray:
@@ -135,7 +103,8 @@ def make_trains(config: dict, n: int, seed: int) -> np.ndarray:
     spec = config["input"]
     gen = registry.load_module("inputs", spec["kind"])
     trains = gen.make(spec, n, int(config["timesteps"]), seed)
-    if trains.shape[-1] != int(config["layer_sizes"][0]):
+    n_in = registry.network(config).n_in(config)
+    if trains.shape[-1] != n_in:
         raise ValueError(f"input width {trains.shape[-1]} differs from the "
-                         f"network's {config['layer_sizes'][0]}")
+                         f"network's {n_in}")
     return trains
