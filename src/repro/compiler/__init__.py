@@ -249,10 +249,12 @@ def compile_network(net: Any, chip: ChipSpec | None = None, *,
             # local-path composition assumes the healthy local graph;
             # a faulty fabric routes flat on the masked global adjacency
             routed = _route_or_raise(groups, placement.assignment,
-                                     adjacency, su.level2_nodes, faults)
+                                     adjacency, su.level2_nodes, faults,
+                                     graph.recurrent)
         else:
             routed = R.route_hierarchical(groups, placement.assignment,
-                                          su.adjacency, su.level2_nodes)
+                                          su.adjacency, su.level2_nodes,
+                                          recurrent=graph.recurrent)
     else:
         dplan, dplacements = None, None
         core_slots = su.core_slots
@@ -274,9 +276,10 @@ def compile_network(net: Any, chip: ChipSpec | None = None, *,
         baseline = PL.placement_cost(
             PL.contiguous_place(groups, core_slots), flows, dist)
         routed = (_route_or_raise(groups, placement.assignment, adjacency,
-                                  su.level2_nodes, faults) if topo
+                                  su.level2_nodes, faults, graph.recurrent)
+                  if topo
                   else R.route(groups, placement.assignment, su.adjacency,
-                               su.level2_nodes))
+                               su.level2_nodes, recurrent=graph.recurrent))
     compiled = CompiledNetwork(net=graph, spec=spec, groups=groups,
                                placement=placement, plan=su, routed=routed,
                                baseline_cost=baseline, domain_plan=dplan,
@@ -288,12 +291,14 @@ def compile_network(net: Any, chip: ChipSpec | None = None, *,
     return compiled
 
 
-def _route_or_raise(groups, assignment, adjacency, level2_nodes, faults):
+def _route_or_raise(groups, assignment, adjacency, level2_nodes, faults,
+                    recurrent=()):
     """Flat route on a fault-masked adjacency, with unroutable pairs
     surfaced as ValueError (the surviving graph is partitioned) instead
     of the routing table's bare assertion."""
     try:
-        return R.route(groups, assignment, adjacency, level2_nodes)
+        return R.route(groups, assignment, adjacency, level2_nodes,
+                       recurrent=recurrent)
     except AssertionError as e:
         raise ValueError(
             f"faults {faults.describe()} disconnect the surviving fabric: "

@@ -2,8 +2,10 @@
 
 A `NetworkGraph` abstracts every supported frontend (dense SNN MLPs from
 models/snn.py, conv SNNs from models/snn_conv.py, raw weight lists) into
-the only facts the mapper needs: per-layer neuron counts, fan-in, and the
-expected spike traffic each layer emits per timestep.  Spike rates can be
+the only facts the mapper needs: per-layer neuron counts, fan-in, the
+expected spike traffic each layer emits per timestep, and which layers
+feed their own spikes back to themselves (a recurrent self-edge, whose
+spikes reach the layer one timestep later).  Spike rates can be
 *measured* (by running the net on event data — see `measure_spike_rates`)
 or *estimated* from the input stream's sparsity with a geometric
 attenuation per layer, which is how real toolchains bootstrap placement
@@ -43,8 +45,14 @@ class LayerSpec:
 
 @dataclasses.dataclass(frozen=True)
 class NetworkGraph:
+    """Layers in feed-forward order, each feeding the next.  A layer
+    index in `recurrent` also has a self-edge: its spikes of step t-1
+    reach every one of its own neurons at step t, at the layer's own
+    spike rate (`self_edges`)."""
+
     layers: tuple[LayerSpec, ...]
     spike_rates: tuple[float, ...]   # spikes/timestep emitted by each layer
+    recurrent: tuple[int, ...] = ()  # placed layers with a self-edge
 
     def __post_init__(self):
         if len(self.layers) < 2:
@@ -53,6 +61,16 @@ class NetworkGraph:
             raise ValueError("one spike rate per layer required")
         if self.layers[0].kind != "input":
             raise ValueError("layer 0 must be the input population")
+        bad = [p for p in self.recurrent
+               if not 1 <= int(p) < len(self.layers)]
+        if bad:
+            raise ValueError(f"recurrent layers {bad} are not placed layers "
+                             f"(1..{len(self.layers) - 1})")
+
+    def self_edges(self) -> tuple[tuple[int, float], ...]:
+        """(layer index, spikes/timestep) of every recurrent self-edge."""
+        return tuple((int(p), float(self.spike_rates[p]))
+                     for p in sorted(set(self.recurrent)))
 
     @property
     def placed_layers(self) -> tuple[LayerSpec, ...]:
@@ -84,18 +102,24 @@ def estimate_spike_rates(layer_sizes: Sequence[int],
 
 def from_layer_sizes(layer_sizes: Sequence[int],
                      spike_rates: Sequence[float] | None = None,
-                     kinds: Sequence[str] | None = None) -> NetworkGraph:
+                     kinds: Sequence[str] | None = None,
+                     recurrent: Sequence[int] = ()) -> NetworkGraph:
+    """A chain of `layer_sizes` (input first); layer indices in
+    `recurrent` add a self-edge, which widens their fan-in by their own
+    width."""
     sizes = [int(s) for s in layer_sizes]
+    rec = tuple(sorted({int(p) for p in recurrent}))
     kinds = list(kinds) if kinds is not None else (
         ["input"] + ["dense"] * (len(sizes) - 1))
     layers = tuple(
         LayerSpec(index=i, n_neurons=n,
-                  fan_in=0 if i == 0 else sizes[i - 1], kind=kinds[i],
-                  name=f"L{i}")
+                  fan_in=0 if i == 0 else sizes[i - 1] + (n if i in rec
+                                                          else 0),
+                  kind=kinds[i], name=f"L{i}")
         for i, n in enumerate(sizes))
     rates = (tuple(float(r) for r in spike_rates) if spike_rates is not None
              else estimate_spike_rates(sizes))
-    return NetworkGraph(layers=layers, spike_rates=rates)
+    return NetworkGraph(layers=layers, spike_rates=rates, recurrent=rec)
 
 
 def from_weights(weights: Sequence,

@@ -216,7 +216,10 @@ def group_traffic(net: NetworkGraph, groups: list[CoreGroup]
     Feed-forward connectivity is dense between consecutive layers, so every
     spike a source group emits must reach *every* group of the next layer
     (each holds a slice of the postsynaptic population).  A source group's
-    share of its layer's traffic is proportional to its neuron share.
+    share of its layer's traffic is proportional to its neuron share.  A
+    recurrent layer's self-edge adds the same share from each of its
+    groups to every *other* group of the layer (a group's delivery to
+    its own core crosses no link, so it carries no placement cost).
     """
     by_layer: dict[int, list[CoreGroup]] = {}
     for g in groups:
@@ -230,4 +233,11 @@ def group_traffic(net: NetworkGraph, groups: list[CoreGroup]
             share = rate * s.n_neurons / layer.n_neurons
             for d in dsts:
                 flows.append((s.gid, d.gid, share))
+    for index, rate in net.self_edges():
+        members = by_layer[index]
+        n = net.layers[index].n_neurons
+        for s in members:
+            share = rate * s.n_neurons / n
+            flows.extend((s.gid, d.gid, share) for d in members
+                         if d.gid != s.gid)
     return flows

@@ -1,8 +1,9 @@
 """Stage 3 — route: emit static per-CMRouter connection-matrix tables.
 
 For every inter-layer flow (all spikes a source core emits fan out to the
-cores holding the next layer) we resolve the shortest-path route once, at
-compile time, into:
+cores holding the next layer, and a recurrent layer's also to every core
+of its own, that core included at zero hops) we resolve the
+shortest-path route once, at compile time, into:
 
   * a `noc.FlowRoute` — the per-flow link set + hop/level-2 accounting the
     simulator replays each timestep (no BFS at sim time), and
@@ -92,7 +93,8 @@ class RoutedNetwork:
 
     adjacency: np.ndarray
     routing: NOC.RoutingTable | None
-    # src layer index -> one FlowRoute per source core of that layer
+    # src layer index -> one FlowRoute per source core of that layer, to
+    # every core of the next layer and, for a recurrent layer, of its own
     layer_flows: dict[int, list[NOC.FlowRoute]]
     router_tables: RouterTables
     level2_nodes: frozenset[int]
@@ -109,21 +111,37 @@ class RoutedNetwork:
         return sum(f.l2_hops for fl in self.layer_flows.values() for f in fl)
 
 
-def route(groups: list[CoreGroup], assignment: dict[int, int],
-          adj: np.ndarray, level2_nodes: frozenset[int]) -> RoutedNetwork:
-    """Resolve every layer-to-layer flow and program the router tables."""
-    rt = NOC.RoutingTable(adj)
+def _flow_targets(groups: list[CoreGroup], assignment: dict[int, int],
+                  recurrent) -> list[tuple[int, list[CoreGroup], list[int]]]:
+    """(layer, its groups, the cores its spikes reach) for every layer
+    that fires into another: every core of the next layer and, for a
+    layer in `recurrent`, every core of its own (its own core at zero
+    hops).  One multicast tree per source core reaches them all."""
     by_layer: dict[int, list[CoreGroup]] = {}
     for g in groups:
         by_layer.setdefault(g.layer, []).append(g)
+    rec = {int(p) for p in recurrent}
+    targets = []
+    for layer, srcs in sorted(by_layer.items()):
+        dst_layers = [d for d in (layer + 1, layer)
+                      if d in by_layer and (d != layer or layer in rec)]
+        if dst_layers:
+            dst_cores = sorted({assignment[g.gid] for d in dst_layers
+                                for g in by_layer[d]})
+            targets.append((layer, srcs, dst_cores))
+    return targets
+
+
+def route(groups: list[CoreGroup], assignment: dict[int, int],
+          adj: np.ndarray, level2_nodes: frozenset[int],
+          recurrent=()) -> RoutedNetwork:
+    """Resolve every layer-to-layer flow (a layer in `recurrent` also
+    reaching its own cores) and program the router tables."""
+    rt = NOC.RoutingTable(adj)
     tables = RouterTables(tables={})
     layer_flows: dict[int, list[NOC.FlowRoute]] = {}
-
-    last = max(by_layer)
-    for layer, srcs in sorted(by_layer.items()):
-        if layer == last:
-            continue
-        dst_cores = sorted({assignment[g.gid] for g in by_layer[layer + 1]})
+    for layer, srcs, dst_cores in _flow_targets(groups, assignment,
+                                                recurrent):
         flows = []
         for g in srcs:
             src_core = assignment[g.gid]
@@ -193,25 +211,18 @@ def _compose_flow(lrt: NOC.RoutingTable, src: int, dsts: list[int],
 
 
 def route_hierarchical(groups: list[CoreGroup], assignment: dict[int, int],
-                       adj: np.ndarray, level2_nodes: frozenset[int]
-                       ) -> RoutedNetwork:
+                       adj: np.ndarray, level2_nodes: frozenset[int],
+                       recurrent=()) -> RoutedNetwork:
     """Resolve every flow from one shared local routing table: local
     paths for the intra-domain segments, the direct L2 -> L2 edge for the
     inter-chip crossing.  Emits FlowRoutes and RouterTables identical to
     the flat `route` (tests pin this down) at O(domain) instead of
     O(fabric) table-build cost."""
     lrt = NOC.RoutingTable(NOC.fullerene_adjacency(with_level2=True))
-    by_layer: dict[int, list[CoreGroup]] = {}
-    for g in groups:
-        by_layer.setdefault(g.layer, []).append(g)
     tables = RouterTables(tables={})
     layer_flows: dict[int, list[NOC.FlowRoute]] = {}
-
-    last = max(by_layer)
-    for layer, srcs in sorted(by_layer.items()):
-        if layer == last:
-            continue
-        dst_cores = sorted({assignment[g.gid] for g in by_layer[layer + 1]})
+    for layer, srcs, dst_cores in _flow_targets(groups, assignment,
+                                                recurrent):
         flows = []
         for g in srcs:
             src_core = assignment[g.gid]

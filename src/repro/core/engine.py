@@ -34,6 +34,10 @@ arithmetic to the reference loop (DESIGN.md §7).  The engines:
   array engines agree bit-exactly; vs the interpretive reference the
   usual compiled-vs-reference contract applies (below).
 
+A recurrent layer (`ChipSimulator(..., recurrent=(li,))`) reads its
+forward spikes and then its own spikes of the last step, which ride the
+scan carry beside the LIF states; a chain's carry is the states alone.
+
 Both engines shard the batch across available devices with
 `shard_map` (batch axis, weights replicated) when the batch divides the
 device count.  Each `run_batch` enqueues one XLA program: the fused
@@ -90,9 +94,13 @@ class EngineTables:
     """Everything the traced step function closes over, in array form."""
 
     layers: tuple[LayerTables, ...]
-    flows: tuple[NOC.FlowTable | None, ...]   # flows[li]: layer li+1 -> li+2
+    # flows[li]: layer li+1 -> li+2, and -> li+1 itself when recurrent
+    flows: tuple[NOC.FlowTable | None, ...]
     n_active_cores: int
     nominal_sops_per_step: int
+    # back_hops[li]: a recurrent layer's per-flow hops beyond the tree to
+    # the next layer alone (its back-edge share), else None
+    back_hops: tuple[np.ndarray | None, ...]
 
 
 def lower_tables(sim: "ChipSimulator") -> EngineTables:
@@ -118,18 +126,21 @@ def lower_tables(sim: "ChipSimulator") -> EngineTables:
             slice_sizes=np.array([a.n_neurons for a in asn], np.float32),
             core_index=np.array([dense[a.core_id] for a in asn], np.int32),
             slice_onehot=onehot))
-    flows: list[NOC.FlowTable | None] = []
-    for li in range(len(sim.weights)):
-        if li + 1 < len(sim.weights):
-            flows.append(NOC.compile_flow_table(
-                sim._layer_routes[li + 1], sim.router,
-                n_nodes=sim.adj.shape[0], interconnect=sim.interconnect))
-        else:
-            flows.append(None)
+    def table(routes):
+        return NOC.compile_flow_table(routes, sim.router,
+                                      n_nodes=sim.adj.shape[0],
+                                      interconnect=sim.interconnect)
+
+    L = len(sim.weights)
+    flows = tuple(table(sim._layer_routes[li + 1])
+                  if li + 1 in sim._layer_routes else None
+                  for li in range(L))
     nominal = sum(lt.n_pre * lt.n_post for lt in layers)
-    return EngineTables(layers=tuple(layers), flows=tuple(flows),
+    return EngineTables(layers=tuple(layers), flows=flows,
                         n_active_cores=len(active),
-                        nominal_sops_per_step=nominal)
+                        nominal_sops_per_step=nominal,
+                        back_hops=tuple(sim._back_hops.get(li + 1)
+                                        for li in range(L)))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,7 +152,11 @@ class FusedLayerWeights:
     (`idx` int8 indexes + `cbw` per-column level values = words x scale);
     dense f32 fallback otherwise (float-only simulators).  Rows are
     padded to the 16-spike word boundary with zeros — bit-neutral, since
-    the padded spike bits are zero too.
+    the padded spike bits are zero too.  A recurrent layer's input is two
+    spike-word streams, the forward input's and its own last-step
+    spikes' (`fed_words` words), so its rows are padded per stream: the
+    fed-back rows start on a word, and the kernel takes the two word
+    runs concatenated, unchanged.
     """
 
     n_pre: int
@@ -153,6 +168,8 @@ class FusedLayerWeights:
     all_nonzero: bool = False      # every real weight element != 0: the
                                    # touch-count matmul collapses to the
                                    # per-row spike popcount (same ints)
+    fed_words: int = 0             # words of the fed-back stream, at the
+                                   # end of each input row; 0: not recurrent
 
     @property
     def codebook_mode(self) -> bool:
@@ -289,29 +306,41 @@ def _pick_engine_block(m: int, k: int, n: int, interpret: bool, *,
         f"engine='compiled'")
 
 
+def _pad_streams(rows: np.ndarray, widths: list[int]) -> np.ndarray:
+    """Zero-pad each run of `widths` rows to the 16-spike word boundary."""
+    parts, lo = [], 0
+    for n in widths:
+        pad = Z.spike_word_count(n) * Z.SPIKE_WORD_BITS - n
+        parts.append(np.pad(rows[lo:lo + n], ((0, pad), (0, 0))))
+        lo += n
+    return np.concatenate(parts)
+
+
 def lower_fused_weights(sim: "ChipSimulator") -> tuple[FusedLayerWeights, ...]:
     """Lower every layer to its fused-kernel weight operand."""
     out = []
     for li, w in enumerate(sim.weights):
         n_pre, n_post = int(w.shape[0]), int(w.shape[1])
-        kw = Z.spike_word_count(n_pre)
-        kp = kw * Z.SPIKE_WORD_BITS
+        # input streams: the forward spikes, then a recurrent layer's own
+        streams = ([n_pre - n_post, n_post] if li in sim.recurrent
+                   else [n_pre])
+        kw = sum(Z.spike_word_count(n) for n in streams)
+        fed = Z.spike_word_count(n_post) if li in sim.recurrent else 0
         nz = bool(np.all(np.asarray(w) != 0))
         cbk = _lower_codebook_layer(sim, li)
         if cbk is not None:
             idx, cbw = cbk
-            idx = np.pad(idx, ((0, kp - n_pre), (0, 0)))
             out.append(FusedLayerWeights(
                 n_pre=n_pre, n_post=n_post, kw=kw,
-                idx=jnp.asarray(idx), cbw=jnp.asarray(cbw), dense=None,
-                all_nonzero=nz))
+                idx=jnp.asarray(_pad_streams(idx, streams)),
+                cbw=jnp.asarray(cbw), dense=None, all_nonzero=nz,
+                fed_words=fed))
         else:
-            dense = np.pad(np.asarray(w, np.float32),
-                           ((0, kp - n_pre), (0, 0)))
+            dense = _pad_streams(np.asarray(w, np.float32), streams)
             out.append(FusedLayerWeights(
                 n_pre=n_pre, n_post=n_post, kw=kw,
                 idx=None, cbw=None, dense=jnp.asarray(dense),
-                all_nonzero=nz))
+                all_nonzero=nz, fed_words=fed))
     return tuple(out)
 
 
@@ -477,8 +506,10 @@ class _EngineBase:
         the profiler's clock beside the device ops: `snn.run_batch`
         (stats `call`, `batch`, `steps`) holds `snn.upload` (`bytes`),
         `snn.dispatch`, `snn.device_wait`, `snn.readback` (`transfers`,
-        `bytes`), `snn.noc_replay` (`flows`) and `snn.price`.  With no
-        profiler running a span costs about a microsecond.
+        `bytes`), `snn.noc_replay` (`flows`: the flows replayed;
+        `back_flows`: those of them that also reach their own layer)
+        and `snn.price`.  With no profiler running a span costs about a
+        microsecond.
         """
         self.calls += 1
         batch, steps = (tuple(np.shape(spike_trains)) + (0, 0))[:2]
@@ -539,13 +570,19 @@ class _EngineBase:
                          if "skip_words" in host else np.zeros(B))
         nominal = float(tbl.nominal_sops_per_step) * T
 
-        # exact per-flow NoC replay: counts are integers, pricing is f64
+        # exact per-flow NoC replay: counts are integers, pricing is f64;
+        # a recurrent layer's trees also reach its own cores, in the step
+        # its spikes fire; they are its next step's recurrent input
         with jax.profiler.TraceAnnotation(
                 "snn.noc_replay",
-                flows=sum(ft.n_flows for ft in tbl.flows if ft is not None)):
+                flows=sum(ft.n_flows for ft in tbl.flows if ft is not None),
+                back_flows=sum(len(bh) for bh in tbl.back_hops
+                               if bh is not None)):
             noc_hops = np.zeros(B)
             noc_pj = np.zeros(B)
             routed = np.zeros(B)
+            back_hops = np.zeros(B)
+            recurrent_sops = np.zeros(B)
             load = np.zeros((B, T, sim.adj.shape[0]))
             for li, ft in enumerate(tbl.flows):
                 if ft is None:
@@ -556,6 +593,10 @@ class _EngineBase:
                 noc_pj += e.sum(axis=1)
                 load += ld
                 routed += fired_core.sum(axis=(1, 2))
+                if tbl.back_hops[li] is not None:
+                    back_hops += (fired_core @ tbl.back_hops[li]).sum(axis=1)
+                    recurrent_sops += (fired_core[:, :-1].sum(axis=(1, 2))
+                                       * n_posts[li])
             contention = NOC.contention_cycles(
                 load.max(axis=2), core_wall, sim.router)  # (B, T)
             wall = (core_wall + contention).sum(axis=1)
@@ -600,6 +641,8 @@ class _EngineBase:
                     noc_contention_cycles=float(noc_contention[b]),
                     spike_words_skipped=float(skipped_words[b]),
                     weight_writes=float(writes_total[b]),
+                    recurrent_sops=float(recurrent_sops[b]),
+                    back_noc_hops=float(back_hops[b]),
                 )
                 reports.append(ChipReport(
                     steps=T, stats=acc,
@@ -650,28 +693,41 @@ class CompiledEngine(_EngineBase):
         # fault-free scan — same xs, same ops, bit-identical jaxpr
         drop = getattr(sim, "drop_plan", None)
 
-        def step(states, xs):
+        def layer_steps(states, fed, xs):
+            """One timestep of every layer; `fed` holds each recurrent
+            layer's last-step spikes (empty for a chain) -> (states, ys,
+            this step's spikes of the recurrent layers)."""
             spikes, t = xs if drop is not None else (xs, None)
             wall = jnp.zeros((n_active,), jnp.float32)
             nnzs, toucheds, fireds, skips = [], [], [], []
             fired_cores = {}
             new_states = []
+            new_fed = {}
             for li, w in enumerate(weights):
                 with jax.named_scope(f"snn_compiled_l{li + 1}"):
                     lt, slices, core_idx, onehot = layer_consts[li]
-                    nnz = jnp.sum(spikes != 0).astype(jnp.float32)
+                    # a recurrent layer reads its forward input, then
+                    # its own last-step spikes
+                    x = (jnp.concatenate([spikes, fed[li]]) if li in fed
+                         else spikes)
+                    nnz = jnp.sum(x != 0).astype(jnp.float32)
                     if trace_skips:
                         # ZSPE skip telemetry on the layer's input spikes —
                         # packs exactly like the fused engine's native
                         # empty-word counter, so the two agree bit-for-bit
-                        skips.append(Z.empty_spike_words(
-                            Z.pack_spike_words(spikes)).astype(jnp.float32))
+                        skip = Z.empty_spike_words(Z.pack_spike_words(spikes))
+                        if li in fed:
+                            skip = skip + Z.empty_spike_words(
+                                Z.pack_spike_words(fed[li]))
+                        skips.append(skip.astype(jnp.float32))
                     current = jnp.matmul(
-                        spikes, w, precision=Z.CURRENT_PRECISION)
+                        x, w, precision=Z.CURRENT_PRECISION)
                     st, out, touched = lif_step(
                         states[li], current, lif,
-                        touched=touch_mask(spikes, nonzero_w[li]))
+                        touched=touch_mask(x, nonzero_w[li]))
                     new_states.append(st)
+                    if li in fed:
+                        new_fed[li] = out
                     tsum = jnp.sum(touched).astype(jnp.float32)
                     # integer-exact per-core-slice touched counts: the cycle
                     # model ceils them, and exact ints cannot straddle a ceil
@@ -709,14 +765,28 @@ class CompiledEngine(_EngineBase):
             }
             if trace_skips:
                 ys["skip_words"] = jnp.stack(skips)
-            return tuple(new_states), ys
+            return tuple(new_states), ys, new_fed
+
+        def step(states, xs):            # a chain: the carry is the states
+            new_states, ys, _ = layer_steps(states, {}, xs)
+            return new_states, ys
+
+        def step_recurrent(carry, xs):   # (states, last recurrent spikes)
+            new_states, ys, fed = layer_steps(*carry, xs)
+            return (new_states, fed), ys
 
         if not self.plast.enabled:
             def one_sample(train):
                 states = tuple(init_state(int(w.shape[1])) for w in weights)
                 xs = (train if drop is None
                       else (train, jnp.arange(train.shape[0])))
-                _, ys = jax.lax.scan(step, states, xs)
+                if sim.recurrent:
+                    fed = {li: jnp.zeros((int(weights[li].shape[1]),),
+                                         jnp.float32)
+                           for li in sim.recurrent}
+                    _, ys = jax.lax.scan(step_recurrent, (states, fed), xs)
+                else:
+                    _, ys = jax.lax.scan(step, states, xs)
                 return ys
 
             def run(trains):                     # (B, T, n_in) f32
@@ -895,6 +965,11 @@ class ShardedEngine(_EngineBase):
 
     def __init__(self, sim: "ChipSimulator", shard: bool = True,
                  n_shards: int | None = None):
+        if sim.recurrent:
+            raise NotImplementedError(
+                "ShardedEngine runs chains only: a recurrent layer's "
+                "fed-back spikes would need their own cross-shard exchange "
+                "in the scan carry; use engine='compiled' or 'fused'")
         super().__init__(sim, shard=shard)
         max_node = max(a.core_id for a in sim.mapping.assignments)
         self.n_domains = (max_node // NOC.DOMAIN_STRIDE + 1
@@ -1371,6 +1446,33 @@ class FusedEngine(_EngineBase):
                 all_nonzero=lw.all_nonzero, block=block, interpret=interp,
                 name=name, **lif_kw)
 
+        def layer_counters(li, wall, out, tc, nnz_rows, ew):
+            """Layer li's counters from its kernel's outputs, over any
+            leading axes (..., B): -> (wall plus its cores' cycles, input
+            spikes, touched, fired, empty words, per-core fired and
+            touched counts)."""
+            lt, slices, core_idx, onehot = layer_consts[li]
+            nnz = nnz_rows[..., 0].astype(jnp.float32)     # (..., B)
+            ew = ew[..., 0]
+            tsum = jnp.sum(tc, axis=-1).astype(jnp.float32)
+            fired = jnp.sum(out, axis=-1)                  # (..., B)
+            # exact per-slice touched counts (tc is the 0/1 mask)
+            core_touched = tc.astype(jnp.float32) @ onehot  # (..., B, A)
+            core_cyc = cyc.timestep_cycles_array(
+                lt.n_pre, slices, nnz[..., None], core_touched,
+                sim.zero_skip, sim.partial_update)         # (..., B, A)
+            per_core = lambda c: jax.ops.segment_sum(  # noqa: E731
+                c, core_idx, num_segments=n_active)
+            for _ in range(core_cyc.ndim - 1):
+                per_core = jax.vmap(per_core)
+            wall = wall + per_core(core_cyc)
+            cores = {}
+            if has_flow[li] or traced:
+                cores[f"fired_core_{li}"] = out @ onehot
+            if traced:
+                cores[f"touched_core_{li}"] = core_touched
+            return wall, nnz, tsum, fired, ew.astype(jnp.float32), cores
+
         def step(states, xs):                # xs: (B, kw0) uint16 [+ t]
             from repro.core.neuron import LIFState
 
@@ -1382,30 +1484,16 @@ class FusedEngine(_EngineBase):
             new_states = []
             out = None
             for li, lw in enumerate(fused_w):
-                lt, slices, core_idx, onehot = layer_consts[li]
                 vo, eo, out, tc, nnz_rows, ew = layer_apply(
                     li, packed, states[li])
                 new_states.append(LIFState(v=vo, elapsed=eo))
-                nnz = nnz_rows[:, 0].astype(jnp.float32)       # (B,)
-                ew = ew[:, 0]
-                tsum = jnp.sum(tc, axis=-1).astype(jnp.float32)
-                fired = jnp.sum(out, axis=-1)                  # (B,)
-                # exact per-slice touched counts (tc is the 0/1 mask)
-                core_touched = tc.astype(jnp.float32) @ onehot  # (B, A)
-                core_cyc = cyc.timestep_cycles_array(
-                    lt.n_pre, slices, nnz[:, None], core_touched,
-                    sim.zero_skip, sim.partial_update)         # (B, A)
-                wall = wall + jax.vmap(
-                    lambda c: jax.ops.segment_sum(
-                        c, core_idx, num_segments=n_active))(core_cyc)
-                if has_flow[li] or traced:
-                    fired_cores[f"fired_core_{li}"] = out @ onehot
-                if traced:
-                    fired_cores[f"touched_core_{li}"] = core_touched
+                wall, nnz, tsum, fired, ew, cores = layer_counters(
+                    li, wall, out, tc, nnz_rows, ew)
+                fired_cores.update(cores)
                 nnzs.append(nnz)
                 toucheds.append(tsum)
                 fireds.append(fired)
-                skips.append(ew.astype(jnp.float32))
+                skips.append(ew)
                 # counters above are pre-drop; the next layer's spike
                 # words carry only the packets that survived the hops
                 nxt = (out * drop.mask(li, t)
@@ -1423,22 +1511,85 @@ class FusedEngine(_EngineBase):
             }
             return tuple(new_states), ys
 
-        def scan_trains(step_fn, carry, trains):  # (B, T, n_in) f32
+        def step_recurrent(carry, packed):   # (states, last spike words)
+            """One timestep of every layer, a recurrent layer taking its
+            forward words then its own last step's, each run starting on
+            a word.  A step emits only its kernels' outputs, as one int32
+            row block (`recurrent_counters` reads it after the scan): a
+            step of a 100-step scan runs a few device ops, not dozens."""
+            from repro.core.neuron import LIFState
+
+            states, fed = carry
+            new_states, new_fed, rows = [], {}, []
+            for li in range(len(fused_w)):
+                x = (jnp.concatenate([packed, fed[li]], axis=-1)
+                     if li in fed else packed)
+                vo, eo, out, tc, nnz_rows, ew = layer_apply(
+                    li, x, states[li])
+                new_states.append(LIFState(v=vo, elapsed=eo))
+                rows += [out.astype(jnp.int32), tc, nnz_rows, ew]
+                packed = Z.pack_spike_words(out)
+                if li in fed:
+                    new_fed[li] = packed
+            return (tuple(new_states), new_fed), jnp.concatenate(rows,
+                                                                 axis=-1)
+
+        def recurrent_counters(rows):        # (T, B, W) -> ys, T leading
+            wall = jnp.zeros(rows.shape[:2] + (n_active,), jnp.float32)
+            cols = [rows[..., lo:hi] for lo, hi in row_slices]
+            ys = {k: [] for k in ("nnz", "touched", "fired", "skip_words")}
+            for li in range(len(fused_w)):
+                out, tc, nnz_rows, ew = cols[4 * li:4 * li + 4]
+                out = out.astype(jnp.float32)
+                wall, *counts, cores = layer_counters(li, wall, out, tc,
+                                                      nnz_rows, ew)
+                for k, c in zip(ys, counts):
+                    ys[k].append(c)
+                ys.update(cores)
+            ys.update({k: jnp.stack(ys[k], axis=-1)
+                       for k in ("nnz", "touched", "fired", "skip_words")})
+            ys["wall"] = jnp.max(wall, axis=-1)
+            ys["out"] = out
+            return ys
+
+        # the row block's columns: per layer (out, touched, nnz, empty words)
+        row_slices, lo = [], 0
+        for lw in fused_w:
+            for width in (lw.n_post, lw.n_post, 1, 1):
+                row_slices.append((lo, lo + width))
+                lo += width
+
+        def scan_trains(step_fn, carry, trains,
+                        counters=None):  # (B, T, n_in) f32
             # packing is the program's first op: (T, B, kw0) uint16 words
             packed_t = jnp.swapaxes(Z.pack_spike_words(trains), 0, 1)
             xs = (packed_t if drop is None
                   else (packed_t, jnp.arange(packed_t.shape[0])))
             final, ys = jax.lax.scan(step_fn, carry, xs)
+            if counters is not None:
+                ys = counters(ys)
             ys = jax.tree_util.tree_map(lambda a: jnp.swapaxes(a, 0, 1), ys)
             return ys, final
 
         def zero_states(batch):
             return tuple(init_batch_state(batch, lw.n_post) for lw in fused_w)
 
-        if not self.plast.enabled:
+        if not self.plast.enabled and not sim.recurrent:
             def run(trains):                 # (B, T, n_in) f32
                 return scan_trains(step, zero_states(trains.shape[0]),
                                    trains)
+
+            return run
+
+        if not self.plast.enabled:
+            def run(trains):                 # (B, T, n_in) f32
+                B = trains.shape[0]
+                fed = {li: jnp.zeros((B, fused_w[li].fed_words), jnp.uint16)
+                       for li in sim.recurrent}
+                ys, (states, _) = scan_trains(
+                    step_recurrent, (zero_states(B), fed), trains,
+                    counters=recurrent_counters)
+                return ys, states
 
             return run
 

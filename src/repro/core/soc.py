@@ -190,13 +190,15 @@ def validate_capacity(layer_sizes: Sequence[int],
 
 def map_network(layer_sizes: Sequence[int],
                 neurons_per_core: int = E.NEURONS_PER_CORE,
-                strategy: str = "greedy", seed: int = 0) -> Mapping:
-    """Place a feed-forward SNN onto the 20 cores.
+                strategy: str = "greedy", seed: int = 0,
+                recurrent: Sequence[int] = ()) -> Mapping:
+    """Place an SNN onto the 20 cores.
 
     strategy "greedy" is the legacy contiguous layout (layers onto cores in
     id order, traffic-blind, no spreading).  Any other value is forwarded
     to the mapping compiler (repro.compiler.compile_network), e.g.
-    "anneal" — traffic-aware placement with simulated-annealing refinement.
+    "anneal" — traffic-aware placement with simulated-annealing refinement,
+    which also weighs the self-edges of the layer indices in `recurrent`.
 
     Layer 0 is the input population (not placed).  Raises ValueError when
     the network exceeds chip capacity.
@@ -206,8 +208,9 @@ def map_network(layer_sizes: Sequence[int],
         from repro import compiler as CC
 
         spec = CC.ChipSpec(neurons_per_core=neurons_per_core)
-        compiled = CC.compile_network(list(layer_sizes), spec,
-                                      strategy=strategy, seed=seed)
+        compiled = CC.compile_network(
+            CC.from_layer_sizes(layer_sizes, recurrent=recurrent), spec,
+            strategy=strategy, seed=seed)
         return compiled.to_soc_mapping()
     cores = list(NOC.core_ids())
     assignments: list[CoreAssignment] = []
@@ -326,6 +329,27 @@ def _reject_index_like(w, layer: int, quant_cfg: CodebookConfig | None) -> None:
                 f"from quant.quantize(), or the dequantized float weights")
 
 
+def _layer_sizes(weights, recurrent: Sequence[int]) -> list[int]:
+    """Population widths, input first, of weight layers that each feed
+    the next; a weight layer in `recurrent` holds (n_pre + n_post,
+    n_post) rows, its forward input's and then its own."""
+    sizes = [int(w.shape[1]) for w in weights]
+    n_in = int(weights[0].shape[0]) - (sizes[0] if 0 in recurrent else 0)
+    sizes = [n_in] + sizes
+    for li in recurrent:
+        if not 0 <= li < len(weights):
+            raise ValueError(f"recurrent layer {li} is not one of the "
+                             f"{len(weights)} weight layers")
+    for li, w in enumerate(weights):
+        want = sizes[li] + (sizes[li + 1] if li in recurrent else 0)
+        if int(w.shape[0]) != want or n_in <= 0:
+            kind = "recurrent " if li in recurrent else ""
+            raise ValueError(
+                f"{kind}weight layer {li} has {int(w.shape[0])} rows; its "
+                f"input needs {want} (layer sizes {sizes})")
+    return sizes
+
+
 @dataclasses.dataclass
 class StepStats:
     """Per-timestep accounting gathered by the functional simulator."""
@@ -341,6 +365,10 @@ class StepStats:
     noc_contention_cycles: float = 0.0  # M/M/1 bottleneck-router wait cycles
     spike_words_skipped: float = 0.0  # ZSPE word-scan skips (fused engine)
     weight_writes: float = 0.0       # plasticity register-index writes
+    recurrent_sops: float = 0.0      # performed SOPs of last-step spikes
+                                     # fed back into recurrent layers
+    back_noc_hops: float = 0.0       # of noc_hops, the recurrent trees'
+                                     # hops beyond the next layer's tree
 
     @property
     def sparsity(self) -> float:
@@ -377,8 +405,20 @@ class ChipReport:
 
 
 class ChipSimulator:
-    """Functional + energy simulation of the whole SoC for a feed-forward
-    SNN described by per-layer weight matrices.
+    """Functional + energy simulation of the whole SoC for an SNN
+    described by per-layer weight matrices.
+
+    Weight layer `li` feeds layer `li + 1`.  A weight layer listed in
+    `recurrent` also feeds its own last-step spikes back to itself:
+
+        I_t = W_in^T s_in[t] + W_rec^T s_out[t-1]        (s_out[-1] = 0)
+
+    and its weight is the one (n_pre + n_post, n_post) matrix a core
+    stores, forward rows first, under one codebook per core slice.  The
+    layer's spikes travel one multicast tree per source core, to the next
+    layer's cores and its own (its own core at zero hops), in the step
+    they fire, and count as input spikes, performed SOPs and touches of
+    the next step, like any other input spike.
 
     Three execution engines share one lowered mapping:
 
@@ -424,6 +464,7 @@ class ChipSimulator:
         trace=None,                            # telemetry.TraceConfig
         faults=None,                           # faults.FaultConfig
         plasticity=None,                       # plasticity.PlasticityConfig
+        recurrent: Sequence[int] = (),         # weight layers fed back
     ):
         from repro.core.neuron import LIFParams  # local import to avoid cycle
         from repro.core import quant as Q
@@ -468,8 +509,12 @@ class ChipSimulator:
             for li, w in enumerate(weights):
                 _reject_index_like(w, li, quant_cfg)
             self.weights = [jnp.asarray(w, jnp.float32) for w in weights]
-        sizes = [int(self.weights[0].shape[0])] + [int(w.shape[1]) for w in self.weights]
-        self.mapping = mapping or map_network(sizes, strategy=mapping_strategy)
+        self.recurrent = tuple(sorted({int(li) for li in recurrent}))
+        sizes = _layer_sizes(self.weights, self.recurrent)
+        self.n_in = sizes[0]
+        self.mapping = mapping or map_network(
+            sizes, strategy=mapping_strategy,
+            recurrent=[li + 1 for li in self.recurrent])
         self.quant_cfg = quant_cfg or CodebookConfig(n_levels=16, bit_width=8)
         self.geom = geometry or CoreGeometry(freq_hz=freq_hz)
         self.freq_hz = freq_hz
@@ -504,7 +549,7 @@ class ChipSimulator:
         self.routing = NOC.RoutingTable(self.adj)
         # routes are compiled ONCE from the mapping; each timestep only
         # replays them (no BFS in the simulation loop)
-        self._layer_routes = self._compile_layer_routes()
+        self._layer_routes, self._back_hops = self._compile_layer_routes()
         # a full LIFParams (e.g. the SNNConfig's, for train->deploy parity)
         # wins over the scalar threshold/leak conveniences
         self.lif = (dataclasses.replace(lif, partial_update=partial_update)
@@ -543,6 +588,12 @@ class ChipSimulator:
         from repro.core.plasticity import NULL_PLASTICITY
         self.plasticity = (plasticity if plasticity is not None
                            else NULL_PLASTICITY)
+        if self.recurrent and (self.plasticity.enabled
+                               or self.drop_plan is not None):
+            raise NotImplementedError(
+                "recurrent layers run without plasticity and without NoC "
+                "packet drop: those scan bodies are chain-only until they "
+                "merge into one layer-step body")
         self.write_model = E.WeightWriteModel()
         self._plast_tables = None  # lazy lower_plasticity_tables result
         self._ref_learned = None   # reference-engine learned indexes
@@ -645,17 +696,36 @@ class ChipSimulator:
             self.mapping, qweights=self.qweights, lif=self.lif,
             layer_cfgs=self._layer_qcfg, default_cfg=self.quant_cfg)
 
-    def _compile_layer_routes(self) -> dict[int, list[NOC.FlowRoute]]:
-        """Static routes for every layer->layer transition in the mapping:
-        the spikes layer `li` fires travel from each of its cores to every
-        core holding layer `li+1`."""
-        routes: dict[int, list[NOC.FlowRoute]] = {}
-        for li in range(1, len(self.weights)):
+    def _compile_layer_routes(self) -> tuple[dict[int, list[NOC.FlowRoute]],
+                                             dict[int, np.ndarray]]:
+        """Static routes of every layer that fires into another, keyed by
+        the firing layer: the spikes layer `li` fires travel from each of
+        its cores to every core holding layer `li+1` and, for a recurrent
+        layer, to every core holding `li` itself (a core's own delivery
+        crosses no link), one multicast tree per source core.  With them,
+        per recurrent layer, each tree's back-edge share of hops: those
+        it has beyond the tree to layer `li+1` alone."""
+        def flows(li: int, dst_layers: list[int]) -> list[NOC.FlowRoute]:
             srcs = [a.core_id for a in self.mapping.cores_of_layer(li)]
-            dsts = sorted({a.core_id for a in self.mapping.cores_of_layer(li + 1)})
-            routes[li] = [NOC.compile_flow(self.routing, s, dsts, self._level2)
-                          for s in srcs]
-        return routes
+            dsts = sorted({a.core_id for d in dst_layers
+                           for a in self.mapping.cores_of_layer(d)})
+            return [NOC.compile_flow(self.routing, s, dsts, self._level2)
+                    for s in srcs]
+
+        L = len(self.weights)
+        routes: dict[int, list[NOC.FlowRoute]] = {}
+        back_hops: dict[int, np.ndarray] = {}
+        for li in range(1, L + 1):
+            forward = [li + 1] if li < L else []
+            if li - 1 in self.recurrent:
+                routes[li] = flows(li, forward + [li])
+                alone = ([f.hops for f in flows(li, forward)] if forward
+                         else 0)
+                back_hops[li] = np.array([f.hops for f in routes[li]],
+                                         np.int64) - alone
+            elif forward:
+                routes[li] = flows(li, forward)
+        return routes, back_hops
 
     # -- execution ----------------------------------------------------------
 
@@ -761,6 +831,9 @@ class ChipSimulator:
 
         T = int(spike_train.shape[0])
         states = [init_state(int(w.shape[1])) for w in self.weights]
+        # last step's spikes of each recurrent layer (s_out[-1] = 0)
+        fed_back = {li: jnp.zeros((int(self.weights[li].shape[1]),),
+                                  jnp.float32) for li in self.recurrent}
         out_counts = jnp.zeros((int(self.weights[-1].shape[1]),), jnp.float32)
         acc = StepStats()
         wall = 0.0
@@ -797,17 +870,26 @@ class ChipSimulator:
                     w = self.weights[li]
                     nzw = self.nonzero_weights[li]
                 n_pre, n_post = int(w.shape[0]), int(w.shape[1])
-                nnz = float(jnp.sum(spikes != 0))
+                # a recurrent layer's input: the forward spikes, then its
+                # own spikes of the last step (two spike-word streams)
+                streams = ([spikes, fed_back[li]] if li in fed_back
+                           else [spikes])
+                x = jnp.concatenate(streams) if li in fed_back else spikes
+                nnz = float(jnp.sum(x != 0))
                 acc.spikes_in += nnz
+                if li in fed_back:
+                    acc.recurrent_sops += float(
+                        jnp.sum(fed_back[li] != 0)) * n_post
                 if traced:
                     rec_nnz[-1].append(nnz)
                     if trace_skips:
-                        rec_skip[-1].append(float(Z.empty_spike_words(
-                            Z.pack_spike_words(spikes))))
-                current = jnp.matmul(spikes, w, precision=Z.CURRENT_PRECISION)
+                        rec_skip[-1].append(sum(
+                            float(Z.empty_spike_words(Z.pack_spike_words(s)))
+                            for s in streams))
+                current = jnp.matmul(x, w, precision=Z.CURRENT_PRECISION)
                 st, out, touched = lif_step(
                     states[li], current, self.lif,
-                    touched=touch_mask(spikes, nzw))
+                    touched=touch_mask(x, nzw))
                 states[li] = st
                 acc.nominal_sops += n_pre * n_post
                 acc.performed_sops += nnz * n_post
@@ -853,19 +935,22 @@ class ChipSimulator:
                 # precompiled flow (replay, no BFS here) — source-exact,
                 # so where a spike fires from changes what it costs
                 fired = float(out_np.sum())
-                if fired > 0 and li + 1 < len(self.weights):
-                    routes = self._layer_routes[li + 1]
-                    fired_per_src = [
-                        int(out_np[a.neuron_lo:a.neuron_hi].sum())
-                        for a in asn]
+                fired_per_src = [int(out_np[a.neuron_lo:a.neuron_hi].sum())
+                                 for a in asn]
+                if li in fed_back:
+                    fed_back[li] = out
+                if fired > 0 and li + 1 in self._layer_routes:
                     rep = NOC.replay_flows(
-                        list(zip(routes, fired_per_src)), self.router,
-                        n_nodes=self.adj.shape[0],
+                        list(zip(self._layer_routes[li + 1], fired_per_src)),
+                        self.router, n_nodes=self.adj.shape[0],
                         interconnect=self.interconnect)
                     acc.noc_hops += rep.total_hops
                     acc.noc_energy_pj += rep.energy_pj
                     acc.spikes_routed += fired
                     step_load += rep.router_load
+                    if li + 1 in self._back_hops:
+                        acc.back_noc_hops += float(
+                            np.dot(fired_per_src, self._back_hops[li + 1]))
                 # per-hop packet drop (faults.DropPlan): fired counters
                 # above are pre-drop (the source committed the energy);
                 # what the next layer integrates is post-drop

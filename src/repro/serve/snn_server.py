@@ -71,6 +71,15 @@ from repro.telemetry.metrics import MetricsRegistry
 __all__ = ["SnnRequest", "SnnServer", "Tenant"]
 
 
+def _input_width(sim) -> int:
+    """The width of the trains a simulator takes: `ChipSimulator.n_in`
+    (a recurrent first layer's weight also holds its fed-back rows), or
+    the first weight's rows for a stand-in that models only what the
+    server calls (the benchmark's open-loop driver tests serve such a
+    fake simulator)."""
+    return int(getattr(sim, "n_in", sim.weights[0].shape[0]))
+
+
 class Tenant:
     """One registered model: a compiled simulator plus residency state."""
 
@@ -81,7 +90,7 @@ class Tenant:
                              "(engine='compiled' or 'fused')")
         self.name = name
         self.sim = sim
-        self.n_in = int(sim.weights[0].shape[0])
+        self.n_in = _input_width(sim)
         self.n_out = int(sim.weights[-1].shape[1])
         self.core_ids = frozenset(sim.mapping.active_core_ids())
         self.resident = False
@@ -89,7 +98,7 @@ class Tenant:
             if degraded_sim.engine not in ("compiled", "fused"):
                 raise ValueError(
                     "degraded_sim must be an array-engine simulator")
-            din = int(degraded_sim.weights[0].shape[0])
+            din = _input_width(degraded_sim)
             dout = int(degraded_sim.weights[-1].shape[1])
             if (din, dout) != (self.n_in, self.n_out):
                 raise ValueError(

@@ -86,6 +86,9 @@ class ChipTrace:
     contention_cycles: np.ndarray  # (B, T) M/M/1 bottleneck wait
     noc_hops: np.ndarray          # (B, T, S) hops charged to source slice
     noc_pj: np.ndarray            # (B, T, S) NoC pJ charged to source slice
+    back_noc_hops: np.ndarray     # (B, T, S) of noc_hops, the back-edges'
+                                  # share of recurrent layers' trees
+    recurrent_sops: np.ndarray    # (B, T, L) SOPs of fed-back spikes
 
     @property
     def batch(self) -> int:
@@ -125,6 +128,8 @@ class ChipTrace:
         assert self.contention_cycles.shape == (B, T)
         assert self.noc_pj.shape == (B, T, S)
         assert self.noc_hops.shape == (B, T, S)
+        assert self.back_noc_hops.shape == (B, T, S)
+        assert self.recurrent_sops.shape == (B, T, L)
         assert len(self.slice_layer) == S and len(self.slice_core) == S
 
     @staticmethod
@@ -187,7 +192,12 @@ def build_trace(sim: "ChipSimulator", fired, touched, nnz,
     None.  `weight_writes` is the plasticity register-write count per
     layer-step (raw counter only — its stage cycles are priced in-scan
     per core, and its energy by `WeightWriteModel` in the report).  All
-    derived series are computed here — identically for every engine.
+    derived series are computed here — identically for every engine.  A
+    recurrent layer's trees also reach its own cores in the step its
+    spikes fire (`back_noc_hops`: the hops beyond the tree to the next
+    layer alone, inside `noc_hops`), and its spikes are its recurrent
+    input one step later (`recurrent_sops`, inside the SOPs that `nnz`
+    prices).
     """
     fired = np.asarray(fired, np.float64)
     touched = np.asarray(touched, np.float64)
@@ -209,6 +219,8 @@ def build_trace(sim: "ChipSimulator", fired, touched, nnz,
     cycles = np.zeros((B, T, S))
     noc_pj = np.zeros((B, T, S))
     noc_hops = np.zeros((B, T, S))
+    back_hops = np.zeros((B, T, S))
+    recurrent_sops = np.zeros((B, T, L))
     router_load = np.zeros((B, T, n_nodes))
     for li in range(L):
         sel = np.flatnonzero(slice_layer == li)
@@ -217,14 +229,19 @@ def build_trace(sim: "ChipSimulator", fired, touched, nnz,
         upd = (np.ceil(touched[..., sel]) if sim.partial_update
                else np.broadcast_to(slice_n, (B, T, len(sel))))
         cycles[..., sel] = np.maximum(np.maximum(load, syn), upd) + depth
-        if li + 1 < len(sim.weights):
+        fired_li = fired[..., sel]                        # (B, T, F)
+        if li in sim.recurrent:
+            recurrent_sops[:, 1:, li] = (fired_li[:, :-1].sum(axis=-1)
+                                         * sim.weights[li].shape[1])
+        if li + 1 in sim._layer_routes:
             ft = NOC.compile_flow_table(
                 sim._layer_routes[li + 1], sim.router, n_nodes=n_nodes,
                 interconnect=sim.interconnect)
-            fired_li = fired[..., sel]                    # (B, T, F)
             noc_pj[..., sel] = fired_li * ft.energy_pj
             noc_hops[..., sel] = fired_li * ft.hops.astype(np.float64)
             router_load += fired_li @ ft.router_load.astype(np.float64)
+        if li + 1 in sim._back_hops:
+            back_hops[..., sel] = fired_li * sim._back_hops[li + 1]
 
     core_cycles = np.zeros((B, T, len(active)))
     np.add.at(core_cycles.transpose(2, 0, 1), core_index,
@@ -243,6 +260,7 @@ def build_trace(sim: "ChipSimulator", fired, touched, nnz,
         weight_writes=weight_writes,
         cycles=cycles, core_cycles=core_cycles, core_wall=core_wall,
         router_load=router_load, contention_cycles=contention,
-        noc_hops=noc_hops, noc_pj=noc_pj)
+        noc_hops=noc_hops, noc_pj=noc_pj, back_noc_hops=back_hops,
+        recurrent_sops=recurrent_sops)
     trace.validate()
     return trace

@@ -258,3 +258,116 @@ def test_measured_spike_rates_feed_placement():
     graph = COMP.from_weights(w, spike_rates=rates)
     cn = COMP.compile_network(graph)
     assert cn.net.spike_rates == tuple(rates)
+
+
+# ---------------------------------------------------------------------------
+# recurrent self-edges: back-edges placed, routed and priced
+# ---------------------------------------------------------------------------
+
+RECURRENT_SIZES = (48, 64, 10)
+
+
+def test_back_edge_flows_cover_every_slice_pair():
+    net = COMP.from_layer_sizes(RECURRENT_SIZES, recurrent=[1])
+    assert net.layers[1].fan_in == 48 + 64 and net.layers[2].fan_in == 64
+    assert net.self_edges() == ((1, net.spike_rates[1]),)
+    cn = COMP.compile_network(net, verify=True)
+    hidden = [g for g in cn.groups if g.layer == 1]
+    assert len(hidden) > 1
+    flows = COMP.group_traffic(net, cn.groups)
+    pairs = {(s, d) for s, d, _ in flows}
+    for s in hidden:
+        for d in hidden:
+            assert ((s.gid, d.gid) in pairs) == (s.gid != d.gid)
+    # one tree per slice carries its spikes to every readout core and
+    # back to every hidden slice, its own core included; the readout
+    # fires into no other layer
+    cores = {cn.core_of_group(g.gid) for g in hidden}
+    readout = {cn.core_of_group(g.gid) for g in cn.groups if g.layer == 2}
+    trees = cn.routed.layer_flows[1]
+    assert [f.src for f in trees] == [cn.core_of_group(g.gid) for g in hidden]
+    assert all(list(f.dsts) == sorted(cores | readout) for f in trees)
+    assert sorted(cn.routed.layer_flows) == [1] and cn.cost > 0
+    chain = COMP.compile_network(list(RECURRENT_SIZES), verify=True)
+    chain_readout = {chain.core_of_group(g.gid) for g in chain.groups
+                     if g.layer == 2}
+    assert all(list(f.dsts) == sorted(chain_readout)
+               for f in chain.routed.layer_flows[1])
+
+
+def test_back_edge_routes_agree_flat_and_hierarchical():
+    spec = COMP.ChipSpec(neurons_per_core=8, max_domains=2)
+    net = COMP.from_layer_sizes((40, 200, 10), recurrent=[1])
+    hier = COMP.compile_network(net, spec, hierarchical=True)
+    flat = R_route(hier, net)
+    assert hier.n_domains_used == 2
+    assert hier.routed.layer_flows == flat.layer_flows
+    assert hier.routed.total_l2_hops() > 0
+    COMP.verify_roundtrip(hier.routed)
+
+
+def R_route(compiled, net):
+    from repro.compiler import route as R
+
+    return R.route(compiled.groups, compiled.placement.assignment,
+                   compiled.plan.adjacency, compiled.plan.level2_nodes,
+                   recurrent=net.recurrent)
+
+
+def test_on_core_delivery_costs_zero_hops():
+    """A recurrent layer held by one core delivers its spikes back to
+    that core over no link: its tree is the path to the readout alone,
+    and the back-edge's share of hops is zero."""
+    spec = COMP.ChipSpec()
+    net = COMP.from_layer_sizes((30, 40, 10), recurrent=[1])
+    cn = COMP.compile_network(net, spec, spread=False, verify=True)
+    (tree,) = cn.routed.layer_flows[1]
+    (readout,) = {cn.core_of_group(g.gid) for g in cn.groups if g.layer == 2}
+    assert readout != tree.src and set(tree.dsts) == {tree.src, readout}
+    alone = NOC.compile_flow(cn.routed.routing_table(), tree.src, [readout])
+    assert set(tree.links) == set(alone.links) and tree.hops == alone.hops > 0
+    sim = ChipSimulator([np.ones((70, 40), np.float32) * 0.3,
+                         np.ones((40, 10), np.float32) * 0.2],
+                        engine="compiled", mapping=cn.to_soc_mapping(),
+                        recurrent=(0,))
+    assert sim.compiled_engine().tables.back_hops[0].tolist() == [0]
+    x = np.zeros((1, 4, 30), np.float32)
+    x[:, :, :5] = 1.0
+    _, [rep] = sim.run_batch(x)
+    assert rep.stats.recurrent_sops > 0 and rep.stats.spikes_routed > 0
+    assert rep.stats.back_noc_hops == 0.0
+    assert rep.stats.noc_hops == rep.stats.spikes_routed * alone.hops
+
+
+def test_back_edge_share_of_a_tree_by_hand():
+    """Each hidden slice's one tree reaches every readout and hidden
+    core; its back-edge share is the links the tree has beyond the tree
+    to the readout alone, and each fired spike is routed once."""
+    net = COMP.from_layer_sizes(RECURRENT_SIZES, recurrent=[1])
+    cn = COMP.compile_network(net, verify=True)
+    from repro.telemetry.trace import TraceConfig
+
+    sim = ChipSimulator([np.full((48 + 64, 64), 0.05, np.float32),
+                         np.full((64, 10), 0.2, np.float32)],
+                        engine="compiled", mapping=cn.to_soc_mapping(),
+                        recurrent=(0,), trace=TraceConfig(enabled=True))
+    rt = sim.routing
+    hidden = [a.core_id for a in sim.mapping.cores_of_layer(1)]
+    readout = sorted({a.core_id for a in sim.mapping.cores_of_layer(2)})
+
+    def tree_links(src, dsts):
+        return {link for d in dsts
+                for link in zip(rt.path(src, d)[:-1], rt.path(src, d)[1:])}
+
+    want = [len(tree_links(src, hidden + readout))
+            - len(tree_links(src, readout)) for src in hidden]
+    assert sim._back_hops[1].tolist() == want and sum(want) > 0
+    x = np.zeros((1, 3, 48), np.float32)
+    x[:, 0, :] = 1.0
+    _, [rep] = sim.run_batch(x)
+    tr = sim.last_trace()
+    sel = np.asarray(tr.slice_layer) == 0
+    assert np.asarray(tr.slice_core)[sel].tolist() == hidden
+    per_slice = tr.fired[0][:, sel].sum(axis=0)
+    assert rep.stats.spikes_routed == per_slice.sum() > 0
+    assert rep.stats.back_noc_hops == float(per_slice @ np.array(want))

@@ -77,3 +77,34 @@ def test_broadcast_forks_share_prefix_links():
     assert fr.mode == "broadcast"
     assert fr.hops < per_dst
     assert fr.hops == len(fr.links)
+
+
+def test_self_flow_replay_and_contention_by_hand():
+    """A flow whose destinations include its own source core (a recurrent
+    layer's back-edge): the source's delivery crosses no link, so the
+    replay charges only the links to the other cores."""
+    adj = NOC.fullerene_adjacency()
+    rt = NOC.RoutingTable(adj)
+    cores = [int(c) for c in NOC.core_ids()]
+    src = cores[0]
+    other = max(cores, key=lambda c: len(rt.path(src, c)))
+    fr = NOC.compile_flow(rt, src, [src, other])
+    path = rt.path(src, other)
+    n = len(path) - 1                           # the farthest core: 4 links
+    assert fr.mode == "broadcast" and fr.hops == n >= 4
+    assert set(fr.links) == set(zip(path[:-1], path[1:]))
+    table = NOC.compile_flow_table([fr])
+    hops, pj, load = NOC.replay_flows_exact(table, np.array([[5.0], [0.0]]))
+    assert hops.tolist() == [5.0 * n, 0.0]
+    assert pj[0] == 5 * (n * NOC.RouterParams().e_hop_bcast_pj) \
+        and pj[1] == 0
+    want = np.zeros(NOC.N_NODES)
+    want[path[:-1]] = 5.0                      # each sender on the path
+    np.testing.assert_array_equal(load[0], want)
+    # one step: the busiest router holds 5 spikes at 0.4 spikes/cycle
+    cyc = NOC.contention_cycles(load.max(axis=-1), np.array([50.0, 50.0]))
+    assert cyc[0] == 12.5 + 12.5 ** 2 / 50.0 and cyc[1] == 0.0
+    # a core's delivery to itself alone crosses nothing
+    own = NOC.compile_flow_table([NOC.compile_flow(rt, src, [src])])
+    h, e, ld = NOC.replay_flows_exact(own, np.array([9.0]))
+    assert h == 0.0 and e == 0.0 and not ld.any()

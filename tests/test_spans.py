@@ -110,7 +110,24 @@ def test_run_batch_emits_each_phase_once_per_call(rec, monkeypatch, engine):
     flows = sum(ft.n_flows for ft in eng.tables.flows if ft is not None)
     assert flows > 0
     assert [s["stats"] for s in inner if s["name"] == "snn.noc_replay"] \
-        == [{"flows": flows}] * 2
+        == [{"flows": flows, "back_flows": 0}] * 2
+
+
+@pytest.mark.parametrize("engine", ["compiled", "fused"])
+def test_noc_replay_counts_back_flows(rec, engine):
+    """A recurrent layer's flows, one tree per core slice to the readout
+    and back to the layer, ride as the replay span's `back_flows` stat
+    too."""
+    w_in, w_out = _net()
+    w_rec = np.random.default_rng(9).normal(0, 0.5, (16, 16))
+    sim = ChipSimulator([np.concatenate([w_in, w_rec]).astype(np.float32),
+                         w_out], engine=engine, recurrent=(0,))
+    sim.run_batch((np.random.default_rng(1).random((2, 3, 8)) < 0.4
+                   ).astype(np.float32))
+    slices = len(sim.mapping.cores_of_layer(1))
+    assert slices > 1
+    assert [s["stats"] for s in rec.spans if s["name"] == "snn.noc_replay"] \
+        == [{"flows": slices, "back_flows": slices}]
 
 
 def test_device_array_input_uploads_nothing(rec):

@@ -93,3 +93,24 @@ def test_compiled_engine_compiles_for_v5e(one_chip, arch_sim, batch):
     used = (mem.generated_code_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes + mem.temp_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("batch", [32, 12])
+def test_recurrent_kernel_compiles_for_v5e(one_chip, batch):
+    """The SHD network's recurrent layer (700 + 1024 rows -> 1024) as the
+    fused engine feeds it: 44 input words, then 64 words of the layer's
+    own last-step spikes, with the tile the engine picks."""
+    kw = Z.spike_word_count(700) + Z.spike_word_count(1024)
+    k, n = kw * Z.SPIKE_WORD_BITS, 1024
+    block = _pick_engine_block(batch, k, n, interpret=False, codebook=True,
+                               all_nonzero=True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = fused_timestep_codebook.lower(
+        sds((batch, kw), jnp.uint16), sds((k, n), jnp.int8),
+        sds((16, n), jnp.float32), sds((batch, n), jnp.float32),
+        sds((batch, n), jnp.int32), gather=False, all_nonzero=True,
+        block=block, interpret=False, name="snn_fused_l1")
+    assert "tpu_custom_call" in lowered.compile().as_text()
