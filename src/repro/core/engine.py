@@ -40,9 +40,11 @@ scan carry beside the LIF states; a chain's carry is the states alone.
 
 Both engines shard the batch across available devices with
 `shard_map` (batch axis, weights replicated) when the batch divides the
-device count.  Each `run_batch` enqueues one XLA program: the fused
-engine builds its zero membrane state and packs the input spikes inside
-it, so nothing but the trains crosses from the host.
+device count.  Each `run_batch` enqueues the engine's XLA program and,
+behind it, `_host_slab`, which packs the output counts and every counter
+the host prices into one f32 array, read back in one transfer.  The
+fused engine builds its zero membrane state and packs the input spikes
+inside its program, so nothing but the trains crosses from the host.
 
 The bit-identical-spikes contract is validated on the CPU backend,
 where XLA's reduction order for the (B, n) @ (n, m) batched matmul
@@ -62,6 +64,7 @@ compiled/fused/reference sweep.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import TYPE_CHECKING
 
 import jax
@@ -356,6 +359,24 @@ def _shard_map(fn, mesh, in_specs, out_specs):
 # shared execution / pricing stage
 # ---------------------------------------------------------------------------
 
+# counters the host prices from every body that emits them (`writes`:
+# plastic; `skip_words`: fused, or traced with `skip_words`)
+HOST_COUNTERS = ("writes", "nnz", "touched", "wall", "skip_words")
+
+
+@jax.jit
+def _host_slab(out: jax.Array, counters: list[jax.Array]) -> jax.Array:
+    """(B, T, n_out) output spikes and the priced counters (B leading)
+    -> one (B, X) f32 slab: the output counts, then each counter
+    flattened per sample.  Every counter is f32 and holds integers or
+    f32 cycle sums, so the host's f64 view is exact."""
+    B = out.shape[0]
+    parts = [jnp.sum(out, axis=1)] + [c.reshape(B, -1) for c in counters]
+    assert all(p.dtype == jnp.float32 for p in parts), \
+        [p.dtype for p in parts]
+    return jnp.concatenate(parts, axis=1)
+
+
 class _EngineBase:
     """Lowering + execution + pricing shared by both array engines.
 
@@ -492,24 +513,28 @@ class _EngineBase:
         return self._dispatch(sharded, trains, learned)
 
     def run_batch(self, spike_trains: jax.Array, learned=None
-                  ) -> tuple[jax.Array, list["ChipReport"]]:
-        """(B, T, n_in) spike trains -> ((B, n_out) counts, per-sample
-        ChipReports).
+                  ) -> tuple[np.ndarray, list["ChipReport"]]:
+        """(B, T, n_in) spike trains -> ((B, n_out) f32 counts as a host
+        array, per-sample ChipReports).
 
-        NoC pricing happens here, on the host, in float64: the scan emits
-        integer-exact per-core fired counts (`fired_core_{li}`) and the
-        per-flow replay (`noc.replay_flows_exact`) + the M/M/1 contention
-        term (`noc.contention_cycles`) run the same f64 arithmetic the
+        The output counts and every counter the host prices come back in
+        one transfer: a small jitted program (`_host_slab`) packs them
+        into one (B, X) f32 slab behind the engine's program, and the
+        host splits it by `_readback_keys`.  NoC pricing happens here, on
+        the host, in float64: the scan emits integer-exact per-core fired
+        counts (`fired_core_{li}`) and the per-flow replay
+        (`noc.replay_flows_exact`) + the M/M/1 contention term
+        (`noc.contention_cycles`) run the same f64 arithmetic the
         interpretive reference does, so the engines cannot drift from it.
 
         Each phase runs under a `jax.profiler.TraceAnnotation` span, on
         the profiler's clock beside the device ops: `snn.run_batch`
         (stats `call`, `batch`, `steps`) holds `snn.upload` (`bytes`),
-        `snn.dispatch`, `snn.device_wait`, `snn.readback` (`transfers`,
-        `bytes`), `snn.noc_replay` (`flows`: the flows replayed;
-        `back_flows`: those of them that also reach their own layer)
-        and `snn.price`.  With no profiler running a span costs about a
-        microsecond.
+        `snn.dispatch`, `snn.device_wait` (launches the slab's program
+        and waits for it), `snn.readback` (`transfers`: 1, `bytes`),
+        `snn.noc_replay` (`flows`: the flows replayed; `back_flows`:
+        those of them that also reach their own layer) and `snn.price`.
+        With no profiler running a span costs about a microsecond.
         """
         self.calls += 1
         batch, steps = (tuple(np.shape(spike_trains)) + (0, 0))[:2]
@@ -518,19 +543,24 @@ class _EngineBase:
                 steps=int(steps)):
             return self._run_batch(spike_trains, learned)
 
-    def _run_batch(self, spike_trains, learned):
-        from repro.core.soc import ChipReport, StepStats
+    def _readback_keys(self, ys: dict) -> list[str]:
+        """The counters the host prices, in the slab's order after the
+        output counts: those of `HOST_COUNTERS` the body emits, the
+        per-core fired counts of each layer with flows (of every layer
+        under trace), and under trace the per-core touched counts."""
+        keys = [k for k in HOST_COUNTERS if k in ys]
+        keys += [f"fired_core_{li}" for li, ft in enumerate(self.tables.flows)
+                 if ft is not None or self.trace.enabled]
+        if self.trace.enabled:
+            keys += [f"touched_core_{li}"
+                     for li in range(len(self.tables.layers))]
+        return keys
 
-        sim = self.sim
-        tbl = self.tables
+    def _run_batch(self, spike_trains, learned):
         ys = self.run_raw(spike_trains, learned=learned)
         # injected transient dispatch faults fire HERE: the scan ran, the
         # readback is lost (mid-flight), so a retry can succeed
-        sim._consume_transient_fault()
-        B, T = int(spike_trains.shape[0]), int(spike_trains.shape[1])
-        out_counts = jnp.sum(ys["out"], axis=1)
-        with jax.profiler.TraceAnnotation("snn.device_wait"):
-            jax.block_until_ready((ys, out_counts))
+        self.sim._consume_transient_fault()
 
         if self.plast.enabled:
             # learned state is stashed per engine (B leading, global
@@ -544,19 +574,33 @@ class _EngineBase:
                     ys.pop(f"elig_{li}") if pt is not None else None
                     for li, pt in enumerate(self.plast_tables)]
 
-        # every counter the host prices, one array at a time
-        L = len(tbl.layers)
-        read = [k for k in ("writes", "nnz", "touched", "wall", "skip_words")
-                if k in ys]
-        read += [f"fired_core_{li}" for li, ft in enumerate(tbl.flows)
-                 if ft is not None or self.trace.enabled]
-        if self.trace.enabled:
-            read += [f"touched_core_{li}" for li in range(L)]
+        keys = self._readback_keys(ys)
+        with jax.profiler.TraceAnnotation("snn.device_wait"):
+            slab = _host_slab(ys["out"], [ys[k] for k in keys])
+            jax.block_until_ready(slab)
         with jax.profiler.TraceAnnotation(
-                "snn.readback", transfers=len(read),
-                bytes=sum(int(ys[k].nbytes) for k in read)):
-            host = {k: np.asarray(ys[k], np.float64) for k in read}
+                "snn.readback", transfers=1, bytes=int(slab.nbytes)):
+            flat = np.asarray(slab, np.float64)
+        B = flat.shape[0]
+        n_out = int(ys["out"].shape[-1])
+        host, lo = {}, n_out
+        for k in keys:                       # views of the one host array
+            shape = tuple(ys[k].shape[1:])
+            n = math.prod(shape)
+            host[k] = flat[:, lo:lo + n].reshape((B,) + shape)
+            lo += n
+        return flat[:, :n_out].astype(np.float32), self._reports(host)
 
+    def _reports(self, host: dict) -> list["ChipReport"]:
+        """Per-sample ChipReports from the priced counters on the host
+        (`_readback_keys`, each (B, T, ...) float64); under trace also
+        sets `last_trace`."""
+        from repro.core.soc import ChipReport, StepStats
+
+        sim = self.sim
+        tbl = self.tables
+        L = len(tbl.layers)
+        B, T = host["nnz"].shape[:2]
         writes = host.get("writes")                      # (B, T, L)
         writes_total = (writes.sum(axis=(1, 2)) if writes is not None
                         else np.zeros(B))
@@ -652,10 +696,10 @@ class _EngineBase:
                     riscv_energy_pj=float(priced["riscv_pj"][b]),
                     wall_cycles=float(wall[b]), freq_hz=sim.freq_hz,
                     write_energy_pj=float(priced["write_pj"][b])))
-        return out_counts, reports
+        return reports
 
     def run(self, spike_train: jax.Array,
-            learned=None) -> tuple[jax.Array, "ChipReport"]:
+            learned=None) -> tuple[np.ndarray, "ChipReport"]:
         """Single-sample convenience wrapper (batch of 1)."""
         counts, reports = self.run_batch(jnp.asarray(spike_train)[None],
                                          learned=learned)

@@ -742,8 +742,9 @@ class ChipSimulator:
                 f"injected transient fault at dispatch {i}")
 
     def run(self, spike_train: jax.Array,
-            learned=None) -> tuple[jax.Array, ChipReport]:
-        """spike_train: (T, n_in) binary.  Returns (out_spike_counts, report).
+            learned=None) -> tuple[np.ndarray | jax.Array, ChipReport]:
+        """spike_train: (T, n_in) binary.  Returns (out_spike_counts, report);
+        the array engines' counts are a host array.
 
         Dispatches to the engine selected at construction; all engines
         return identical spikes and matching accounting.  `learned`
@@ -755,10 +756,11 @@ class ChipSimulator:
         return self.run_reference(spike_train, learned=learned)
 
     def run_batch(self, spike_trains: jax.Array,
-                  learned=None) -> tuple[jax.Array, list[ChipReport]]:
-        """spike_trains: (B, T, n_in).  Returns ((B, n_out) counts, one
-        ChipReport per sample).  The array engines run the batch as a
-        single XLA program; the reference engine loops samples.
+                  learned=None) -> tuple[np.ndarray, list[ChipReport]]:
+        """spike_trains: (B, T, n_in).  Returns ((B, n_out) f32 counts as a
+        host array, one ChipReport per sample).  The array engines run the
+        batch as a single XLA program and read the counts back in the same
+        transfer as the counters; the reference engine loops samples.
 
         With plasticity enabled every sample starts from the same initial
         indexes (broadcast `learned`, or per-sample (B, ...) entries) and
@@ -797,7 +799,7 @@ class ChipSimulator:
                 None if eligs[0][li] is None
                 else jnp.stack([e[li] for e in eligs])
                 for li in range(len(eligs[0]))])
-        return jnp.stack(outs), reports
+        return np.asarray(jnp.stack(outs)), reports
 
     def run_reference(self, spike_train: jax.Array,
                       learned=None) -> tuple[jax.Array, ChipReport]:

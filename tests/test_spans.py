@@ -103,6 +103,9 @@ def test_run_batch_emits_each_phase_once_per_call(rec, monkeypatch, engine):
     # every device array the call converts is read inside snn.readback
     assert {span for span, _ in counting.reads} == {"snn.readback"}
     readbacks = [s["stats"] for s in inner if s["name"] == "snn.readback"]
+    # one conversion a call: the slab of counts and counters
+    assert len(counting.reads) == 2
+    assert [r["transfers"] for r in readbacks] == [1, 1]
     per_call = len(counting.reads) // 2
     assert readbacks == [{"transfers": per_call,
                           "bytes": sum(b for _, b in counting.reads[:per_call])
