@@ -6,19 +6,23 @@ the host boundary.  That is the right shape for a *reference* model and
 the wrong shape for throughput: the chip's dataflow is static per
 (mapping, T), so the whole inference can be one XLA program.
 
-Two array engines share one lowering (`lower_tables`) and one
+Three array engines share one lowering (`lower_tables`) and one
 pricing/report stage (`_EngineBase.run_batch` -> `energy.price_batched`,
 the same function the interpretive reference uses, so the paths cannot
-drift).  NoC accounting is source-exact: the scan emits integer per-core
-fired counts (`out @ slice_onehot`) and the host replays them against
-the per-flow `noc.FlowTable` vectors in float64, adding the bottleneck
-router's M/M/1 `contention_cycles` to the wall clock — identical
-arithmetic to the reference loop (DESIGN.md §7).  The engines:
+drift).  NoC accounting is source-exact: the programs emit integer
+per-core fired counts (`out @ slice_onehot`) and the host replays them
+against the per-flow `noc.FlowTable` vectors in float64, adding the
+bottleneck router's M/M/1 `contention_cycles` to the wall clock —
+identical arithmetic to the reference loop (DESIGN.md §7).  Each engine
+keeps one layer-step body: a recurrent layer, packet drop, trace
+counters and a learnable layer are branches in it, chosen at trace time
+from the simulator's configuration.  The engines:
 
 * `CompiledEngine` (PR 2) — the mapping, cycle and NoC models lowered to
   arrays; per layer-step a dense `spikes @ w` against dequantized f32
   weight constants plus a separate `lif_step`.  `jax.lax.scan` over T
-  under `jax.vmap` over the batch.
+  under `jax.vmap` over the batch; `layer_steps` computes the counters
+  inside the step.
 
 * `FusedEngine` (PR 4) — the chip's actual pipeline shape: each
   layer-step is ONE Pallas kernel (kernels/fused_timestep.py) that scans
@@ -29,22 +33,33 @@ arithmetic to the reference loop (DESIGN.md §7).  The engines:
   smaller), and applies the partial-update LIF step in the same VMEM
   pass.  Spikes stay packed between layers; per-row empty-word counts
   are emitted as ZSPE skip telemetry (`StepStats.spike_words_skipped`).
+  Its inference step emits only the kernels' outputs, one int32 row
+  block a step, and `layer_counters` computes every counter from the
+  stacked blocks after the scan.  A plastic run's step keeps its
+  counters in the scan, through the same `layer_counters`: its
+  learnable layers' weights are scan state.
   In interpret mode the kernel runs one (B, K, N) tile whose float
   program is expression-identical to the compiled engine's, so the two
   array engines agree bit-exactly; vs the interpretive reference the
   usual compiled-vs-reference contract applies (below).
 
+* `ShardedEngine` (PR 8) — the cores axis across devices in one
+  `shard_map` program, spikes exchanged as packed words between
+  layer-steps; chains only, with a plastic body of its own.
+
 A recurrent layer (`ChipSimulator(..., recurrent=(li,))`) reads its
 forward spikes and then its own spikes of the last step, which ride the
-scan carry beside the LIF states; a chain's carry is the states alone.
+scan carry beside the LIF states; a chain without plasticity carries the
+states alone.
 
-Both engines shard the batch across available devices with
-`shard_map` (batch axis, weights replicated) when the batch divides the
-device count.  Each `run_batch` enqueues the engine's XLA program and,
-behind it, `_host_slab`, which packs the output counts and every counter
-the host prices into one f32 array, read back in one transfer.  The
-fused engine builds its zero membrane state and packs the input spikes
-inside its program, so nothing but the trains crosses from the host.
+The compiled and fused engines shard the batch across available devices
+with `shard_map` (batch axis, weights replicated) when the batch divides
+the device count.  Each `run_batch` enqueues the engine's XLA program
+and, behind it, `_host_slab`, which packs the output counts and every
+counter the host prices into one f32 array, read back in one transfer.
+The fused engine builds its zero membrane state and packs the input
+spikes inside its program, so nothing but the trains crosses from the
+host.
 
 The bit-identical-spikes contract is validated on the CPU backend,
 where XLA's reduction order for the (B, n) @ (n, m) batched matmul
@@ -74,8 +89,8 @@ import numpy as np
 from repro.core import energy as E
 from repro.core import noc as NOC
 from repro.core import zspe as Z
-from repro.core.neuron import (init_batch_state, init_state, lif_step,
-                                touch_mask)
+from repro.core.neuron import (LIFState, init_batch_state, init_state,
+                                lif_step, touch_mask)
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard (soc -> engine)
     from repro.core.soc import ChipReport, ChipSimulator
@@ -718,6 +733,8 @@ class CompiledEngine(_EngineBase):
     """
 
     def _build_run(self):
+        from repro.core import plasticity as PLC
+
         sim = self.sim
         tbl = self.tables
         weights = tuple(sim.weights)
@@ -736,17 +753,27 @@ class CompiledEngine(_EngineBase):
         # per-hop packet drop (faults.DropPlan); None lowers the exact
         # fault-free scan — same xs, same ops, bit-identical jaxpr
         drop = getattr(sim, "drop_plan", None)
+        # on-chip learning: the learnable layers' codebook indexes and
+        # traces are scan state; none when plasticity is off
+        plast = self.plast
+        cbws = {li: jnp.asarray(pt[1])
+                for li, pt in enumerate(self.plast_tables) if pt is not None}
+        reward = plast.mode == "reward"
 
-        def layer_steps(states, fed, xs):
-            """One timestep of every layer; `fed` holds each recurrent
-            layer's last-step spikes (empty for a chain) -> (states, ys,
-            this step's spikes of the recurrent layers)."""
+        def layer_steps(carry, xs):
+            """One timestep of every layer.  The carry holds the LIF
+            states, each recurrent layer's last-step spikes (`fed`) and
+            each learnable layer's (indexes, pre trace, post trace,
+            eligibility) (`learn`); the last two are empty for a chain
+            without plasticity, whose carry is then the states alone."""
+            states, fed, learn = carry
             spikes, t = xs if drop is not None else (xs, None)
             wall = jnp.zeros((n_active,), jnp.float32)
-            nnzs, toucheds, fireds, skips = [], [], [], []
+            nnzs, toucheds, fireds, skips, wr = [], [], [], [], []
             fired_cores = {}
             new_states = []
             new_fed = {}
+            new_learn = {}
             for li, w in enumerate(weights):
                 with jax.named_scope(f"snn_compiled_l{li + 1}"):
                     lt, slices, core_idx, onehot = layer_consts[li]
@@ -754,6 +781,13 @@ class CompiledEngine(_EngineBase):
                     # its own last-step spikes
                     x = (jnp.concatenate([spikes, fed[li]]) if li in fed
                          else spikes)
+                    nzw = nonzero_w[li]
+                    if li in learn:
+                        # live weights from the carried indexes — the
+                        # chip's SPEs dequantizing the current registers
+                        pidx, xpre, xpost, elig = learn[li]
+                        w = PLC.dequant_indices(pidx, cbws[li])
+                        nzw = (w != 0).astype(jnp.float32)
                     nnz = jnp.sum(x != 0).astype(jnp.float32)
                     if trace_skips:
                         # ZSPE skip telemetry on the layer's input spikes —
@@ -768,7 +802,7 @@ class CompiledEngine(_EngineBase):
                         x, w, precision=Z.CURRENT_PRECISION)
                     st, out, touched = lif_step(
                         states[li], current, lif,
-                        touched=touch_mask(x, nonzero_w[li]))
+                        touched=touch_mask(x, nzw))
                     new_states.append(st)
                     if li in fed:
                         new_fed[li] = out
@@ -777,9 +811,26 @@ class CompiledEngine(_EngineBase):
                     # model ceils them, and exact ints cannot straddle a ceil
                     # boundary between f32 (here) and f64 (reference)
                     core_touched = touched.astype(jnp.float32) @ onehot
+                    core_writes = None
+                    writes_l = jnp.float32(0.0)
+                    if li in learn:
+                        if reward:
+                            xp, xq, e = PLC.elig_step(
+                                plast, x, out, xpre, xpost, elig)
+                            new_learn[li] = (pidx, xp, xq, e)
+                        else:
+                            ni, xp, xq, changed = PLC.stdp_step(
+                                plast, x, out, xpre, xpost, pidx, cbws[li])
+                            new_learn[li] = (ni, xp, xq, elig)
+                            # integer-exact per-post write counts ->
+                            # per-core plasticity-stage occupancy + energy
+                            col_ch = jnp.sum(changed, axis=0
+                                             ).astype(jnp.float32)
+                            core_writes = col_ch @ onehot
+                            writes_l = jnp.sum(col_ch)
                     core_cyc = cyc.timestep_cycles_array(
                         lt.n_pre, slices, nnz, core_touched,
-                        sim.zero_skip, sim.partial_update)
+                        sim.zero_skip, sim.partial_update, writes=core_writes)
                     wall = wall + jax.ops.segment_sum(
                         core_cyc, core_idx, num_segments=n_active)
                     fired = jnp.sum(out).astype(jnp.float32)
@@ -792,6 +843,7 @@ class CompiledEngine(_EngineBase):
                     nnzs.append(nnz)
                     toucheds.append(tsum)
                     fireds.append(fired)
+                    wr.append(writes_l)
                     # fired counters above are pre-drop (the source fired and
                     # committed the energy); the next layer integrates what
                     # survived the hops
@@ -809,148 +861,32 @@ class CompiledEngine(_EngineBase):
             }
             if trace_skips:
                 ys["skip_words"] = jnp.stack(skips)
-            return tuple(new_states), ys, new_fed
+            if plast.enabled:
+                ys["writes"] = jnp.stack(wr)
+            return (tuple(new_states), new_fed, new_learn), ys
 
-        def step(states, xs):            # a chain: the carry is the states
-            new_states, ys, _ = layer_steps(states, {}, xs)
-            return new_states, ys
-
-        def step_recurrent(carry, xs):   # (states, last recurrent spikes)
-            new_states, ys, fed = layer_steps(*carry, xs)
-            return (new_states, fed), ys
-
-        if not self.plast.enabled:
-            def one_sample(train):
-                states = tuple(init_state(int(w.shape[1])) for w in weights)
-                xs = (train if drop is None
-                      else (train, jnp.arange(train.shape[0])))
-                if sim.recurrent:
-                    fed = {li: jnp.zeros((int(weights[li].shape[1]),),
-                                         jnp.float32)
-                           for li in sim.recurrent}
-                    _, ys = jax.lax.scan(step_recurrent, (states, fed), xs)
-                else:
-                    _, ys = jax.lax.scan(step, states, xs)
-                return ys
-
-            def run(trains):                     # (B, T, n_in) f32
-                return jax.vmap(one_sample)(trains)
-
-            return run
-
-        # ---- plasticity path: codebook indexes + traces are scan state ----
-        from repro.core import plasticity as PLC
-
-        plast = self.plast
-        cbws = [None if pt is None else jnp.asarray(pt[1])
-                for pt in self.plast_tables]
-        reward = plast.mode == "reward"
-
-        def step_plast(carry, xs):
-            states, pidx, xpre, xpost, elig = carry
-            spikes, t = xs if drop is not None else (xs, None)
-            wall = jnp.zeros((n_active,), jnp.float32)
-            nnzs, toucheds, fireds, skips, wr = [], [], [], [], []
-            fired_cores = {}
-            new_states = []
-            nidx, nxpre, nxpost, nelig = (list(pidx), list(xpre),
-                                          list(xpost), list(elig))
-            for li in range(len(weights)):
-                lt, slices, core_idx, onehot = layer_consts[li]
-                learns = cbws[li] is not None
-                if learns:
-                    # live weights from the carried indexes — the chip's
-                    # SPEs dequantizing the current register state
-                    w = PLC.dequant_indices(pidx[li], cbws[li])
-                    nzw = (w != 0).astype(jnp.float32)
-                else:
-                    w = weights[li]
-                    nzw = nonzero_w[li]
-                nnz = jnp.sum(spikes != 0).astype(jnp.float32)
-                if trace_skips:
-                    skips.append(Z.empty_spike_words(
-                        Z.pack_spike_words(spikes)).astype(jnp.float32))
-                current = jnp.matmul(spikes, w, precision=Z.CURRENT_PRECISION)
-                st, out, touched = lif_step(
-                    states[li], current, lif,
-                    touched=touch_mask(spikes, nzw))
-                new_states.append(st)
-                tsum = jnp.sum(touched).astype(jnp.float32)
-                core_touched = touched.astype(jnp.float32) @ onehot
-                core_writes = None
-                writes_l = jnp.float32(0.0)
-                if learns:
-                    if reward:
-                        xp, xq, e = PLC.elig_step(
-                            plast, spikes, out, xpre[li], xpost[li],
-                            elig[li])
-                        nxpre[li], nxpost[li], nelig[li] = xp, xq, e
-                    else:
-                        ni, xp, xq, changed = PLC.stdp_step(
-                            plast, spikes, out, xpre[li], xpost[li],
-                            pidx[li], cbws[li])
-                        nidx[li], nxpre[li], nxpost[li] = ni, xp, xq
-                        # integer-exact per-post write counts -> per-core
-                        # plasticity-stage occupancy + priced energy
-                        col_ch = jnp.sum(changed, axis=0).astype(jnp.float32)
-                        core_writes = col_ch @ onehot
-                        writes_l = jnp.sum(col_ch)
-                core_cyc = cyc.timestep_cycles_array(
-                    lt.n_pre, slices, nnz, core_touched,
-                    sim.zero_skip, sim.partial_update, writes=core_writes)
-                wall = wall + jax.ops.segment_sum(
-                    core_cyc, core_idx, num_segments=n_active)
-                fired = jnp.sum(out).astype(jnp.float32)
-                if has_flow[li] or traced:
-                    fired_cores[f"fired_core_{li}"] = out @ onehot
-                if traced:
-                    fired_cores[f"touched_core_{li}"] = core_touched
-                nnzs.append(nnz)
-                toucheds.append(tsum)
-                fireds.append(fired)
-                wr.append(writes_l)
-                if drop is not None and drop.keep_p[li] is not None:
-                    spikes = out * drop.mask(li, t)
-                else:
-                    spikes = out
-            ys = {
-                "nnz": jnp.stack(nnzs),
-                "touched": jnp.stack(toucheds),
-                "fired": jnp.stack(fireds),
-                "writes": jnp.stack(wr),
-                "wall": jnp.max(wall),
-                "out": spikes,
-                **fired_cores,
-            }
-            if trace_skips:
-                ys["skip_words"] = jnp.stack(skips)
-            return (tuple(new_states), nidx, nxpre, nxpost, nelig), ys
-
-        def one_sample(train, idx0):
+        def one_sample(train, idx0=None):
             states = tuple(init_state(int(w.shape[1])) for w in weights)
-            xpre0 = [None if c is None else
-                     jnp.zeros((int(weights[li].shape[0]),), jnp.float32)
-                     for li, c in enumerate(cbws)]
-            xpost0 = [None if c is None else
-                      jnp.zeros((int(weights[li].shape[1]),), jnp.float32)
-                      for li, c in enumerate(cbws)]
-            elig0 = [jnp.zeros(weights[li].shape, jnp.float32)
-                     if (c is not None and reward) else None
-                     for li, c in enumerate(cbws)]
+            fed = {li: jnp.zeros((int(weights[li].shape[1]),), jnp.float32)
+                   for li in sim.recurrent}
+            learn = {li: (idx0[li],
+                          jnp.zeros((int(weights[li].shape[0]),), jnp.float32),
+                          jnp.zeros((int(weights[li].shape[1]),), jnp.float32),
+                          jnp.zeros(weights[li].shape, jnp.float32)
+                          if reward else None)
+                     for li in cbws}
             xs = (train if drop is None
                   else (train, jnp.arange(train.shape[0])))
-            carry = (states, list(idx0), xpre0, xpost0, elig0)
-            final, ys = jax.lax.scan(step_plast, carry, xs)
-            _, fidx, _, _, felig = final
-            for li, c in enumerate(cbws):
-                if c is not None:
-                    ys[f"learned_idx_{li}"] = fidx[li]
-                    if reward:
-                        ys[f"elig_{li}"] = felig[li]
+            (_, _, learn), ys = jax.lax.scan(
+                layer_steps, (states, fed, learn), xs)
+            for li, (idx, _, _, elig) in learn.items():
+                ys[f"learned_idx_{li}"] = idx
+                if reward:
+                    ys[f"elig_{li}"] = elig
             return ys
 
-        def run(trains, idx0):               # (B, T, n_in) f32, [B-led idx]
-            return jax.vmap(one_sample)(trains, idx0)
+        def run(*args):          # (B, T, n_in) f32 [, per-layer B-led idx]
+            return jax.vmap(one_sample)(*args)
 
         return run
 
@@ -1490,11 +1426,11 @@ class FusedEngine(_EngineBase):
                 all_nonzero=lw.all_nonzero, block=block, interpret=interp,
                 name=name, **lif_kw)
 
-        def layer_counters(li, wall, out, tc, nnz_rows, ew):
-            """Layer li's counters from its kernel's outputs, over any
-            leading axes (..., B): -> (wall plus its cores' cycles, input
-            spikes, touched, fired, empty words, per-core fired and
-            touched counts)."""
+        def layer_counters(li, wall, out, tc, nnz_rows, ew, writes=None):
+            """Layer li's counters from its kernel's outputs (or the
+            plastic body's, `writes` its per-neuron index writes), over
+            any leading axes (..., B): -> (wall plus its cores' cycles,
+            {counter: (..., B)}, per-core fired and touched counts)."""
             lt, slices, core_idx, onehot = layer_consts[li]
             nnz = nnz_rows[..., 0].astype(jnp.float32)     # (..., B)
             ew = ew[..., 0]
@@ -1504,7 +1440,8 @@ class FusedEngine(_EngineBase):
             core_touched = tc.astype(jnp.float32) @ onehot  # (..., B, A)
             core_cyc = cyc.timestep_cycles_array(
                 lt.n_pre, slices, nnz[..., None], core_touched,
-                sim.zero_skip, sim.partial_update)         # (..., B, A)
+                sim.zero_skip, sim.partial_update,
+                writes=None if writes is None else writes @ onehot)
             per_core = lambda c: jax.ops.segment_sum(  # noqa: E731
                 c, core_idx, num_segments=n_active)
             for _ in range(core_cyc.ndim - 1):
@@ -1515,55 +1452,35 @@ class FusedEngine(_EngineBase):
                 cores[f"fired_core_{li}"] = out @ onehot
             if traced:
                 cores[f"touched_core_{li}"] = core_touched
-            return wall, nnz, tsum, fired, ew.astype(jnp.float32), cores
+            counts = {"nnz": nnz, "touched": tsum, "fired": fired,
+                      "skip_words": ew.astype(jnp.float32)}
+            if writes is not None:
+                counts["writes"] = jnp.sum(writes, axis=-1)
+            return wall, counts, cores
 
-        def step(states, xs):                # xs: (B, kw0) uint16 [+ t]
-            from repro.core.neuron import LIFState
+        def stack_counters(wall, out, layers):
+            """Per-layer (counts, per-core counts) -> ys: each counter
+            stacked over the layers (last axis), the wall as its busiest
+            core's, the last layer's spikes."""
+            ys = {}
+            for _, cores in layers:
+                ys.update(cores)
+            for k in layers[0][0]:
+                ys[k] = jnp.stack([counts[k] for counts, _ in layers],
+                                  axis=-1)
+            ys["wall"] = jnp.max(wall, axis=-1)
+            ys["out"] = out
+            return ys
 
-            packed, t = xs if drop is not None else (xs, None)
-            B = packed.shape[0]
-            wall = jnp.zeros((B, n_active), jnp.float32)
-            nnzs, toucheds, fireds, skips = [], [], [], []
-            fired_cores = {}
-            new_states = []
-            out = None
-            for li, lw in enumerate(fused_w):
-                vo, eo, out, tc, nnz_rows, ew = layer_apply(
-                    li, packed, states[li])
-                new_states.append(LIFState(v=vo, elapsed=eo))
-                wall, nnz, tsum, fired, ew, cores = layer_counters(
-                    li, wall, out, tc, nnz_rows, ew)
-                fired_cores.update(cores)
-                nnzs.append(nnz)
-                toucheds.append(tsum)
-                fireds.append(fired)
-                skips.append(ew)
-                # counters above are pre-drop; the next layer's spike
-                # words carry only the packets that survived the hops
-                nxt = (out * drop.mask(li, t)
-                       if drop is not None and drop.keep_p[li] is not None
-                       else out)
-                packed = Z.pack_spike_words(nxt)   # next layer's spike words
-            ys = {
-                "nnz": jnp.stack(nnzs, axis=-1),               # (B, L)
-                "touched": jnp.stack(toucheds, axis=-1),
-                "fired": jnp.stack(fireds, axis=-1),
-                "skip_words": jnp.stack(skips, axis=-1),
-                "wall": jnp.max(wall, axis=-1),                # (B,)
-                "out": out,                                    # (B, n_out)
-                **fired_cores,
-            }
-            return tuple(new_states), ys
-
-        def step_recurrent(carry, packed):   # (states, last spike words)
+        def step(carry, xs):   # (states, last spike words), (B, kw0) [+ t]
             """One timestep of every layer, a recurrent layer taking its
             forward words then its own last step's, each run starting on
             a word.  A step emits only its kernels' outputs, as one int32
-            row block (`recurrent_counters` reads it after the scan): a
-            step of a 100-step scan runs a few device ops, not dozens."""
-            from repro.core.neuron import LIFState
-
+            row block, and `counters` reads them after the scan: a step
+            of a 100-step scan runs a few device ops, not dozens.  A
+            chain carries no spike words (`fed` is empty)."""
             states, fed = carry
+            packed, t = xs if drop is not None else (xs, None)
             new_states, new_fed, rows = [], {}, []
             for li in range(len(fused_w)):
                 x = (jnp.concatenate([packed, fed[li]], axis=-1)
@@ -1572,29 +1489,27 @@ class FusedEngine(_EngineBase):
                     li, x, states[li])
                 new_states.append(LIFState(v=vo, elapsed=eo))
                 rows += [out.astype(jnp.int32), tc, nnz_rows, ew]
+                # the rows are pre-drop; the next layer's spike words
+                # carry only the packets that survived the hops
+                if drop is not None and drop.keep_p[li] is not None:
+                    out = out * drop.mask(li, t)
                 packed = Z.pack_spike_words(out)
                 if li in fed:
                     new_fed[li] = packed
             return (tuple(new_states), new_fed), jnp.concatenate(rows,
                                                                  axis=-1)
 
-        def recurrent_counters(rows):        # (T, B, W) -> ys, T leading
+        def counters(rows):                  # (T, B, W) -> ys, T leading
             wall = jnp.zeros(rows.shape[:2] + (n_active,), jnp.float32)
             cols = [rows[..., lo:hi] for lo, hi in row_slices]
-            ys = {k: [] for k in ("nnz", "touched", "fired", "skip_words")}
+            layers = []
             for li in range(len(fused_w)):
                 out, tc, nnz_rows, ew = cols[4 * li:4 * li + 4]
                 out = out.astype(jnp.float32)
-                wall, *counts, cores = layer_counters(li, wall, out, tc,
-                                                      nnz_rows, ew)
-                for k, c in zip(ys, counts):
-                    ys[k].append(c)
-                ys.update(cores)
-            ys.update({k: jnp.stack(ys[k], axis=-1)
-                       for k in ("nnz", "touched", "fired", "skip_words")})
-            ys["wall"] = jnp.max(wall, axis=-1)
-            ys["out"] = out
-            return ys
+                wall, counts, cores = layer_counters(li, wall, out, tc,
+                                                     nnz_rows, ew)
+                layers.append((counts, cores))
+            return stack_counters(wall, out, layers)
 
         # the row block's columns: per layer (out, touched, nnz, empty words)
         row_slices, lo = [], 0
@@ -1603,37 +1518,27 @@ class FusedEngine(_EngineBase):
                 row_slices.append((lo, lo + width))
                 lo += width
 
-        def scan_trains(step_fn, carry, trains,
-                        counters=None):  # (B, T, n_in) f32
+        def scan_trains(step_fn, carry, trains):  # (B, T, n_in) f32
             # packing is the program's first op: (T, B, kw0) uint16 words
             packed_t = jnp.swapaxes(Z.pack_spike_words(trains), 0, 1)
             xs = (packed_t if drop is None
                   else (packed_t, jnp.arange(packed_t.shape[0])))
-            final, ys = jax.lax.scan(step_fn, carry, xs)
-            if counters is not None:
-                ys = counters(ys)
-            ys = jax.tree_util.tree_map(lambda a: jnp.swapaxes(a, 0, 1), ys)
-            return ys, final
+            return jax.lax.scan(step_fn, carry, xs)   # T leading
+
+        def batch_major(ys):
+            return jax.tree_util.tree_map(lambda a: jnp.swapaxes(a, 0, 1), ys)
 
         def zero_states(batch):
             return tuple(init_batch_state(batch, lw.n_post) for lw in fused_w)
-
-        if not self.plast.enabled and not sim.recurrent:
-            def run(trains):                 # (B, T, n_in) f32
-                return scan_trains(step, zero_states(trains.shape[0]),
-                                   trains)
-
-            return run
 
         if not self.plast.enabled:
             def run(trains):                 # (B, T, n_in) f32
                 B = trains.shape[0]
                 fed = {li: jnp.zeros((B, fused_w[li].fed_words), jnp.uint16)
                        for li in sim.recurrent}
-                ys, (states, _) = scan_trains(
-                    step_recurrent, (zero_states(B), fed), trains,
-                    counters=recurrent_counters)
-                return ys, states
+                (states, _), rows = scan_trains(
+                    step, (zero_states(B), fed), trains)
+                return batch_major(counters(rows)), states
 
             return run
 
@@ -1645,7 +1550,8 @@ class FusedEngine(_EngineBase):
         # matmul -> elementwise lif_step) are the batch-native form of
         # exactly what the compiled engine traces per sample under vmap,
         # so the two engines stay bit-identical at word-aligned widths.
-        # Frozen layers keep the fused kernel.
+        # Frozen layers keep the fused kernel.  The counters stay in the
+        # scan, beside the per-step index writes they price.
         from repro.core import plasticity as PLC
 
         plast = self.plast
@@ -1654,28 +1560,19 @@ class FusedEngine(_EngineBase):
         reward = plast.mode == "reward"
 
         def step_plast(carry, xs):
-            from repro.core.neuron import LIFState
-
             states, pidx, xpre, xpost, elig = carry
             packed, t = xs if drop is not None else (xs, None)
             B = packed.shape[0]
             wall = jnp.zeros((B, n_active), jnp.float32)
-            nnzs, toucheds, fireds, skips, wr = [], [], [], [], []
-            fired_cores = {}
-            new_states = []
+            new_states, layers = [], []
             nidx, nxpre, nxpost, nelig = (list(pidx), list(xpre),
                                           list(xpost), list(elig))
-            out = None
-            for li, lw in enumerate(fused_w):
-                lt, slices, core_idx, onehot = layer_consts[li]
+            for li in range(len(fused_w)):
+                writes = None
                 if cbws[li] is None:
                     vo, eo, out, tc, nnz_rows, ew = layer_apply(
                         li, packed, states[li])
                     new_states.append(LIFState(v=vo, elapsed=eo))
-                    nnz = nnz_rows[:, 0].astype(jnp.float32)   # (B,)
-                    ew = ew[:, 0]
-                    core_writes = None
-                    writes_l = jnp.zeros((B,), jnp.float32)
                 else:
                     s = Z.unpack_spike_words(packed)           # (B, kp)
                     w = PLC.dequant_indices(pidx[li], cbws[li])
@@ -1686,56 +1583,29 @@ class FusedEngine(_EngineBase):
                     st, out, tc = lif_step(states[li], current, lif,
                                            touched=tm)
                     new_states.append(st)
-                    nnz = jnp.sum(s != 0, axis=-1).astype(jnp.float32)
-                    ew = Z.empty_spike_words(packed)
+                    nnz_rows = jnp.sum(s != 0, axis=-1, keepdims=True)
+                    ew = Z.empty_spike_words(packed)[:, None]
                     if reward:
-                        xp, xq, e = PLC.elig_step(
+                        nxpre[li], nxpost[li], nelig[li] = PLC.elig_step(
                             plast, s, out, xpre[li], xpost[li], elig[li])
-                        nxpre[li], nxpost[li], nelig[li] = xp, xq, e
-                        core_writes = None
-                        writes_l = jnp.zeros((B,), jnp.float32)
                     else:
-                        ni, xp, xq, changed = PLC.stdp_step(
-                            plast, s, out, xpre[li], xpost[li],
-                            pidx[li], cbws[li])
-                        nidx[li], nxpre[li], nxpost[li] = ni, xp, xq
-                        col_ch = jnp.sum(changed, axis=-2
+                        nidx[li], nxpre[li], nxpost[li], changed = \
+                            PLC.stdp_step(plast, s, out, xpre[li],
+                                          xpost[li], pidx[li], cbws[li])
+                        # integer-exact per-post write counts -> per-core
+                        # plasticity-stage occupancy + priced energy
+                        writes = jnp.sum(changed, axis=-2
                                          ).astype(jnp.float32)  # (B, N)
-                        core_writes = col_ch @ onehot           # (B, A)
-                        writes_l = jnp.sum(col_ch, axis=-1)     # (B,)
-                tsum = jnp.sum(tc, axis=-1).astype(jnp.float32)
-                fired = jnp.sum(out, axis=-1)
-                core_touched = tc.astype(jnp.float32) @ onehot
-                core_cyc = cyc.timestep_cycles_array(
-                    lt.n_pre, slices, nnz[:, None], core_touched,
-                    sim.zero_skip, sim.partial_update, writes=core_writes)
-                wall = wall + jax.vmap(
-                    lambda c: jax.ops.segment_sum(
-                        c, core_idx, num_segments=n_active))(core_cyc)
-                if has_flow[li] or traced:
-                    fired_cores[f"fired_core_{li}"] = out @ onehot
-                if traced:
-                    fired_cores[f"touched_core_{li}"] = core_touched
-                nnzs.append(nnz)
-                toucheds.append(tsum)
-                fireds.append(fired)
-                skips.append(ew.astype(jnp.float32))
-                wr.append(writes_l)
+                wall, counts, cores = layer_counters(
+                    li, wall, out, tc, nnz_rows, ew, writes=writes)
+                counts.setdefault("writes", jnp.zeros((B,), jnp.float32))
+                layers.append((counts, cores))
                 nxt = (out * drop.mask(li, t)
                        if drop is not None and drop.keep_p[li] is not None
                        else out)
                 packed = Z.pack_spike_words(nxt)
-            ys = {
-                "nnz": jnp.stack(nnzs, axis=-1),               # (B, L)
-                "touched": jnp.stack(toucheds, axis=-1),
-                "fired": jnp.stack(fireds, axis=-1),
-                "skip_words": jnp.stack(skips, axis=-1),
-                "writes": jnp.stack(wr, axis=-1),
-                "wall": jnp.max(wall, axis=-1),                # (B,)
-                "out": out,                                    # (B, n_out)
-                **fired_cores,
-            }
-            return (tuple(new_states), nidx, nxpre, nxpost, nelig), ys
+            return ((tuple(new_states), nidx, nxpre, nxpost, nelig),
+                    stack_counters(wall, out, layers))
 
         kps = [lw.kw * Z.SPIKE_WORD_BITS for lw in fused_w]
 
@@ -1751,7 +1621,8 @@ class FusedEngine(_EngineBase):
                      if (c is not None and reward) else None
                      for li, c in enumerate(cbws)]
             carry = (zero_states(B), list(idx0), xpre0, xpost0, elig0)
-            return scan_trains(step_plast, carry, trains)
+            final, ys = scan_trains(step_plast, carry, trains)
+            return batch_major(ys), final
 
         return run
 

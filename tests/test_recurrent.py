@@ -222,8 +222,8 @@ def test_recurrent_weight_shape_is_checked():
         ChipSimulator([w_in, w_out], quant_cfg=QCFG, recurrent=(2,))
 
 
-def _scan_carry(sim, n_in):
-    """The avals of the engine program's scan carry."""
+def _engine_scan(sim, n_in):
+    """The scan equation of the engine program at B=2, T=3."""
     run = sim.array_engine()._build_run()
     jaxpr = jax.make_jaxpr(run)(jnp.zeros((2, 3, n_in), jnp.float32))
     scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
@@ -232,6 +232,12 @@ def _scan_carry(sim, n_in):
                  for sub in jax.core.subjaxprs(eq)
                  for e in sub.eqns if e.primitive.name == "scan"]
     (scan,) = scans
+    return scan
+
+
+def _scan_carry(sim, n_in):
+    """The avals of the engine program's scan carry."""
+    scan = _engine_scan(sim, n_in)
     n = scan.params["num_carry"]
     return [v.aval for v in scan.invars[scan.params["num_consts"]:][:n]]
 
@@ -254,6 +260,31 @@ def test_chain_carry_holds_no_spike_state(engine):
         ["float32", "int32"] * 2 + [spikes]
     width = 64 if engine == "compiled" else 64 // 16
     assert carry[-1].shape[-1] == width
+
+
+@pytest.mark.parametrize("network_kind", ["chain", "recurrent", "chain_drop"])
+def test_fused_scan_emits_one_row_block_a_step(network_kind):
+    """Every fused inference program's step emits its kernels' outputs
+    as one int32 (B, W) row block and nothing else; the counters are
+    computed from the stacked blocks after the scan."""
+    from repro.faults import FaultConfig
+
+    w_in, w_out = network("aligned")
+    if network_kind == "recurrent":
+        sim = ChipSimulator([w_in, w_out], quant_cfg=QCFG, engine="fused",
+                            recurrent=(0,))
+    else:
+        kw = ({"faults": FaultConfig(drop_p=0.2, seed=1)}
+              if network_kind == "chain_drop" else {})
+        sim = ChipSimulator([codebook(np.random.default_rng(0), 48, 64,
+                                      2.0 ** -5), w_out],
+                            quant_cfg=QCFG, engine="fused", **kw)
+    assert (sim.drop_plan is not None) == (network_kind == "chain_drop")
+    scan = _engine_scan(sim, 48)
+    (rows,) = scan.outvars[scan.params["num_carry"]:]
+    assert str(rows.aval.dtype) == "int32"
+    # per layer: spikes, touched mask, input spikes, empty words
+    assert rows.aval.shape == (3, 2, 2 * (64 + 10) + 4)
 
 
 @pytest.mark.parametrize("shape", sorted(SHAPES))
