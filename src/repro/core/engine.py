@@ -30,8 +30,11 @@ from the simulator's configuration.  The engines:
   lanes), popcounts and zero-skips empty spike tiles (`pl.when`),
   dequantizes codebook indexes against `RegisterTable` words in-register
   (the dense f32 matrix never exists in HBM — indexes are int8, 4x
-  smaller), and applies the partial-update LIF step in the same VMEM
-  pass.  Spikes stay packed between layers; per-row empty-word counts
+  smaller) or, where the tables are exact integer words x one step per
+  core (`_lower_word_layer`), accumulates the int8 words in one bf16 MXU
+  pass and applies the step once (`word_layers` counts those layers),
+  and applies the partial-update LIF step in the same VMEM pass.
+  Spikes stay packed between layers; per-row empty-word counts
   are emitted as ZSPE skip telemetry (`StepStats.spike_words_skipped`).
   Its inference step emits only the kernels' outputs, one int32 row
   block a step, and `layer_counters` computes every counter from the
@@ -63,12 +66,14 @@ host.
 
 The bit-identical-spikes contract is validated on the CPU backend,
 where XLA's reduction order for the (B, n) @ (n, m) batched matmul
-matches the reference's per-sample product.  Every current matmul runs
-at `zspe.CURRENT_PRECISION` (HIGHEST), so a TPU keeps the f32 codebook
-weights instead of rounding them to bf16.  Its MXU still sums in its own
-order and its `leak ** n` differs in the last bit, so TPU currents and
-decays can differ from the CPU's by a few ulps: spikes agree unless a
-membrane value lands within that distance of the threshold.
+matches the reference's per-sample product.  Every current matmul over
+f32 weights runs at `zspe.CURRENT_PRECISION` (HIGHEST), so a TPU keeps
+the f32 codebook weights instead of rounding them to bf16 (the fused
+word layout's bf16 pass is exact by construction).  Its MXU still sums
+in its own order and its `leak ** n` differs in the last bit, so TPU
+currents and decays can differ from the CPU's by a few ulps: spikes
+agree unless a membrane value lands within that distance of the
+threshold.
 `chip_smoke.py` holds the TPU to exact agreement on its workload.
 
 Differential testing lives in tests/test_engine_equiv.py (both engines
@@ -166,12 +171,15 @@ class FusedLayerWeights:
     """One layer's weight operand for the fused kernel.
 
     Codebook form when every core slice of the layer has a programmed
-    `RegisterTable` whose words reproduce the executed weights exactly
-    (`idx` int8 indexes + `cbw` per-column level values = words x scale);
-    dense f32 fallback otherwise (float-only simulators).  Rows are
-    padded to the 16-spike word boundary with zeros — bit-neutral, since
-    the padded spike bits are zero too.  A recurrent layer's input is two
-    spike-word streams, the forward input's and its own last-step
+    `RegisterTable` whose words reproduce the executed weights exactly,
+    in one of two layouts: `words` (int8, each synapse's table word) +
+    `step` (per-column fixed-point step) where every level is exactly
+    word x step and the word sums stay exact (`_lower_word_layer`), else
+    `idx` (int8 indexes) + `cbw` (per-column level values = words x
+    scale).  Dense f32 fallback otherwise (float-only simulators).  Rows
+    are padded to the 16-spike word boundary with zeros — bit-neutral,
+    since the padded spike bits are zero too.  A recurrent layer's input
+    is two spike-word streams, the forward input's and its own last-step
     spikes' (`fed_words` words), so its rows are padded per stream: the
     fed-back rows start on a word, and the kernel takes the two word
     runs concatenated, unchanged.
@@ -188,17 +196,46 @@ class FusedLayerWeights:
                                    # per-row spike popcount (same ints)
     fed_words: int = 0             # words of the fed-back stream, at the
                                    # end of each input row; 0: not recurrent
+    words: jax.Array | None = None  # (kw*16, n_post) int8 table words
+    step: jax.Array | None = None   # (1, n_post) f32 fixed-point step
+
+    @property
+    def word_layout(self) -> bool:
+        return self.words is not None
 
     @property
     def codebook_mode(self) -> bool:
-        return self.idx is not None
+        return self.idx is not None or self.word_layout
 
     def hbm_bytes_per_step(self, batch: int) -> int:
         """Weight + input-spike HBM traffic for one timestep at `batch`."""
         spikes = batch * self.kw * 2                       # uint16 words
+        if self.word_layout:
+            return self.words.size * 1 + self.step.size * 4 + spikes
         if self.codebook_mode:
             return (self.idx.size * 1 + self.cbw.size * 4 + spikes)
         return self.dense.size * 4 + spikes
+
+
+def _layer_tables(sim: "ChipSimulator", li: int) -> list | None:
+    """[(assignment, RegisterTable)] of layer `li`'s core slices, or None
+    when a core has two tables, a slice has none, or the slices do not
+    cover the layer."""
+    # one physical core holds one assignment, so core_id keys the table
+    # regardless of list ordering (deploy's per-core PTQ orders tables by
+    # (layer, slice), the simulator by mapping.assignments)
+    by_core: dict[int, object] = {}
+    for rt in sim.register_tables:
+        if rt.core_id in by_core:
+            return None                                # ambiguous: bail
+        by_core[rt.core_id] = rt
+    slices = [(a, by_core.get(a.core_id))
+              for a in sim.mapping.assignments if a.layer == li + 1]
+    if not slices or any(rt is None for _, rt in slices):
+        return None
+    if sum(a.n_neurons for a, _ in slices) != int(sim.weights[li].shape[1]):
+        return None
+    return slices
 
 
 def _lower_codebook_layer(sim: "ChipSimulator", li: int, fill: float = 0.0,
@@ -217,20 +254,8 @@ def _lower_codebook_layer(sim: "ChipSimulator", li: int, fill: float = 0.0,
     """
     w = np.asarray(sim.weights[li], np.float32)
     n_pre, n_post = w.shape
-    # one physical core holds one assignment, so core_id keys the table
-    # regardless of list ordering (deploy's per-core PTQ orders tables by
-    # (layer, slice), the simulator by mapping.assignments)
-    by_core: dict[int, object] = {}
-    for rt in sim.register_tables:
-        if rt.core_id in by_core:
-            return None                                # ambiguous: bail
-        by_core[rt.core_id] = rt
-    slices = [(a, by_core.get(a.core_id))
-              for a in sim.mapping.assignments if a.layer == li + 1]
-    if not slices or any(rt is None for _, rt in slices):
-        return None
-    covered = sum(a.n_neurons for a, _ in slices)
-    if covered != n_post:
+    slices = _layer_tables(sim, li)
+    if slices is None:
         return None
     n_levels = max(rt.weight_levels for _, rt in slices)
     idx = np.zeros((n_pre, n_post), np.int8)
@@ -246,6 +271,38 @@ def _lower_codebook_layer(sim: "ChipSimulator", li: int, fill: float = 0.0,
         idx[:, a.neuron_lo:a.neuron_hi] = ii.astype(np.int8)
         cbw[:len(cb), a.neuron_lo:a.neuron_hi] = cb[:, None]
     return idx, cbw
+
+
+def _lower_word_layer(sim: "ChipSimulator", idx: np.ndarray, li: int,
+                      ) -> tuple[np.ndarray, np.ndarray] | None:
+    """(words (n_pre, n_post) int8, step (1, n_post) f32) for layer `li`,
+    whose table indexes are `idx`: each synapse's table word and each
+    column's fixed-point step, so that the weight is word x step.
+
+    None unless, for every column: (i) each level of its table equals
+    word x step exactly (f64, step finite and > 0), (ii) each word fits
+    int8, so it is exact in bf16 too, and (iii) the sum of |word| down
+    the column is under 2**24, so the word sums are exact f32 integers
+    in any order.  Then `(spikes @ words) * step` is the correctly
+    rounded f32 current.  A `quant.quantize()` table's step has a full
+    f32 mantissa and fails (i).
+    """
+    slices = _layer_tables(sim, li)
+    words = np.zeros(idx.shape, np.int8)
+    step = np.zeros((1, idx.shape[1]), np.float32)
+    for a, rt in slices:
+        tw = np.asarray(rt.codebook_words, np.int64)
+        st = np.float32(rt.codebook_scale)
+        if not (np.isfinite(st) and st > 0) or tw.min() < -128 \
+                or tw.max() > 127 or not np.array_equal(
+                    tw * np.float64(st), rt.codebook().astype(np.float64)):
+            return None
+        cols = slice(a.neuron_lo, a.neuron_hi)
+        words[:, cols] = tw[idx[:, cols]]
+        step[0, cols] = st
+    if np.abs(words.astype(np.int32)).sum(axis=0).max() >= 1 << 24:
+        return None
+    return words, step
 
 
 def lower_plasticity_tables(sim: "ChipSimulator"):
@@ -286,9 +343,10 @@ def lower_plasticity_tables(sim: "ChipSimulator"):
 
 
 def _pick_engine_block(m: int, k: int, n: int, interpret: bool, *,
-                       codebook: bool = True, n_levels: int = 16,
+                       layout: str = "codebook", n_levels: int = 16,
                        all_nonzero: bool = False) -> tuple[int, int] | None:
-    """Kernel tile for one engine layer-step ((m, k) spikes, (k, n) weights).
+    """Kernel tile for one engine layer-step ((m, k) spikes, (k, n) weights
+    in `layout`: "codebook", "words" or "dense").
 
     Interpret mode runs one exact (m, n) tile — that is what makes the
     fused path bit-exact against the compiled engine.  A compiled (TPU)
@@ -315,7 +373,7 @@ def _pick_engine_block(m: int, k: int, n: int, interpret: bool, *,
            if n % d == 0 and (d == n or d % 128 == 0)]
     for bm in bms:
         for bn in bns:
-            if F.vmem_bytes(bm, bn, kw, codebook=codebook, n_levels=n_levels,
+            if F.vmem_bytes(bm, bn, kw, layout=layout, n_levels=n_levels,
                             all_nonzero=all_nonzero) <= F.VMEM_BUDGET_BYTES:
                 return bm, bn
     raise ValueError(
@@ -346,7 +404,14 @@ def lower_fused_weights(sim: "ChipSimulator") -> tuple[FusedLayerWeights, ...]:
         fed = Z.spike_word_count(n_post) if li in sim.recurrent else 0
         nz = bool(np.all(np.asarray(w) != 0))
         cbk = _lower_codebook_layer(sim, li)
-        if cbk is not None:
+        wl = None if cbk is None else _lower_word_layer(sim, cbk[0], li)
+        if wl is not None:
+            out.append(FusedLayerWeights(
+                n_pre=n_pre, n_post=n_post, kw=kw, idx=None, cbw=None,
+                dense=None, all_nonzero=nz, fed_words=fed,
+                words=jnp.asarray(_pad_streams(wl[0], streams)),
+                step=jnp.asarray(wl[1])))
+        elif cbk is not None:
             idx, cbw = cbk
             out.append(FusedLayerWeights(
                 n_pre=n_pre, n_post=n_post, kw=kw,
@@ -423,6 +488,7 @@ class _EngineBase:
         self.last_learned = None     # per-layer learned indexes (B leading)
         self.last_elig = None        # per-layer eligibility (reward mode)
         self.calls = 0               # run_batch calls so far (span `call`)
+        self.word_layers = 0         # layers on the fused word layout
 
     # -- trace construction (subclass hooks) --------------------------------
 
@@ -544,7 +610,8 @@ class _EngineBase:
 
         Each phase runs under a `jax.profiler.TraceAnnotation` span, on
         the profiler's clock beside the device ops: `snn.run_batch`
-        (stats `call`, `batch`, `steps`) holds `snn.upload` (`bytes`),
+        (stats `call`, `batch`, `steps`, and `word_layers` where a fused
+        layer runs on the word layout) holds `snn.upload` (`bytes`),
         `snn.dispatch`, `snn.device_wait` (launches the slab's program
         and waits for it), `snn.readback` (`transfers`: 1, `bytes`),
         `snn.noc_replay` (`flows`: the flows replayed; `back_flows`:
@@ -553,9 +620,10 @@ class _EngineBase:
         """
         self.calls += 1
         batch, steps = (tuple(np.shape(spike_trains)) + (0, 0))[:2]
-        with jax.profiler.TraceAnnotation(
-                "snn.run_batch", call=self.calls, batch=int(batch),
-                steps=int(steps)):
+        stats = dict(call=self.calls, batch=int(batch), steps=int(steps))
+        if self.word_layers:
+            stats["word_layers"] = self.word_layers
+        with jax.profiler.TraceAnnotation("snn.run_batch", **stats):
             return self._run_batch(spike_trains, learned)
 
     def _readback_keys(self, ys: dict) -> list[str]:
@@ -1373,6 +1441,7 @@ class FusedEngine(_EngineBase):
                 "engine='compiled'")
         super().__init__(sim, shard=shard)
         self.fused_weights = lower_fused_weights(sim)
+        self.word_layers = sum(lw.word_layout for lw in self.fused_weights)
         self.last_states = None      # final LIF states of the last run
 
     @property
@@ -1385,7 +1454,8 @@ class FusedEngine(_EngineBase):
 
     def _build_run(self):
         from repro.kernels.fused_timestep import (fused_timestep_codebook,
-                                                  fused_timestep_dense)
+                                                  fused_timestep_dense,
+                                                  fused_timestep_words)
         from repro.kernels.ops import interpret_default
 
         sim = self.sim
@@ -1409,22 +1479,25 @@ class FusedEngine(_EngineBase):
 
         def layer_apply(li, packed, state):
             lw = fused_w[li]
+            layout = ("words" if lw.word_layout else
+                      "codebook" if lw.codebook_mode else "dense")
             block = _pick_engine_block(
                 int(packed.shape[0]), lw.kw * Z.SPIKE_WORD_BITS, lw.n_post,
-                interp, codebook=lw.codebook_mode,
-                n_levels=int(lw.cbw.shape[0]) if lw.codebook_mode else 0,
+                interp, layout=layout,
+                n_levels=int(lw.cbw.shape[0]) if layout == "codebook" else 0,
                 all_nonzero=lw.all_nonzero)
             # a stable kernel name per layer, for the profile's op line
-            name = f"snn_fused_l{li + 1}"
-            if lw.codebook_mode:
+            kw = dict(all_nonzero=lw.all_nonzero, block=block,
+                      interpret=interp, name=f"snn_fused_l{li + 1}", **lif_kw)
+            if layout == "words":
+                return fused_timestep_words(packed, lw.words, lw.step,
+                                            state.v, state.elapsed, **kw)
+            if layout == "codebook":
                 return fused_timestep_codebook(
                     packed, lw.idx, lw.cbw, state.v, state.elapsed,
-                    gather=interp, all_nonzero=lw.all_nonzero,
-                    block=block, interpret=interp, name=name, **lif_kw)
-            return fused_timestep_dense(
-                packed, lw.dense, state.v, state.elapsed,
-                all_nonzero=lw.all_nonzero, block=block, interpret=interp,
-                name=name, **lif_kw)
+                    gather=interp, **kw)
+            return fused_timestep_dense(packed, lw.dense, state.v,
+                                        state.elapsed, **kw)
 
         def layer_counters(li, wall, out, tc, nnz_rows, ew, writes=None):
             """Layer li's counters from its kernel's outputs (or the

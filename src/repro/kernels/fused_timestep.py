@@ -18,13 +18,22 @@ Stage map (chip -> kernel):
                     work, just the partial-update bookkeeping (elapsed+1).
                     Per-row empty-word counts are emitted as the skip
                     telemetry the energy model and tests consume.
-  SPE dequant       weights arrive as log2(N)-bit codebook indexes plus a
-                    per-column level table (`RegisterTable` words x scale,
-                    f32) and are expanded **in-register** — the dense f32
-                    matrix never exists in HBM.  Two expansion strategies:
-                    N compare+select passes (TPU VPU-friendly) or a flat
+  SPE               weights arrive codebook-compressed and the dense f32
+                    matrix never exists in HBM.  Where every level of a
+                    layer's tables is an exact (<= 8-bit) integer word
+                    times the core's fixed-point step (the lowering
+                    checks it), the kernel takes the int8 **word** of
+                    each synapse plus a per-column step, as the chip's
+                    SPE does: it accumulates the words in one bf16 MXU
+                    pass (0/1 spikes x integers, exact, f32 sums under
+                    2**24) and multiplies the column by its step once.
+                    Otherwise it takes log2(N)-bit codebook indexes plus
+                    a per-column level table (`RegisterTable` words x
+                    scale, f32), expanded **in-register** by N
+                    compare+select passes (TPU VPU-friendly) or a flat
                     one-pass gather (faster under interpret mode on
-                    CPU); both produce bit-identical f32 values.
+                    CPU), bit-identical f32 values, for an f32 HIGHEST
+                    matmul.
   neuron updater    the partial-update LIF step (paper C2) runs on the
                     same VMEM tile: lazy-leak decay, integrate, fire,
                     hard reset, `elapsed` stamp — using the integer-exact
@@ -37,7 +46,8 @@ plain `spikes @ w` matmul (K zero-padding is bit-neutral; see
 tests/test_fused_kernel.py).  The engine invokes it with bm=M, bn=N in
 interpret mode, which makes the fused path bit-identical to the compiled
 engine's dense matmul + `lif_step`; smaller blocks are for real-TPU VMEM
-budgets, where tiling only perturbs float currents at the ulp level.
+budgets, where tiling only perturbs float currents at the ulp level (on
+the word layout not at all: its sums are exact integers).
 
 The dense-weight variant (`fused_timestep_dense`) exists for float
 (unquantized) simulators — same ZSPE/LIF fusion, weights as plain f32.
@@ -100,17 +110,19 @@ def _unpack_words(pk: jax.Array) -> tuple[jax.Array, jax.Array]:
     return bits.astype(jnp.float32), jnp.sum(bits, axis=1, keepdims=True)
 
 
-def vmem_bytes(bm: int, bn: int, kw: int, *, codebook: bool,
+def vmem_bytes(bm: int, bn: int, kw: int, *, layout: str = "codebook",
                n_levels: int = 16, all_nonzero: bool = False) -> int:
     """Conservative VMEM footprint of one grid step of the compiled kernel.
 
     Counts every operand block double-buffered (spike words, the weight
-    slab, the level table, v/elapsed in; four state tiles and two row
-    counters out), each padded to its (sublane, 128-lane) tile, plus the
-    in-kernel temporaries: the unpack's expand matrix and its iotas, the
-    unpacked spike lanes, the dequantized f32 slab with its int32 indexes and
-    select temporary, the nonzero mask unless `all_nonzero`, and the LIF
-    tile arithmetic.
+    slab, the level table or step row, v/elapsed in; four state tiles and
+    two row counters out), each padded to its (sublane, 128-lane) tile,
+    plus the in-kernel temporaries: the unpack's expand matrix and its
+    iotas, the unpacked spike lanes, the weight slab as the MXU takes it
+    (`codebook`: the dequantized f32 slab with its int32 indexes and
+    select temporaries; `words`: the bf16 words, their int32 and f32
+    steps of widening, and the bf16 spikes; `dense`: the f32 slab), its
+    nonzero mask unless `all_nonzero`, and the LIF tile arithmetic.
     """
     k = kw * SPIKE_WORD_BITS
 
@@ -118,14 +130,19 @@ def vmem_bytes(bm: int, bn: int, kw: int, *, codebook: bool,
         sub = 8 * (4 // itemsize)
         return (-(-rows // sub) * sub) * (-(-cols // 128) * 128) * itemsize
 
-    blocks = (tile(bm, kw, 2) + tile(k, bn, 1 if codebook else 4)
-              + (tile(n_levels, bn, 4) if codebook else 0)
+    slab, table, temp = {
+        "codebook": (tile(k, bn, 1), tile(n_levels, bn, 4),
+                     4 * tile(k, bn, 4)),
+        "words": (tile(k, bn, 1), tile(1, bn, 4),
+                  2 * tile(k, bn, 4) + tile(k, bn, 2) + tile(bm, k, 2)),
+        "dense": (tile(k, bn, 4), 0, tile(k, bn, 4)),
+    }[layout]
+    mask = 0 if all_nonzero else tile(k, bn, 2 if layout == "words" else 4)
+    blocks = (tile(bm, kw, 2) + slab + table
               + 6 * tile(bm, bn, 4) + 2 * tile(bm, 1, 4))
     cw = min(kw, _CHUNK_WORDS)
     temps = (3 * tile(cw, cw * SPIKE_WORD_BITS, 4) + 6 * tile(bm, k, 4)
-             + (4 if codebook else 1) * tile(k, bn, 4)
-             + (0 if all_nonzero else tile(k, bn, 4))
-             + 8 * tile(bm, bn, 4))
+             + temp + mask + 8 * tile(bm, bn, 4))
     return 2 * blocks + temps
 
 
@@ -173,9 +190,15 @@ def _lif_tile(v, el, cur, tcnt, *, threshold, leak, reset, partial_update):
     return v_new, el_new, spikes, touched.astype(jnp.int32)
 
 
+def _widen(words: jax.Array) -> jax.Array:
+    """(K, bn) int8 words -> bf16, exact (|word| <= 256), through int32
+    and f32 (Mosaic converts neither int8 nor int32 to bf16 directly)."""
+    return words.astype(jnp.int32).astype(jnp.float32).astype(jnp.bfloat16)
+
+
 def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
             vo_ref, elo_ref, sp_ref, tc_ref, nnz_ref, ew_ref, *,
-            codebook: bool, gather: bool, threshold: float, leak: float,
+            layout: str, gather: bool, threshold: float, leak: float,
             reset: float, partial_update: bool, all_nonzero: bool):
     j = pl.program_id(1)
     pk = pk_ref[...]                                   # (bm, kw) uint16
@@ -208,13 +231,20 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
 
     @pl.when(nnz_tile > 0)
     def _work():
-        if codebook:
-            idx = w0_ref[...].astype(jnp.int32)        # (K, bn) indexes
-            w = _dequant_columns(idx, w1_ref[...], gather)
+        if layout == "words":
+            # integer words: one bf16 pass, exact (0/1 x |word| <= 256,
+            # f32 sums under 2**24), then the column's step once — the
+            # correctly rounded f32 of the exact current
+            sb = s.astype(jnp.bfloat16)
+            w = _widen(w0_ref[...])                        # (K, bn) bf16
+            cur = jnp.dot(sb, w, preferred_element_type=jnp.float32
+                          ) * w1_ref[...]
         else:
-            w = w0_ref[...]                            # (K, bn) dense f32
-        cur = jnp.dot(s, w, precision=CURRENT_PRECISION,
-                      preferred_element_type=jnp.float32)
+            w = (_dequant_columns(w0_ref[...].astype(jnp.int32),
+                                  w1_ref[...], gather)
+                 if layout == "codebook" else w0_ref[...])   # (K, bn) f32
+            cur = jnp.dot(s, w, precision=CURRENT_PRECISION,
+                          preferred_element_type=jnp.float32)
         # integer-exact touch counts: valid spikes through nonzero
         # synapses.  With a fully-nonzero weight slab (the static
         # `all_nonzero` flag, decided at lowering time) the nonzero mask
@@ -222,6 +252,9 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
         # popcount — the identical integers, one MXU pass cheaper.
         if all_nonzero:
             tcnt = jnp.broadcast_to(nnz_rows.astype(jnp.float32), v.shape)
+        elif layout == "words":
+            tcnt = jnp.dot(sb, (w != 0).astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32)
         else:
             nz = (w != 0.0).astype(jnp.float32)
             tcnt = jnp.dot(s, nz, precision=CURRENT_PRECISION,
@@ -235,7 +268,7 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
         tc_ref[...] = tc
 
 
-def _call(pk, w0, w1, v, elapsed, *, codebook, gather, threshold, leak,
+def _call(pk, w0, w1, v, elapsed, *, layout, gather, threshold, leak,
           reset, partial_update, all_nonzero, block, interpret, name):
     m, kw = pk.shape
     k = kw * SPIKE_WORD_BITS
@@ -245,7 +278,7 @@ def _call(pk, w0, w1, v, elapsed, *, codebook, gather, threshold, leak,
     assert w0.shape[0] == k, (w0.shape, k)
 
     kern = functools.partial(
-        _kernel, codebook=codebook, gather=gather, threshold=threshold,
+        _kernel, layout=layout, gather=gather, threshold=threshold,
         leak=leak, reset=reset, partial_update=partial_update,
         all_nonzero=all_nonzero)
     state_spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
@@ -255,16 +288,15 @@ def _call(pk, w0, w1, v, elapsed, *, codebook, gather, threshold, leak,
         pl.BlockSpec((k, bn), lambda i, j: (0, j)),
     ]
     operands = [pk, w0]
-    if codebook:
-        n_levels = w1.shape[0]
-        in_specs.append(pl.BlockSpec((n_levels, bn), lambda i, j: (0, j)))
+    if w1 is not None:           # the level table or the step row
+        in_specs.append(pl.BlockSpec((w1.shape[0], bn), lambda i, j: (0, j)))
         operands.append(w1)
     in_specs += [state_spec, state_spec]
     operands += [v, elapsed]
     n_in = len(operands)
 
     return pl.pallas_call(
-        kern if codebook else _drop_w1(kern),
+        kern if w1 is not None else _drop_w1(kern),
         grid=(m // bm, n // bn),
         in_specs=in_specs,
         out_specs=[state_spec, state_spec, state_spec, state_spec,
@@ -325,8 +357,43 @@ def fused_timestep_codebook(
     configuration; pass (bm, bn) divisors to tile for TPU VMEM.  `name`
     names the kernel in the compiled program and its profile.
     """
-    return _call(packed, idx, cbw, v, elapsed, codebook=True, gather=gather,
-                 threshold=threshold, leak=leak, reset=reset,
+    return _call(packed, idx, cbw, v, elapsed, layout="codebook",
+                 gather=gather, threshold=threshold, leak=leak, reset=reset,
+                 partial_update=partial_update, all_nonzero=all_nonzero,
+                 block=block, interpret=interpret, name=name)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "threshold", "leak", "reset", "partial_update", "all_nonzero", "block",
+    "interpret", "name"))
+def fused_timestep_words(
+    packed: jax.Array,        # (M, Kw) uint16 spike words
+    words: jax.Array,         # (Kw*16, N) int8 integer weight words
+    step: jax.Array,          # (1, N) f32 per-column fixed-point step
+    v: jax.Array,             # (M, N) f32 membrane potential
+    elapsed: jax.Array,       # (M, N) int32 idle-step stamps
+    *,
+    threshold: float = 1.0,
+    leak: float = 0.9,
+    reset: float = 0.0,
+    partial_update: bool = True,
+    all_nonzero: bool = False,
+    block: tuple[int, int] | None = None,
+    interpret: bool = True,
+    name: str | None = None,
+):
+    """One fused layer-timestep, weights as integer words x a per-column
+    step: the current is `(spikes @ words) * step`.
+
+    The caller guarantees what makes that exact: every |word| <= 256 and
+    every column's sum of |word| under 2**24, so the word sums are exact
+    integers in any order and any (bm, bn) tiling, and the current is
+    one correctly rounded f32 product.  Where the levels `word * step`
+    make an exact f32 current in the codebook layout, the two layouts
+    agree bit for bit.  Returns what `fused_timestep_codebook` returns.
+    """
+    return _call(packed, words, step, v, elapsed, layout="words",
+                 gather=False, threshold=threshold, leak=leak, reset=reset,
                  partial_update=partial_update, all_nonzero=all_nonzero,
                  block=block, interpret=interpret, name=name)
 
@@ -354,7 +421,7 @@ def fused_timestep_dense(
     `all_nonzero` refers to the REAL weight rows; the zero rows padding
     K to the word boundary never see spikes, so they cannot affect the
     collapsed touch counts."""
-    return _call(packed, weights, None, v, elapsed, codebook=False,
+    return _call(packed, weights, None, v, elapsed, layout="dense",
                  gather=False, threshold=threshold, leak=leak, reset=reset,
                  partial_update=partial_update, all_nonzero=all_nonzero,
                  block=block, interpret=interpret, name=name)

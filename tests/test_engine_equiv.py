@@ -336,6 +336,98 @@ def test_fused_per_core_register_tables_run_compressed():
     assert_equivalent(ref, fus, make_trains(rng, 4, 6, 64, density=0.2))
 
 
+def _bench_kind_sim(kind, sizes, engine, mapping=None):
+    """A network of one of the benchmark's kinds (`bench/networks`), at
+    small widths: codebook weights of 8-bit words times a step of 11
+    significant bits, drawn from a seed above 2**32."""
+    import pathlib
+    import sys
+
+    root = str(pathlib.Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import registry
+
+    config = {"layer_sizes": list(sizes), "timesteps": 8, "threshold": 1.0,
+              "leak": 0.9, "reset": 0.0, "weight_levels": 16,
+              "weight_bits": 8, "freq_hz": 1e8, "weight_gain": 3.0,
+              "scale_mantissa_bits": 11}
+    if kind == "recurrent_chain":
+        config.update(recurrent=[1], weight_gain=[2.0, 3.0])
+    net = registry.load_module("networks", kind)
+    program, _ = net.make(config, 4294967311)
+    if mapping is None:
+        return net.simulator(config, {"engine": engine}, program)
+    return ChipSimulator(program, engine=engine, mapping=mapping,
+                         quant_cfg=CodebookConfig(16, 8), leak=0.9,
+                         threshold=1.0, freq_hz=1e8,
+                         recurrent=(0,) if kind == "recurrent_chain" else ())
+
+
+@pytest.mark.parametrize("kind,sizes", [
+    ("dense_chain", (50, 64, 32, 10)), ("recurrent_chain", (50, 64, 10)),
+    ("quantized", (48, 80, 32, 10))], ids=["nmnist", "shd", "quantized"])
+def test_fused_word_layout_gate(kind, sizes):
+    """Every layer of the benchmark's kinds (tables of exact 8-bit words
+    x an 11-bit step) lowers to the word layout, and the run equals the
+    compiled engine's exactly; a `quantize()`d network's steps carry a
+    full f32 mantissa, so it keeps the select-decode layout."""
+    n_layers = len(sizes) - 1
+    if kind == "quantized":
+        w = make_weights(np.random.default_rng(17), sizes, scale=0.2)
+        fus = ChipSimulator(w, engine="fused",
+                            quant_cfg=CodebookConfig(16, 8))
+        comp = ChipSimulator(w, engine="compiled", mapping=fus.mapping,
+                             quant_cfg=CodebookConfig(16, 8))
+    else:
+        fus = _bench_kind_sim(kind, sizes, "fused")
+        comp = _bench_kind_sim(kind, sizes, "compiled", mapping=fus.mapping)
+    fe = fus.fused_engine()
+    assert fe.codebook_layers == n_layers
+    words = 0 if kind == "quantized" else n_layers
+    assert fe.word_layers == words
+    assert sum(lw.idx is not None for lw in fe.fused_weights) \
+        == n_layers - words
+    for lw in fe.fused_weights:
+        held, table = (lw.words, lw.step) if lw.word_layout \
+            else (lw.idx, lw.cbw)
+        assert held.dtype == jnp.int8
+        assert held.shape == (lw.kw * 16, lw.n_post)
+        # HBM bytes a step: the int8 slab, its step row or level table,
+        # the uint16 spike words
+        assert lw.hbm_bytes_per_step(4) \
+            == held.size + 4 * table.size + 4 * lw.kw * 2
+    trains = make_trains(np.random.default_rng(3), 4, 8, sizes[0],
+                         density=0.2)
+    counts_c, reps_c = comp.run_batch(trains)
+    counts_f, reps_f = fus.run_batch(trains)
+    assert np.asarray(counts_c).sum() > 0
+    np.testing.assert_array_equal(np.asarray(counts_f), np.asarray(counts_c))
+    for rc, rf in zip(reps_c, reps_f):
+        for f in STAT_FIELDS:
+            assert getattr(rf.stats, f) == getattr(rc.stats, f), f
+        for f in REPORT_FIELDS:
+            assert getattr(rf, f) == getattr(rc, f), f
+
+
+def test_fused_word_layout_needs_int8_words():
+    """Tables of 16-bit words (a power-of-two step, so every level is
+    exact) do not fit the int8 word slab: the select decode keeps them."""
+    from repro.core.quant import QuantizedTensor
+
+    rng = np.random.default_rng(2)
+    words = np.concatenate([-np.arange(1000, 200, -100),
+                            np.arange(300, 1100, 100)])
+    w = [QuantizedTensor(
+        idx=jnp.asarray(rng.integers(0, 16, (a, b)), jnp.int8),
+        codebook=jnp.asarray(words[None] * 2.0 ** -12, jnp.float32),
+        scale=jnp.asarray([2.0 ** -12], jnp.float32), group_axis_size=0)
+        for a, b in ((32, 16), (16, 8))]
+    fe = ChipSimulator(w, engine="fused",
+                       quant_cfg=CodebookConfig(16, 16)).fused_engine()
+    assert (fe.codebook_layers, fe.word_layers) == (2, 0)
+
+
 def test_fused_shard_map_multi_device():
     """With >= 2 devices and a divisible batch the fused engine runs the
     program through shard_map and still matches the reference exactly."""
@@ -390,10 +482,13 @@ def test_fused_engine_block_selection():
     assert _pick_engine_block(32, 2320, 512, interpret=True) is None
     for m, k, n in [(32, 8192, 8192), (12, 2320, 4096), (3, 16, 509),
                     (8, 1024, 10), (256, 4096, 1024)]:
-        bm, bn = _pick_engine_block(m, k, n, interpret=False)
-        assert m % bm == 0 and (bm == m or bm % 16 == 0)
-        assert n % bn == 0 and (bn == n or bn % 128 == 0)
-        assert vmem_bytes(bm, bn, k // 16, codebook=True) <= VMEM_BUDGET_BYTES
+        for layout in ("codebook", "words"):
+            bm, bn = _pick_engine_block(m, k, n, interpret=False,
+                                        layout=layout)
+            assert m % bm == 0 and (bm == m or bm % 16 == 0)
+            assert n % bn == 0 and (bn == n or bn % 128 == 0)
+            assert vmem_bytes(bm, bn, k // 16, layout=layout) \
+                <= VMEM_BUDGET_BYTES
     assert _pick_engine_block(12, 2320, 4096, interpret=False)[0] == 12
     assert _pick_engine_block(3, 16, 509, interpret=False) == (3, 509)
     with pytest.raises(ValueError, match="VMEM"):

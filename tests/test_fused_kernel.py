@@ -265,3 +265,81 @@ def test_interpret_default_cached():
     assert interpret_default() is interpret_default()
     info = interpret_default.cache_info()
     assert info.hits >= 1
+
+
+# ---------------------------------------------------------------------------
+# word layout (integer table words x a per-column step) vs select decode
+# ---------------------------------------------------------------------------
+
+def _exact_step_tables(rng, n, step_kind, zero_word):
+    """(16, n) int words and (n,) f32 steps, two per-core tables across
+    the columns: each level word x step is an exact f32 number."""
+    if step_kind == "11bit":        # odd 11-bit mantissas, as the bench's
+        steps = [np.float32(m * 2.0 ** -19) for m in (1531, 1093)]
+    else:
+        steps = [np.float32(2.0 ** -5), np.float32(2.0 ** -6)]
+    tables = []
+    for _ in steps:
+        mags = np.sort(rng.choice(np.arange(1, 128), 8, replace=False))
+        words = np.concatenate([-mags[::-1], mags])
+        if zero_word:
+            words[7] = 0
+        tables.append(words)
+    half = n // 2
+    words = np.concatenate([np.broadcast_to(t[:, None], (16, w))
+                            for t, w in zip(tables, (half, n - half))], 1)
+    step = np.concatenate([np.full(half, steps[0], np.float32),
+                           np.full(n - half, steps[1], np.float32)])
+    return words, step
+
+
+WORD_CASES = {
+    # id: (step, streams, all_nonzero, partial_update, density, block)
+    "11bit-aligned": ("11bit", (256,), True, True, 0.03, None),
+    "pow2-unaligned-zero-words": ("pow2", (100,), False, True, 0.2, None),
+    "11bit-recurrent-700+1024": ("11bit", (700, 1024), True, True, 0.03,
+                                 None),
+    "11bit-recurrent-full-update": ("11bit", (700, 1024), False, False,
+                                    0.03, None),
+    "11bit-tiled": ("11bit", (300,), False, True, 0.05, (4, 128)),
+    "pow2-spike-free": ("pow2", (75,), True, True, 0.0, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WORD_CASES))
+def test_word_layout_equals_select_decode(case):
+    """Exact-step tables: the word layout's `(spikes @ words) * step` and
+    the select decode's f32 dot over the levels are the same current, so
+    v', elapsed', spikes, the touched mask, nnz and the empty words are
+    bit-equal.  A recurrent input is two word-padded spike streams (700
+    -> 704 rows, then 1024), concatenated as the engine feeds them."""
+    from repro.kernels.fused_timestep import (fused_timestep_codebook,
+                                              fused_timestep_words)
+
+    step_kind, streams, nonzero, partial, density, block = WORD_CASES[case]
+    rng = np.random.default_rng(len(case))
+    m, n = 8, 256
+    packed = jnp.concatenate(
+        [pack_spike_words(jnp.asarray(rng.random((m, s)) < density,
+                                      jnp.float32)) for s in streams], -1)
+    k = packed.shape[1] * SPIKE_WORD_BITS
+    table, step = _exact_step_tables(rng, n, step_kind, zero_word=not nonzero)
+    idx = rng.integers(0, 16, (k, n))
+    words = np.take_along_axis(table, idx, axis=0)
+    assert (words != 0).all() == nonzero
+    levels = table.astype(np.float32) * step
+    assert np.array_equal(table * step.astype(np.float64), levels)
+    v = jnp.asarray(rng.normal(0, 0.3, (m, n)), jnp.float32)
+    el = jnp.asarray(rng.integers(0, 4, (m, n)), jnp.int32)
+    kw = dict(threshold=0.25, leak=0.9, partial_update=partial,
+              all_nonzero=nonzero, block=block)
+    want = fused_timestep_codebook(packed, jnp.asarray(idx, jnp.int8),
+                                   jnp.asarray(levels), v, el, **kw)
+    got = fused_timestep_words(packed, jnp.asarray(words, jnp.int8),
+                               jnp.asarray(step[None, :]), v, el, **kw)
+    for name, g, w in zip(("v", "elapsed", "spikes", "touched", "nnz",
+                           "empty_words"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+    fired = int(np.asarray(got[2]).sum())
+    assert (fired > 0) == (density > 0), fired

@@ -117,6 +117,30 @@ def test_run_batch_emits_each_phase_once_per_call(rec, monkeypatch, engine):
 
 
 @pytest.mark.parametrize("engine", ["compiled", "fused"])
+def test_run_batch_span_counts_word_layers(rec, engine):
+    """Tables of exact integer words x a power-of-two step: the fused
+    engine runs both layers on the word layout and its call span says
+    so (`word_layers`); the compiled engine's span has no such stat."""
+    from repro.core.quant import CodebookConfig, QuantizedTensor
+
+    rng = np.random.default_rng(0)
+    words = np.concatenate([-np.arange(8, 0, -1), np.arange(1, 9)])
+    weights = [QuantizedTensor(
+        idx=jax.numpy.asarray(rng.integers(0, 16, (a, b)), "int8"),
+        codebook=jax.numpy.asarray(words[None] * 2.0 ** -4, "float32"),
+        scale=jax.numpy.asarray([2.0 ** -4], "float32"), group_axis_size=0)
+        for a, b in ((8, 16), (16, 4))]
+    sim = ChipSimulator(weights, engine=engine,
+                        quant_cfg=CodebookConfig(16, 8))
+    sim.run_batch((rng.random((2, 3, 8)) < 0.4).astype(np.float32))
+    (call,) = [s for s in rec.spans if s["name"] == "snn.run_batch"]
+    want = {"call": 1, "batch": 2, "steps": 3}
+    if engine == "fused":
+        want["word_layers"] = 2
+    assert call["stats"] == want
+
+
+@pytest.mark.parametrize("engine", ["compiled", "fused"])
 def test_noc_replay_counts_back_flows(rec, engine):
     """A recurrent layer's flows, one tree per core slice to the readout
     and back to the layer, ride as the replay span's `back_flows` stat

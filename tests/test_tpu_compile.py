@@ -21,7 +21,8 @@ from repro.core import zspe as Z
 from repro.core.engine import CompiledEngine, _pick_engine_block
 from repro.core.soc import ChipSimulator
 from repro.kernels.fused_timestep import (fused_timestep_codebook,
-                                          fused_timestep_dense)
+                                          fused_timestep_dense,
+                                          fused_timestep_words)
 
 LAYERS = list(zip(ARCH.layer_sizes[:-1], ARCH.layer_sizes[1:]))
 V5E_HBM_BYTES = 16 * 2 ** 30
@@ -42,16 +43,18 @@ def one_chip():
 @pytest.mark.parametrize("batch", [8, 32, 12])
 @pytest.mark.parametrize("n_pre,n_post", LAYERS,
                          ids=[f"{a}x{b}" for a, b in LAYERS])
-@pytest.mark.parametrize("codebook", [True, False],
-                         ids=["codebook", "dense"])
-def test_fused_kernel_compiles_for_v5e(one_chip, codebook, n_pre, n_post,
+@pytest.mark.parametrize("layout", ["codebook", "dense", "words"])
+def test_fused_kernel_compiles_for_v5e(one_chip, layout, n_pre, n_post,
                                        batch):
-    """Both kernel variants at every ARCH layer, with the tile the fused
-    engine picks (B=12: an odd batch the server and benches can send)."""
+    """Every kernel layout at every ARCH layer, with the tile the fused
+    engine picks (B=12: an odd batch the server and benches can send);
+    the word layout with every weight nonzero, as the benchmark's
+    tables are."""
     kw = Z.spike_word_count(n_pre)
     k = kw * Z.SPIKE_WORD_BITS
     block = _pick_engine_block(batch, k, n_post, interpret=False,
-                               codebook=codebook)
+                               layout=layout,
+                               all_nonzero=layout == "words")
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -59,11 +62,16 @@ def test_fused_kernel_compiles_for_v5e(one_chip, codebook, n_pre, n_post,
     spikes = sds((batch, kw), jnp.uint16)
     state = (sds((batch, n_post), jnp.float32),
              sds((batch, n_post), jnp.int32))
-    if codebook:
+    if layout == "codebook":
         lowered = fused_timestep_codebook.lower(
             spikes, sds((k, n_post), jnp.int8),
             sds((ARCH.weight_levels, n_post), jnp.float32), *state,
             gather=False, block=block, interpret=False)
+    elif layout == "words":
+        lowered = fused_timestep_words.lower(
+            spikes, sds((k, n_post), jnp.int8),
+            sds((1, n_post), jnp.float32), *state, all_nonzero=True,
+            block=block, interpret=False)
     else:
         lowered = fused_timestep_dense.lower(
             spikes, sds((k, n_post), jnp.float32), *state, block=block,
@@ -102,7 +110,7 @@ def test_recurrent_kernel_compiles_for_v5e(one_chip, batch):
     own last-step spikes, with the tile the engine picks."""
     kw = Z.spike_word_count(700) + Z.spike_word_count(1024)
     k, n = kw * Z.SPIKE_WORD_BITS, 1024
-    block = _pick_engine_block(batch, k, n, interpret=False, codebook=True,
+    block = _pick_engine_block(batch, k, n, interpret=False,
                                all_nonzero=True)
 
     def sds(shape, dtype):
@@ -113,4 +121,28 @@ def test_recurrent_kernel_compiles_for_v5e(one_chip, batch):
         sds((16, n), jnp.float32), sds((batch, n), jnp.float32),
         sds((batch, n), jnp.int32), gather=False, all_nonzero=True,
         block=block, interpret=False, name="snn_fused_l1")
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("all_nonzero", [True, False],
+                         ids=["nonzero", "zero-words"])
+@pytest.mark.parametrize("batch", [32, 12])
+def test_recurrent_word_kernel_compiles_for_v5e(one_chip, batch,
+                                                all_nonzero):
+    """The SHD recurrent layer on the word layout (int8 words, a step
+    row), with the tile the engine picks for that layout; with zero
+    words the touch counts take the bf16 nonzero-mask dot."""
+    kw = Z.spike_word_count(700) + Z.spike_word_count(1024)
+    k, n = kw * Z.SPIKE_WORD_BITS, 1024
+    block = _pick_engine_block(batch, k, n, interpret=False, layout="words",
+                               all_nonzero=all_nonzero)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = fused_timestep_words.lower(
+        sds((batch, kw), jnp.uint16), sds((k, n), jnp.int8),
+        sds((1, n), jnp.float32), sds((batch, n), jnp.float32),
+        sds((batch, n), jnp.int32), all_nonzero=all_nonzero, block=block,
+        interpret=False, name="snn_fused_l1")
     assert "tpu_custom_call" in lowered.compile().as_text()
