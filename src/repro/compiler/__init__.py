@@ -26,8 +26,9 @@ from repro.compiler import ir, partition as P, place as PL, route as R
 from repro.compiler import scaleup as SU
 from repro.compiler.ir import (ChipSpec, LayerSpec, NetworkGraph,
                                estimate_spike_rates, from_conv_config,
-                               from_layer_sizes, from_snn_config,
-                               from_weights, measure_spike_rates)
+                               from_conv_spec, from_layer_sizes,
+                               from_snn_config, from_weights,
+                               measure_spike_rates)
 from repro.compiler.partition import (CoreGroup, DomainPlan, assign_domains,
                                       group_traffic)
 from repro.compiler.place import (DomainPlacement, Placement,
@@ -41,7 +42,7 @@ __all__ = [
     "DomainPlan", "LayerSpec", "NetworkGraph",
     "Placement", "RoutedNetwork", "RouterTables", "ScaleUpPlan",
     "assign_domains", "compile_network", "derive_domain_seed",
-    "estimate_spike_rates", "from_conv_config",
+    "estimate_spike_rates", "from_conv_config", "from_conv_spec",
     "from_layer_sizes", "from_snn_config", "from_weights",
     "measure_spike_rates", "recompile", "repair", "route_hierarchical",
     "verify_roundtrip",
@@ -202,6 +203,7 @@ def compile_network(net: Any, chip: ChipSpec | None = None, *,
 
     groups = P.partition(graph, spec, spread=spread)
     flows = group_traffic(graph, groups)
+    conv = graph.conv_geometries()
     su = SU.plan(groups, spec)
     topo = faults is not None and faults.topology_faults()
     if topo:
@@ -250,11 +252,12 @@ def compile_network(net: Any, chip: ChipSpec | None = None, *,
             # a faulty fabric routes flat on the masked global adjacency
             routed = _route_or_raise(groups, placement.assignment,
                                      adjacency, su.level2_nodes, faults,
-                                     graph.recurrent)
+                                     graph.recurrent, conv)
         else:
             routed = R.route_hierarchical(groups, placement.assignment,
                                           su.adjacency, su.level2_nodes,
-                                          recurrent=graph.recurrent)
+                                          recurrent=graph.recurrent,
+                                          conv=conv)
     else:
         dplan, dplacements = None, None
         core_slots = su.core_slots
@@ -276,10 +279,12 @@ def compile_network(net: Any, chip: ChipSpec | None = None, *,
         baseline = PL.placement_cost(
             PL.contiguous_place(groups, core_slots), flows, dist)
         routed = (_route_or_raise(groups, placement.assignment, adjacency,
-                                  su.level2_nodes, faults, graph.recurrent)
+                                  su.level2_nodes, faults, graph.recurrent,
+                                  conv)
                   if topo
                   else R.route(groups, placement.assignment, su.adjacency,
-                               su.level2_nodes, recurrent=graph.recurrent))
+                               su.level2_nodes, recurrent=graph.recurrent,
+                               conv=conv))
     compiled = CompiledNetwork(net=graph, spec=spec, groups=groups,
                                placement=placement, plan=su, routed=routed,
                                baseline_cost=baseline, domain_plan=dplan,
@@ -292,13 +297,13 @@ def compile_network(net: Any, chip: ChipSpec | None = None, *,
 
 
 def _route_or_raise(groups, assignment, adjacency, level2_nodes, faults,
-                    recurrent=()):
+                    recurrent=(), conv=None):
     """Flat route on a fault-masked adjacency, with unroutable pairs
     surfaced as ValueError (the surviving graph is partitioned) instead
     of the routing table's bare assertion."""
     try:
         return R.route(groups, assignment, adjacency, level2_nodes,
-                       recurrent=recurrent)
+                       recurrent=recurrent, conv=conv)
     except AssertionError as e:
         raise ValueError(
             f"faults {faults.describe()} disconnect the surviving fabric: "

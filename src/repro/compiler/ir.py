@@ -25,18 +25,22 @@ import numpy as np
 
 from repro.core import energy as E
 from repro.core import noc as NOC
+from repro.core.conv import ConvGeometry
 
 
 @dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One mappable layer.  `index` 0 is the input population (never placed
-    on a core); placed layers start at index 1."""
+    on a core); placed layers start at index 1.  A conv layer may carry
+    its geometry (`conv`); its neurons then take input only from the
+    band of the layer before that their receptive fields cover."""
 
     index: int
     n_neurons: int
     fan_in: int
     kind: str = "dense"          # "input" | "dense" | "conv"
     name: str = ""
+    conv: ConvGeometry | None = None
 
     def __post_init__(self):
         if self.n_neurons <= 0:
@@ -83,6 +87,10 @@ class NetworkGraph:
     def layer_sizes(self) -> tuple[int, ...]:
         return tuple(l.n_neurons for l in self.layers)
 
+    def conv_geometries(self) -> dict[int, ConvGeometry]:
+        """{layer index: geometry} of the layers that carry one."""
+        return {l.index: l.conv for l in self.layers if l.conv is not None}
+
 
 # Default traffic estimate: event inputs fire at ~10% (the NMNIST regime);
 # each LIF stage attenuates traffic — deep layers both shrink and sparsify.
@@ -103,19 +111,30 @@ def estimate_spike_rates(layer_sizes: Sequence[int],
 def from_layer_sizes(layer_sizes: Sequence[int],
                      spike_rates: Sequence[float] | None = None,
                      kinds: Sequence[str] | None = None,
-                     recurrent: Sequence[int] = ()) -> NetworkGraph:
+                     recurrent: Sequence[int] = (),
+                     conv: dict | None = None) -> NetworkGraph:
     """A chain of `layer_sizes` (input first); layer indices in
     `recurrent` add a self-edge, which widens their fan-in by their own
-    width."""
+    width; `conv` maps layer indices to the `ConvGeometry` of a conv
+    layer, whose widths it must match."""
     sizes = [int(s) for s in layer_sizes]
     rec = tuple(sorted({int(p) for p in recurrent}))
+    conv = dict(conv or {})
+    for i, g in conv.items():
+        if not 1 <= i < len(sizes) or (g.n_in, g.n_out) != (
+                sizes[i - 1], sizes[i]):
+            raise ValueError(f"conv layer {i} {g} does not fit layer sizes "
+                             f"{sizes}")
+        if i in rec:
+            raise ValueError(f"conv layer {i} cannot be recurrent")
     kinds = list(kinds) if kinds is not None else (
-        ["input"] + ["dense"] * (len(sizes) - 1))
+        ["input"] + ["conv" if i in conv else "dense"
+                     for i in range(1, len(sizes))])
     layers = tuple(
         LayerSpec(index=i, n_neurons=n,
-                  fan_in=0 if i == 0 else sizes[i - 1] + (n if i in rec
-                                                          else 0),
-                  kind=kinds[i], name=f"L{i}")
+                  fan_in=(0 if i == 0 else conv[i].fan_in if i in conv
+                          else sizes[i - 1] + (n if i in rec else 0)),
+                  kind=kinds[i], name=f"L{i}", conv=conv.get(i))
         for i, n in enumerate(sizes))
     rates = (tuple(float(r) for r in spike_rates) if spike_rates is not None
              else estimate_spike_rates(sizes))
@@ -161,6 +180,69 @@ def from_conv_config(cfg, spike_rates: Sequence[float] | None = None
     rates = (tuple(float(r) for r in spike_rates) if spike_rates is not None
              else estimate_spike_rates(sizes))
     return NetworkGraph(layers=layers, spike_rates=rates)
+
+
+def parse_conv_spec(spec: str, padding: str = "same"
+                    ) -> tuple[int, list]:
+    """SLAYER's network notation (Shrestha & Orchard, NeurIPS 2018), e.g.
+    `34x34x2-12c5-2a-64c5-2a-10o` -> (input width, weight layers), each
+    a `ConvGeometry` or a dense width:
+
+    * `HxWxC`: the input map, first;
+    * `Nck`: a k x k conv to N channels at stride 1, `padding` "same"
+      (k // 2 on each side, k odd) or "valid";
+    * `ka`: a spiking k x k sum-pool of stride k, no padding (a conv
+      whose filter is diagonal over channels);
+    * `No`: a dense layer of N neurons (flattening the map), last.
+    """
+    if padding not in ("same", "valid"):
+        raise ValueError(f"padding must be 'same' or 'valid', got "
+                         f"{padding!r}")
+    head, *tokens = spec.split("-")
+    try:
+        h, w, c = (int(v) for v in head.split("x"))
+    except ValueError:
+        raise ValueError(f"{spec!r}: the input must be HxWxC") from None
+    if not tokens:
+        raise ValueError(f"{spec!r} has no layer")
+    n_in = h * w * c
+    layers: list = []
+    shape: tuple[int, int, int] | None = (h, w, c)
+    for i, tok in enumerate(tokens):
+        if shape is None:
+            raise ValueError(f"{spec!r}: {tok!r} follows the dense output")
+        h, w, c = shape
+        if "c" in tok:
+            n, k = (int(v) for v in tok.split("c"))
+            if padding == "same" and k % 2 == 0:
+                raise ValueError(f"{tok!r}: same padding needs an odd kernel")
+            g = ConvGeometry(h, w, c, n, k, 1,
+                             k // 2 if padding == "same" else 0)
+        elif tok.endswith("a"):
+            k = int(tok[:-1])
+            g = ConvGeometry(h, w, c, c, k, k, 0, pool=True)
+        elif tok.endswith("o"):
+            layers.append(int(tok[:-1]))
+            shape = None
+            continue
+        else:
+            raise ValueError(f"{spec!r}: cannot read layer {tok!r}")
+        layers.append(g)
+        shape = (g.out_height, g.out_width, g.c_out)
+    return n_in, layers
+
+
+def from_conv_spec(spec: str, padding: str = "same",
+                   spike_rates: Sequence[float] | None = None
+                   ) -> NetworkGraph:
+    """A network in SLAYER's notation (`parse_conv_spec`), its conv and
+    pool layers carrying their geometry."""
+    n_in, layers = parse_conv_spec(spec, padding)
+    sizes = [n_in] + [g.n_out if isinstance(g, ConvGeometry) else g
+                      for g in layers]
+    conv = {i + 1: g for i, g in enumerate(layers)
+            if isinstance(g, ConvGeometry)}
+    return from_layer_sizes(sizes, spike_rates, conv=conv)
 
 
 def measure_spike_rates(weights: Sequence, spike_train,

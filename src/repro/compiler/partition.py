@@ -213,13 +213,16 @@ def group_traffic(net: NetworkGraph, groups: list[CoreGroup]
                   ) -> list[tuple[int, int, float]]:
     """Inter-group spike flows: [(src_gid, dst_gid, spikes_per_timestep)].
 
-    Feed-forward connectivity is dense between consecutive layers, so every
-    spike a source group emits must reach *every* group of the next layer
-    (each holds a slice of the postsynaptic population).  A source group's
-    share of its layer's traffic is proportional to its neuron share.  A
-    recurrent layer's self-edge adds the same share from each of its
-    groups to every *other* group of the layer (a group's delivery to
-    its own core crosses no link, so it carries no placement cost).
+    A source group's spikes reach every group of the next layer that
+    holds a synapse from one of its neurons: every group, where the next
+    layer is dense (each holds a slice of the postsynaptic population),
+    and only those whose input band (`ConvGeometry.band`) meets the
+    source slice, where it is a conv layer with its geometry.  A source
+    group's share of its layer's traffic is proportional to its neuron
+    share.  A recurrent layer's self-edge adds the same share from each
+    of its groups to every *other* group of the layer (a group's
+    delivery to its own core crosses no link, so it carries no
+    placement cost).
     """
     by_layer: dict[int, list[CoreGroup]] = {}
     for g in groups:
@@ -229,10 +232,12 @@ def group_traffic(net: NetworkGraph, groups: list[CoreGroup]
         srcs = by_layer[layer.index]
         dsts = by_layer[layer.index + 1]
         rate = net.spike_rates[layer.index]
+        geom = net.layers[layer.index + 1].conv
         for s in srcs:
             share = rate * s.n_neurons / layer.n_neurons
             for d in dsts:
-                flows.append((s.gid, d.gid, share))
+                if geom is None or geom.reaches(s.lo, s.hi, d.lo, d.hi):
+                    flows.append((s.gid, d.gid, share))
     for index, rate in net.self_edges():
         members = by_layer[index]
         n = net.layers[index].n_neurons

@@ -1,8 +1,9 @@
 """Stage 3 — route: emit static per-CMRouter connection-matrix tables.
 
 For every inter-layer flow (all spikes a source core emits fan out to the
-cores holding the next layer, and a recurrent layer's also to every core
-of its own, that core included at zero hops) we resolve the
+cores holding the next layer, or, before a conv layer, to those of its
+cores whose input band meets the source slice; a recurrent layer's also
+to every core of its own, that core included at zero hops) we resolve the
 shortest-path route once, at compile time, into:
 
   * a `noc.FlowRoute` — the per-flow link set + hop/level-2 accounting the
@@ -112,38 +113,52 @@ class RoutedNetwork:
 
 
 def _flow_targets(groups: list[CoreGroup], assignment: dict[int, int],
-                  recurrent) -> list[tuple[int, list[CoreGroup], list[int]]]:
-    """(layer, its groups, the cores its spikes reach) for every layer
-    that fires into another: every core of the next layer and, for a
+                  recurrent, conv=None
+                  ) -> list[tuple[int, list[tuple[CoreGroup, list[int]]]]]:
+    """(layer, [(source group, the cores its spikes reach)]) for every
+    layer that fires into another: every core of the next layer (only
+    those whose input band meets the group's slice where the next layer
+    is a conv layer in `conv`, {layer index: ConvGeometry}) and, for a
     layer in `recurrent`, every core of its own (its own core at zero
     hops).  One multicast tree per source core reaches them all."""
     by_layer: dict[int, list[CoreGroup]] = {}
     for g in groups:
         by_layer.setdefault(g.layer, []).append(g)
     rec = {int(p) for p in recurrent}
+    conv = conv or {}
     targets = []
     for layer, srcs in sorted(by_layer.items()):
         dst_layers = [d for d in (layer + 1, layer)
                       if d in by_layer and (d != layer or layer in rec)]
-        if dst_layers:
+        if not dst_layers:
+            continue
+        geom = conv.get(layer + 1)
+        if geom is None:
             dst_cores = sorted({assignment[g.gid] for d in dst_layers
                                 for g in by_layer[d]})
-            targets.append((layer, srcs, dst_cores))
+            targets.append((layer, [(s, dst_cores) for s in srcs]))
+            continue
+        own = [assignment[g.gid] for g in srcs] if layer in rec else []
+        targets.append((layer, [
+            (s, sorted({assignment[d.gid] for d in by_layer[layer + 1]
+                        if geom.reaches(s.lo, s.hi, d.lo, d.hi)} | set(own)))
+            for s in srcs]))
     return targets
 
 
 def route(groups: list[CoreGroup], assignment: dict[int, int],
           adj: np.ndarray, level2_nodes: frozenset[int],
-          recurrent=()) -> RoutedNetwork:
+          recurrent=(), conv=None) -> RoutedNetwork:
     """Resolve every layer-to-layer flow (a layer in `recurrent` also
-    reaching its own cores) and program the router tables."""
+    reaching its own cores; a conv layer's cores only from the groups
+    their input bands meet) and program the router tables."""
     rt = NOC.RoutingTable(adj)
     tables = RouterTables(tables={})
     layer_flows: dict[int, list[NOC.FlowRoute]] = {}
-    for layer, srcs, dst_cores in _flow_targets(groups, assignment,
-                                                recurrent):
+    for layer, targets in _flow_targets(groups, assignment, recurrent,
+                                        conv):
         flows = []
-        for g in srcs:
+        for g, dst_cores in targets:
             src_core = assignment[g.gid]
             fr = NOC.compile_flow(rt, src_core, dst_cores, level2_nodes)
             flows.append(fr)
@@ -212,7 +227,7 @@ def _compose_flow(lrt: NOC.RoutingTable, src: int, dsts: list[int],
 
 def route_hierarchical(groups: list[CoreGroup], assignment: dict[int, int],
                        adj: np.ndarray, level2_nodes: frozenset[int],
-                       recurrent=()) -> RoutedNetwork:
+                       recurrent=(), conv=None) -> RoutedNetwork:
     """Resolve every flow from one shared local routing table: local
     paths for the intra-domain segments, the direct L2 -> L2 edge for the
     inter-chip crossing.  Emits FlowRoutes and RouterTables identical to
@@ -221,10 +236,10 @@ def route_hierarchical(groups: list[CoreGroup], assignment: dict[int, int],
     lrt = NOC.RoutingTable(NOC.fullerene_adjacency(with_level2=True))
     tables = RouterTables(tables={})
     layer_flows: dict[int, list[NOC.FlowRoute]] = {}
-    for layer, srcs, dst_cores in _flow_targets(groups, assignment,
-                                                recurrent):
+    for layer, targets in _flow_targets(groups, assignment, recurrent,
+                                        conv):
         flows = []
-        for g in srcs:
+        for g, dst_cores in targets:
             src_core = assignment[g.gid]
             flows.append(_compose_flow(lrt, src_core, dst_cores,
                                        level2_nodes))

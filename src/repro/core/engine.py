@@ -55,6 +55,14 @@ forward spikes and then its own spikes of the last step, which ride the
 scan carry beside the LIF states; a chain without plasticity carries the
 states alone.
 
+A conv layer (core/conv.py) runs as an XLA conv at HIGHEST precision in
+`CompiledEngine` and as one `snn_conv_l<i>` kernel
+(kernels/conv_timestep.py) from its filter's int8 words and one step in
+`FusedEngine` (`_build_conv_run`); both emit its per-core SOPs
+(`sops_core_<i>`), which the host prices in place of nnz x n_post, and
+count its cores' input bands and cycles as core/conv.py defines them.
+A network without a conv layer traces the same programs as before.
+
 The compiled and fused engines shard the batch across available devices
 with `shard_map` (batch axis, weights replicated) when the batch divides
 the device count.  Each `run_batch` enqueues the engine's XLA program
@@ -84,6 +92,7 @@ compiled/fused/reference sweep.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import TYPE_CHECKING
 
@@ -94,8 +103,8 @@ import numpy as np
 from repro.core import energy as E
 from repro.core import noc as NOC
 from repro.core import zspe as Z
-from repro.core.neuron import (LIFState, init_batch_state, init_state,
-                                lif_step, touch_mask)
+from repro.core.neuron import (LIFState, decay_table, init_batch_state,
+                                init_state, lif_step, touch_mask)
 
 if TYPE_CHECKING:  # pragma: no cover - import cycle guard (soc -> engine)
     from repro.core.soc import ChipReport, ChipSimulator
@@ -110,6 +119,29 @@ class LayerTables:
     slice_sizes: np.ndarray    # (A,) neurons held by each core slice
     core_index: np.ndarray     # (A,) dense index into the active-core list
     slice_onehot: np.ndarray   # (n_post, A) f32 neuron -> core-slice indicator
+    # a conv layer's per-core input bands (core/conv.py); None otherwise
+    conv: "ConvTables | None" = None
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvTables:
+    """A conv layer's per-core lowering: each slice's input band as an
+    indicator over input neurons and over input words, the words it
+    spans, and its synapses."""
+
+    band_onehot: np.ndarray    # (n_pre, A) f32
+    word_onehot: np.ndarray    # (input words, A) f32
+    band_words: np.ndarray     # (A,) f32 words each band spans
+    synapses: np.ndarray       # (A,) f32 synapses into each slice
+
+
+def slice_sums(x: jax.Array, onehot) -> jax.Array:
+    """(..., n) integers under 2**24 -> (..., A) f32 sums over each core
+    slice of `onehot` (n, A), exact: an f32 product at HIGHEST precision
+    keeps every integer (a TPU's default one-pass bf16 product would
+    round counts past 256)."""
+    return jnp.matmul(x.astype(jnp.float32), onehot,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -140,15 +172,26 @@ def lower_tables(sim: "ChipSimulator") -> EngineTables:
     layers = []
     for li, w in enumerate(sim.weights):
         asn = sim.mapping.cores_of_layer(li + 1)
-        n_post = int(w.shape[1])
+        conv = li in sim.conv
+        n_pre, n_post = ((sim.layer_sizes[li], sim.layer_sizes[li + 1])
+                         if conv else (int(w.shape[0]), int(w.shape[1])))
         onehot = np.zeros((n_post, len(asn)), np.float32)
         for i, a in enumerate(asn):
             onehot[a.neuron_lo:a.neuron_hi, i] = 1.0
+        ct = None
+        if conv:
+            tabs = sim.conv_slices(li)
+            band, words = tabs.onehots(n_pre)
+            ct = ConvTables(
+                band_onehot=band, word_onehot=words,
+                band_words=(tabs.words[:, 1] - tabs.words[:, 0]
+                            ).astype(np.float32),
+                synapses=tabs.synapses.astype(np.float32))
         layers.append(LayerTables(
-            n_pre=int(w.shape[0]), n_post=n_post,
+            n_pre=n_pre, n_post=n_post,
             slice_sizes=np.array([a.n_neurons for a in asn], np.float32),
             core_index=np.array([dense[a.core_id] for a in asn], np.int32),
-            slice_onehot=onehot))
+            slice_onehot=onehot, conv=ct))
     def table(routes):
         return NOC.compile_flow_table(routes, sim.router,
                                       n_nodes=sim.adj.shape[0],
@@ -158,7 +201,8 @@ def lower_tables(sim: "ChipSimulator") -> EngineTables:
     flows = tuple(table(sim._layer_routes[li + 1])
                   if li + 1 in sim._layer_routes else None
                   for li in range(L))
-    nominal = sum(lt.n_pre * lt.n_post for lt in layers)
+    nominal = sum(sim.conv_slices(li).total_synapses if lt.conv is not None
+                  else lt.n_pre * lt.n_post for li, lt in enumerate(layers))
     return EngineTables(layers=tuple(layers), flows=flows,
                         n_active_cores=len(active),
                         nominal_sops_per_step=nominal,
@@ -233,7 +277,7 @@ def _layer_tables(sim: "ChipSimulator", li: int) -> list | None:
               for a in sim.mapping.assignments if a.layer == li + 1]
     if not slices or any(rt is None for _, rt in slices):
         return None
-    if sum(a.n_neurons for a, _ in slices) != int(sim.weights[li].shape[1]):
+    if sum(a.n_neurons for a, _ in slices) != sim.layer_sizes[li + 1]:
         return None
     return slices
 
@@ -303,6 +347,76 @@ def _lower_word_layer(sim: "ChipSimulator", idx: np.ndarray, li: int,
     if np.abs(words.astype(np.int32)).sum(axis=0).max() >= 1 << 24:
         return None
     return words, step
+
+
+@dataclasses.dataclass(frozen=True)
+class ConvLayerWeights:
+    """A conv layer's operand for `kernels/conv_timestep.py`: the
+    filter's int8 table words and nonzero taps (k, k, C_in, C_out), as
+    every core of the layer holds them, and the table's step."""
+
+    geometry: "object"             # conv.ConvGeometry
+    words: jax.Array               # (k, k, C_in, C_out) int8
+    nonzero: jax.Array             # (k, k, C_in, C_out) int8 0/1
+    step: jax.Array                # (1, 1) f32
+
+    codebook_mode = True
+    word_layout = False            # the dense word kernel's layout: no
+
+    def hbm_bytes_per_step(self, batch: int) -> int:
+        """Filter words, nonzero taps, the step and the input spikes'
+        words, for one timestep at `batch`."""
+        return (self.words.size * 2 + 4
+                + batch * -(-self.geometry.n_in // Z.SPIKE_WORD_BITS) * 2)
+
+    def operands(self) -> tuple[jax.Array, jax.Array]:
+        """(words, nonzero) as the kernel takes them: (C_out, K), the taps
+        in `conv.tap_patches`' (c_in, dy, dx) order, with the channels
+        padded to a multiple of 8 and the taps to a multiple of 128, with
+        zeros."""
+        c = self.words.shape[-1]
+        k = math.prod(self.words.shape[:-1])
+        pad = ((0, -(-c // 8) * 8 - c), (0, -(-k // 128) * 128 - k))
+        return tuple(jnp.pad(jnp.transpose(a, (3, 2, 0, 1)).reshape(c, k),
+                             pad) for a in (self.words, self.nonzero))
+
+
+def lower_conv_words(sim: "ChipSimulator", li: int) -> ConvLayerWeights:
+    """Conv weight layer `li`'s filter as table words and one step.
+
+    Every core of the layer must hold the same table (the filter is
+    shared), each of its levels must be word x step exactly with int8
+    words, and each output channel's sum of |word| over the filter must
+    stay under 2**24, so the kernel's word sums are exact f32 integers
+    in any order; raises ValueError otherwise (a conv layer has no
+    dense fallback)."""
+    slices = _layer_tables(sim, li)
+    tables = {(rt.codebook_words, rt.codebook_scale)
+              for _, rt in slices or []}
+    if len(tables) != 1 or not next(iter(tables))[0]:
+        raise ValueError(f"conv weight layer {li}: its cores must hold one "
+                         f"programmed table, the shared filter's")
+    rt = slices[0][1]
+    tw = np.asarray(rt.codebook_words, np.int64)
+    st = np.float32(rt.codebook_scale)
+    cb = rt.codebook()
+    if not (np.isfinite(st) and st > 0) or tw.min() < -128 or tw.max() > 127 \
+            or not np.array_equal(tw * np.float64(st), cb.astype(np.float64)):
+        raise ValueError(f"conv weight layer {li}: its table is not int8 "
+                         f"words x one step")
+    w = np.asarray(sim.weights[li], np.float32)
+    ii = np.argmin(np.abs(w[..., None] - cb), axis=-1)
+    if not np.array_equal(cb[ii], w):
+        raise ValueError(f"conv weight layer {li}: the filter is not its "
+                         f"table's levels")
+    words = tw[ii]
+    if np.abs(words).sum(axis=(0, 1, 2)).max() >= 1 << 24:
+        raise ValueError(f"conv weight layer {li}: a channel's word sums "
+                         f"reach 2**24, past exact f32")
+    return ConvLayerWeights(
+        geometry=sim.conv[li], words=jnp.asarray(words, jnp.int8),
+        nonzero=jnp.asarray(w != 0, jnp.int8),
+        step=jnp.full((1, 1), st, jnp.float32))
 
 
 def lower_plasticity_tables(sim: "ChipSimulator"):
@@ -392,10 +506,15 @@ def _pad_streams(rows: np.ndarray, widths: list[int]) -> np.ndarray:
     return np.concatenate(parts)
 
 
-def lower_fused_weights(sim: "ChipSimulator") -> tuple[FusedLayerWeights, ...]:
-    """Lower every layer to its fused-kernel weight operand."""
+def lower_fused_weights(sim: "ChipSimulator"
+                        ) -> tuple[FusedLayerWeights | ConvLayerWeights, ...]:
+    """Lower every layer to its fused-kernel weight operand (a conv
+    layer to its filter's words, `lower_conv_words`)."""
     out = []
     for li, w in enumerate(sim.weights):
+        if li in sim.conv:
+            out.append(lower_conv_words(sim, li))
+            continue
         n_pre, n_post = int(w.shape[0]), int(w.shape[1])
         # input streams: the forward spikes, then a recurrent layer's own
         streams = ([n_pre - n_post, n_post] if li in sim.recurrent
@@ -489,6 +608,7 @@ class _EngineBase:
         self.last_elig = None        # per-layer eligibility (reward mode)
         self.calls = 0               # run_batch calls so far (span `call`)
         self.word_layers = 0         # layers on the fused word layout
+        self.conv_layers = 0         # layers on the fused conv kernel
 
     # -- trace construction (subclass hooks) --------------------------------
 
@@ -610,8 +730,9 @@ class _EngineBase:
 
         Each phase runs under a `jax.profiler.TraceAnnotation` span, on
         the profiler's clock beside the device ops: `snn.run_batch`
-        (stats `call`, `batch`, `steps`, and `word_layers` where a fused
-        layer runs on the word layout) holds `snn.upload` (`bytes`),
+        (stats `call`, `batch`, `steps`, `word_layers` where a fused
+        layer runs on the word layout, and `conv_layers` where fused
+        layers run on the conv kernel) holds `snn.upload` (`bytes`),
         `snn.dispatch`, `snn.device_wait` (launches the slab's program
         and waits for it), `snn.readback` (`transfers`: 1, `bytes`),
         `snn.noc_replay` (`flows`: the flows replayed; `back_flows`:
@@ -623,6 +744,8 @@ class _EngineBase:
         stats = dict(call=self.calls, batch=int(batch), steps=int(steps))
         if self.word_layers:
             stats["word_layers"] = self.word_layers
+        if self.conv_layers:
+            stats["conv_layers"] = self.conv_layers
         with jax.profiler.TraceAnnotation("snn.run_batch", **stats):
             return self._run_batch(spike_trains, learned)
 
@@ -637,6 +760,13 @@ class _EngineBase:
         if self.trace.enabled:
             keys += [f"touched_core_{li}"
                      for li in range(len(self.tables.layers))]
+        # a conv layer's per-core SOPs, and under trace its per-core
+        # input spikes
+        conv = [li for li, lt in enumerate(self.tables.layers)
+                if lt.conv is not None]
+        keys += [f"sops_core_{li}" for li in conv]
+        if self.trace.enabled:
+            keys += [f"nnz_core_{li}" for li in conv]
         return keys
 
     def _run_batch(self, spike_trains, learned):
@@ -690,7 +820,15 @@ class _EngineBase:
         n_posts = np.array([lt.n_post for lt in tbl.layers], np.float64)
         nnz = host["nnz"]                                # (B, T, L)
         spikes_in = nnz.sum(axis=(1, 2))
-        performed = (nnz * n_posts).sum(axis=(1, 2))
+        conv = [li for li, lt in enumerate(tbl.layers) if lt.conv is not None]
+        if conv:
+            # a conv layer's spikes perform their own synapses, per core
+            dense = np.where([lt.conv is None for lt in tbl.layers],
+                             n_posts, 0.0)
+            performed = (nnz * dense).sum(axis=(1, 2)) + sum(
+                host[f"sops_core_{li}"].sum(axis=(1, 2)) for li in conv)
+        else:
+            performed = (nnz * n_posts).sum(axis=(1, 2))
         neurons_touched = host["touched"].sum(axis=(1, 2))
         core_wall = host["wall"]                         # (B, T) core-only
         skipped_words = (host["skip_words"].sum(axis=(1, 2))
@@ -735,6 +873,18 @@ class _EngineBase:
             # counters — one implementation for all three engines
             from repro.telemetry.trace import build_trace
 
+            core_nnz = core_sops = None
+            if conv:
+                # per slice; a dense slice's are its layer's nnz, and
+                # nnz x its neurons
+                core_nnz = np.concatenate([
+                    host[f"nnz_core_{li}"] if lt.conv is not None else
+                    np.repeat(nnz[..., li:li + 1], len(lt.slice_sizes), -1)
+                    for li, lt in enumerate(tbl.layers)], axis=-1)
+                core_sops = np.concatenate([
+                    host[f"sops_core_{li}"] if lt.conv is not None else
+                    nnz[..., li:li + 1] * lt.slice_sizes.astype(np.float64)
+                    for li, lt in enumerate(tbl.layers)], axis=-1)
             self.last_trace = build_trace(
                 sim,
                 np.concatenate([host[f"fired_core_{li}"]
@@ -744,7 +894,8 @@ class _EngineBase:
                 nnz,
                 host["skip_words"] if self.trace.skip_words
                 and "skip_words" in host else None,
-                weight_writes=writes)
+                weight_writes=writes, core_nnz=core_nnz,
+                core_sops=core_sops)
 
         with jax.profiler.TraceAnnotation("snn.price"):
             priced = E.price_batched(
@@ -807,6 +958,7 @@ class CompiledEngine(_EngineBase):
         tbl = self.tables
         weights = tuple(sim.weights)
         nonzero_w = tuple(sim.nonzero_weights)
+        conv = sim.conv
         lif = sim.lif
         cyc = sim.cycle_model
         n_active = tbl.n_active_cores
@@ -828,7 +980,44 @@ class CompiledEngine(_EngineBase):
                 for li, pt in enumerate(self.plast_tables) if pt is not None}
         reward = plast.mode == "reward"
 
-        def layer_steps(carry, xs):
+        def conv_step(li, x, state, wall, decays):
+            """Conv weight layer `li` of one sample's timestep (an XLA
+            conv at HIGHEST precision): -> (its spikes, its counters,
+            its per-core counts)."""
+            from repro.core.conv import conv_spikes
+
+            geom = conv[li]
+            lt, slices, core_idx, onehot = layer_consts[li]
+            ct = lt.conv
+            current = conv_spikes(x, weights[li], geom)
+            tcnt = conv_spikes(x, nonzero_w[li], geom)
+            st, out, touched = lif_step(state, current, lif,
+                                        touched=tcnt > 0, decays=decays)
+            sops_core = slice_sums(tcnt, onehot)
+            core_touched = touched.astype(jnp.float32) @ onehot
+            core_cyc = cyc.conv_timestep_cycles_array(
+                jnp.asarray(ct.band_words), sops_core, core_touched,
+                jnp.asarray(ct.synapses), slices, sim.zero_skip,
+                sim.partial_update)
+            wall = wall + jax.ops.segment_sum(core_cyc, core_idx,
+                                              num_segments=n_active)
+            counts = {"state": st, "wall": wall,
+                      "nnz": jnp.sum(x != 0).astype(jnp.float32),
+                      "touched": jnp.sum(touched).astype(jnp.float32),
+                      "fired": jnp.sum(out).astype(jnp.float32)}
+            cores = {f"sops_core_{li}": sops_core}
+            if has_flow[li] or traced:
+                cores[f"fired_core_{li}"] = out @ onehot
+            if traced:
+                cores[f"touched_core_{li}"] = core_touched
+                cores[f"nnz_core_{li}"] = (
+                    (x != 0).astype(jnp.float32) @ ct.band_onehot)
+            if trace_skips:
+                empty = (Z.pack_spike_words(x) == 0).astype(jnp.float32)
+                counts["skip"] = jnp.sum(empty @ ct.word_onehot)
+            return out, counts, cores
+
+        def layer_steps(carry, xs, decays=None):
             """One timestep of every layer.  The carry holds the LIF
             states, each recurrent layer's last-step spikes (`fed`) and
             each learnable layer's (indexes, pre trace, post trace,
@@ -843,6 +1032,20 @@ class CompiledEngine(_EngineBase):
             new_fed = {}
             new_learn = {}
             for li, w in enumerate(weights):
+                if li in conv:
+                    with jax.named_scope(f"snn_compiled_l{li + 1}"):
+                        spikes, counts, cores = conv_step(
+                            li, spikes, states[li], wall, decays)
+                    new_states.append(counts.pop("state"))
+                    wall = counts.pop("wall")
+                    fired_cores.update(cores)
+                    nnzs.append(counts["nnz"])
+                    toucheds.append(counts["touched"])
+                    fireds.append(counts["fired"])
+                    wr.append(jnp.float32(0.0))
+                    if trace_skips:
+                        skips.append(counts["skip"])
+                    continue
                 with jax.named_scope(f"snn_compiled_l{li + 1}"):
                     lt, slices, core_idx, onehot = layer_consts[li]
                     # a recurrent layer reads its forward input, then
@@ -870,7 +1073,7 @@ class CompiledEngine(_EngineBase):
                         x, w, precision=Z.CURRENT_PRECISION)
                     st, out, touched = lif_step(
                         states[li], current, lif,
-                        touched=touch_mask(x, nzw))
+                        touched=touch_mask(x, nzw), decays=decays)
                     new_states.append(st)
                     if li in fed:
                         new_fed[li] = out
@@ -934,7 +1137,7 @@ class CompiledEngine(_EngineBase):
             return (tuple(new_states), new_fed, new_learn), ys
 
         def one_sample(train, idx0=None):
-            states = tuple(init_state(int(w.shape[1])) for w in weights)
+            states = tuple(init_state(lt.n_post) for lt in tbl.layers)
             fed = {li: jnp.zeros((int(weights[li].shape[1]),), jnp.float32)
                    for li in sim.recurrent}
             learn = {li: (idx0[li],
@@ -945,8 +1148,13 @@ class CompiledEngine(_EngineBase):
                      for li in cbws}
             xs = (train if drop is None
                   else (train, jnp.arange(train.shape[0])))
+            # a network with conv layers takes every leak exact
+            # (`decay_table`)
+            decays = (decay_table(float(lif.leak), train.shape[0])
+                      if conv and lif.partial_update else None)
             (_, _, learn), ys = jax.lax.scan(
-                layer_steps, (states, fed, learn), xs)
+                functools.partial(layer_steps, decays=decays),
+                (states, fed, learn), xs)
             for li, (idx, _, _, elig) in learn.items():
                 ys[f"learned_idx_{li}"] = idx
                 if reward:
@@ -1013,11 +1221,13 @@ class ShardedEngine(_EngineBase):
 
     def __init__(self, sim: "ChipSimulator", shard: bool = True,
                  n_shards: int | None = None):
-        if sim.recurrent:
-            raise NotImplementedError(
-                "ShardedEngine runs chains only: a recurrent layer's "
-                "fed-back spikes would need their own cross-shard exchange "
-                "in the scan carry; use engine='compiled' or 'fused'")
+        from repro.core.soc import refuse_layers
+
+        refuse_layers(
+            sim, ["recurrent", "conv"],
+            "ShardedEngine runs chains only (a recurrent layer's fed-back "
+            "spikes and a conv layer's input bands would need their own "
+            "cross-shard exchange; use engine='compiled' or 'fused')")
         super().__init__(sim, shard=shard)
         max_node = max(a.core_id for a in sim.mapping.assignments)
         self.n_domains = (max_node // NOC.DOMAIN_STRIDE + 1
@@ -1442,6 +1652,8 @@ class FusedEngine(_EngineBase):
         super().__init__(sim, shard=shard)
         self.fused_weights = lower_fused_weights(sim)
         self.word_layers = sum(lw.word_layout for lw in self.fused_weights)
+        self.conv_layers = sum(isinstance(lw, ConvLayerWeights)
+                               for lw in self.fused_weights)
         self.last_states = None      # final LIF states of the last run
 
     @property
@@ -1477,7 +1689,7 @@ class FusedEngine(_EngineBase):
                       reset=float(lif.reset),
                       partial_update=bool(lif.partial_update))
 
-        def layer_apply(li, packed, state):
+        def layer_apply(li, packed, state, decays=None):
             lw = fused_w[li]
             layout = ("words" if lw.word_layout else
                       "codebook" if lw.codebook_mode else "dense")
@@ -1488,7 +1700,8 @@ class FusedEngine(_EngineBase):
                 all_nonzero=lw.all_nonzero)
             # a stable kernel name per layer, for the profile's op line
             kw = dict(all_nonzero=lw.all_nonzero, block=block,
-                      interpret=interp, name=f"snn_fused_l{li + 1}", **lif_kw)
+                      interpret=interp, name=f"snn_fused_l{li + 1}",
+                      decays=decays, **lif_kw)
             if layout == "words":
                 return fused_timestep_words(packed, lw.words, lw.step,
                                             state.v, state.elapsed, **kw)
@@ -1544,6 +1757,10 @@ class FusedEngine(_EngineBase):
             ys["wall"] = jnp.max(wall, axis=-1)
             ys["out"] = out
             return ys
+
+        if self.conv_layers:
+            return self._build_conv_run(layer_apply, layer_counters,
+                                        stack_counters)
 
         def step(carry, xs):   # (states, last spike words), (B, kw0) [+ t]
             """One timestep of every layer, a recurrent layer taking its
@@ -1696,6 +1913,200 @@ class FusedEngine(_EngineBase):
             carry = (zero_states(B), list(idx0), xpre0, xpost0, elig0)
             final, ys = scan_trains(step_plast, carry, trains)
             return batch_major(ys), final
+
+        return run
+
+    def _build_conv_run(self, layer_apply, layer_counters, stack_counters):
+        """The program of a network with conv layers (no recurrent layer,
+        plasticity or packet drop: the simulator refuses those).  A conv
+        layer-step is one `snn_conv_l<i>` kernel (kernels/
+        conv_timestep.py) on its input's tap patches; a dense one keeps
+        its `snn_fused_l<i>` kernel.  A conv layer's rows in the step's
+        int32 block are its output spike words and its per-core SOPs and
+        touched neurons; its input band counts are read after the scan,
+        from its input's spikes (the trains, or the layer before's
+        rows)."""
+        from repro.core.conv import tap_patches
+        from repro.kernels.conv_timestep import conv_timestep, pick_lane_tile
+        from repro.kernels.ops import interpret_default
+
+        sim = self.sim
+        tbl = self.tables
+        cyc = sim.cycle_model
+        n_active = tbl.n_active_cores
+        interp = interpret_default()
+        fused_w = self.fused_weights
+        L = len(fused_w)
+        conv_ops = {li: lw.operands() for li, lw in enumerate(fused_w)
+                    if isinstance(lw, ConvLayerWeights)}
+        has_flow = [ft is not None for ft in tbl.flows]
+        traced = self.trace.enabled
+        lif = sim.lif
+        lif_kw = dict(threshold=float(lif.threshold), leak=float(lif.leak),
+                      reset=float(lif.reset),
+                      partial_update=bool(lif.partial_update))
+
+        def conv_shape(li, batch):
+            """(channels, pixels, lane tile, padded pixels) of conv
+            layer `li`'s kernel at `batch`."""
+            g = fused_w[li].geometry
+            c, k = conv_ops[li][0].shape
+            m = batch * g.out_height * g.out_width
+            bm = pick_lane_tile(c, k, m, interp)
+            return c, m, bm, -(-m // bm) * bm
+
+        def to_hwc(a, li, batch):
+            """(channels, pixels) kernel layout -> (B, n_out) HWC."""
+            g = fused_w[li].geometry
+            pix = g.out_height * g.out_width
+            a = a[:g.c_out, :batch * pix].reshape(g.c_out, batch, pix)
+            return jnp.transpose(a, (1, 2, 0)).reshape(batch, pix * g.c_out)
+
+        def conv_apply(li, x, state, decays):
+            """(B, n_in) 0/1 spikes through conv layer `li`: -> (state,
+            spikes (B, n_out), per-core SOPs and touched (B, A))."""
+            lw, (words, nonzero) = fused_w[li], conv_ops[li]
+            B = x.shape[0]
+            _, m, bm, m_pad = conv_shape(li, B)
+            p = tap_patches(x, lw.geometry)
+            p = jnp.pad(p, ((0, words.shape[1] - p.shape[0]),
+                            (0, m_pad - m)))
+            vo, eo, sp, tc = conv_timestep(
+                p, words, nonzero, lw.step, state.v, state.elapsed,
+                block=None if interp else bm, interpret=interp,
+                name=f"snn_conv_l{li + 1}", decays=decays, **lif_kw)
+            tcnt = to_hwc(tc, li, B)
+            touched = tcnt > 0 if lif.partial_update else \
+                jnp.ones(tcnt.shape, bool)
+            onehot = tbl.layers[li].slice_onehot
+            return (LIFState(v=vo, elapsed=eo), to_hwc(sp, li, B),
+                    slice_sums(tcnt, onehot), slice_sums(touched, onehot))
+
+        def step(states, packed, decays):    # LIF states, (B, kw0) words
+            new_states, rows = [], []
+            x = None                          # the last layer's 0/1 spikes
+            for li in range(L):
+                if li in conv_ops:
+                    if x is None:
+                        x = Z.unpack_spike_words(packed, tbl.layers[li].n_pre)
+                    st, out, sops, touched = conv_apply(li, x, states[li],
+                                                        decays)
+                    new_states.append(st)
+                    packed = Z.pack_spike_words(out)
+                    rows += [packed.astype(jnp.int32),
+                             sops.astype(jnp.int32),
+                             touched.astype(jnp.int32)]
+                else:
+                    vo, eo, out, tc, nnz_rows, ew = layer_apply(
+                        li, packed, states[li], decays)
+                    new_states.append(LIFState(v=vo, elapsed=eo))
+                    rows += [out.astype(jnp.int32), tc, nnz_rows, ew]
+                    packed = Z.pack_spike_words(out)
+                x = out
+            return tuple(new_states), jnp.concatenate(rows, axis=-1)
+
+        # the row block's columns: per conv layer (spike words, per-core
+        # SOPs, per-core touched), per dense one (out, touched, nnz, empty
+        # words)
+        row_slices, lo = [], 0
+        for li, lw in enumerate(fused_w):
+            n_cores = len(tbl.layers[li].slice_sizes)
+            widths = ((Z.spike_word_count(lw.geometry.n_out), n_cores,
+                       n_cores) if li in conv_ops
+                      else (lw.n_post, lw.n_post, 1, 1))
+            cols = []
+            for width in widths:
+                cols.append((lo, lo + width))
+                lo += width
+            row_slices.append(cols)
+
+        def conv_counters(li, wall, in_bits, in_words, out, sops, touched):
+            """Conv layer `li`'s counters over (T, B): its input band
+            counts from its input's spikes and words, its cycles from its
+            per-core SOPs and touched neurons."""
+            lt = tbl.layers[li]
+            ct = lt.conv
+            empty = (in_words == 0).astype(jnp.float32) @ ct.word_onehot
+            core_cyc = cyc.conv_timestep_cycles_array(
+                jnp.asarray(ct.band_words), sops, touched,
+                jnp.asarray(ct.synapses), jnp.asarray(lt.slice_sizes),
+                sim.zero_skip, sim.partial_update)
+            per_core = lambda c: jax.ops.segment_sum(  # noqa: E731
+                c, jnp.asarray(lt.core_index), num_segments=n_active)
+            for _ in range(core_cyc.ndim - 1):
+                per_core = jax.vmap(per_core)
+            cores = {f"sops_core_{li}": sops}
+            if has_flow[li] or traced:
+                cores[f"fired_core_{li}"] = out @ lt.slice_onehot
+            if traced:
+                cores[f"touched_core_{li}"] = touched
+                cores[f"nnz_core_{li}"] = in_bits @ ct.band_onehot
+            counts = {"nnz": jnp.sum(in_bits, axis=-1),
+                      "touched": jnp.sum(touched, axis=-1),
+                      "fired": jnp.sum(out, axis=-1),
+                      "skip_words": jnp.sum(empty, axis=-1)}
+            return wall + per_core(core_cyc), counts, cores
+
+        def counters(rows, packed_t):        # (T, B, W) -> ys, T leading
+            wall = jnp.zeros(rows.shape[:2] + (n_active,), jnp.float32)
+            in_words = packed_t
+            in_bits = None
+            layers = []
+            for li in range(L):
+                cols = [rows[..., a:b] for a, b in row_slices[li]]
+                n_pre = tbl.layers[li].n_pre
+                if li in conv_ops:
+                    words, sops, touched = cols
+                    if in_bits is None:
+                        in_bits = Z.unpack_spike_words(in_words, n_pre)
+                    if in_words is None:
+                        in_words = Z.pack_spike_words(in_bits)
+                    out = Z.unpack_spike_words(words.astype(jnp.uint16),
+                                               tbl.layers[li].n_post)
+                    wall, counts, cores = conv_counters(
+                        li, wall, in_bits, in_words, out,
+                        sops.astype(jnp.float32),
+                        touched.astype(jnp.float32))
+                    in_words = words
+                else:
+                    out, tc, nnz_rows, ew = cols
+                    out = out.astype(jnp.float32)
+                    wall, counts, cores = layer_counters(li, wall, out, tc,
+                                                         nnz_rows, ew)
+                    in_words = None
+                in_bits = out
+                layers.append((counts, cores))
+            return stack_counters(wall, out, layers)
+
+        def zero_states(batch):
+            states = []
+            for li, lw in enumerate(fused_w):
+                if li in conv_ops:
+                    c, _, _, m_pad = conv_shape(li, batch)
+                    states.append(LIFState(
+                        v=jnp.zeros((c, m_pad), jnp.float32),
+                        elapsed=jnp.zeros((c, m_pad), jnp.int32)))
+                else:
+                    states.append(init_batch_state(batch, lw.n_post))
+            return tuple(states)
+
+        def run(trains):                     # (B, T, n_in) f32
+            B = trains.shape[0]
+            packed_t = jnp.swapaxes(Z.pack_spike_words(trains), 0, 1)
+            # every layer's leak exact (`decay_table`), conv or dense
+            decays = (decay_table(float(lif.leak), trains.shape[1])
+                      if lif.partial_update else None)
+            states, rows = jax.lax.scan(
+                functools.partial(step, decays=decays), zero_states(B),
+                packed_t)
+            # final states in the (B, n_post) neuron order
+            states = tuple(
+                LIFState(v=to_hwc(st.v, li, B),
+                         elapsed=to_hwc(st.elapsed, li, B))
+                if li in conv_ops else st for li, st in enumerate(states))
+            ys = jax.tree_util.tree_map(lambda a: jnp.swapaxes(a, 0, 1),
+                                        counters(rows, packed_t))
+            return ys, states                # ys batch-major
 
         return run
 

@@ -17,6 +17,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,9 +70,19 @@ def _spike_bwd(res, g):
 spike_fn.defvjp(_spike_fwd, _spike_bwd)
 
 
+def decay_table(leak: float, steps: int) -> tuple[float, ...]:
+    """`leak**n` for n = 0..steps, each the correctly rounded f32 power
+    (NumPy's float32 power).  XLA's `pow` is not: on the CPU it is an ulp
+    off at n = 4, 17, 18, 19, ..., and a TPU's differs elsewhere, so a
+    membrane near the threshold can fire on one device and not another.
+    A run of `steps` timesteps never has more than `steps` pending."""
+    n = np.arange(steps + 1, dtype=np.float32)
+    return tuple(float(x) for x in np.float32(leak) ** n)
+
+
 def lif_step(
     state: LIFState, current: jax.Array, p: LIFParams,
-    touched: jax.Array | None = None,
+    touched: jax.Array | None = None, decays: tuple | None = None,
 ) -> tuple[LIFState, jax.Array, jax.Array]:
     """One LIF timestep.
 
@@ -92,12 +103,17 @@ def lif_step(
     update: untouched neurons accumulate pending leak steps in ``elapsed``
     and apply ``leak**elapsed`` lazily when next touched (or when read out).
     This mirrors the chip, where the updater stores a timestep stamp.
+    `decays` (`decay_table`) gives `leak**pending` exactly, in place of
+    XLA's `pow`.
     """
     has_input = (current != 0.0) if touched is None else touched
     if p.partial_update:
         pending = state.elapsed + 1
+        leak_n = (p.leak ** pending.astype(state.v.dtype) if decays is None
+                  else jnp.asarray(decays, state.v.dtype)[
+                      jnp.minimum(pending, len(decays) - 1)])
         # Lazy leak: apply alpha**pending only for touched neurons.
-        decay = jnp.where(has_input, p.leak ** pending.astype(state.v.dtype), 1.0)
+        decay = jnp.where(has_input, leak_n, 1.0)
         v_int = state.v * decay + current
         # Untouched neurons keep raw v and bump `elapsed`.
         new_elapsed = jnp.where(has_input, 0, pending)

@@ -191,14 +191,17 @@ def validate_capacity(layer_sizes: Sequence[int],
 def map_network(layer_sizes: Sequence[int],
                 neurons_per_core: int = E.NEURONS_PER_CORE,
                 strategy: str = "greedy", seed: int = 0,
-                recurrent: Sequence[int] = ()) -> Mapping:
+                recurrent: Sequence[int] = (),
+                conv: dict | None = None) -> Mapping:
     """Place an SNN onto the 20 cores.
 
     strategy "greedy" is the legacy contiguous layout (layers onto cores in
     id order, traffic-blind, no spreading).  Any other value is forwarded
     to the mapping compiler (repro.compiler.compile_network), e.g.
     "anneal" — traffic-aware placement with simulated-annealing refinement,
-    which also weighs the self-edges of the layer indices in `recurrent`.
+    which also weighs the self-edges of the layer indices in `recurrent`
+    and the local flows into the conv layers of `conv` ({layer index:
+    ConvGeometry}).
 
     Layer 0 is the input population (not placed).  Raises ValueError when
     the network exceeds chip capacity.
@@ -209,7 +212,8 @@ def map_network(layer_sizes: Sequence[int],
 
         spec = CC.ChipSpec(neurons_per_core=neurons_per_core)
         compiled = CC.compile_network(
-            CC.from_layer_sizes(layer_sizes, recurrent=recurrent), spec,
+            CC.from_layer_sizes(layer_sizes, recurrent=recurrent,
+                                conv=conv), spec,
             strategy=strategy, seed=seed)
         return compiled.to_soc_mapping()
     cores = list(NOC.core_ids())
@@ -329,25 +333,56 @@ def _reject_index_like(w, layer: int, quant_cfg: CodebookConfig | None) -> None:
                 f"from quant.quantize(), or the dequantized float weights")
 
 
-def _layer_sizes(weights, recurrent: Sequence[int]) -> list[int]:
+def _layer_sizes(weights, recurrent: Sequence[int],
+                 conv: dict | None = None) -> list[int]:
     """Population widths, input first, of weight layers that each feed
     the next; a weight layer in `recurrent` holds (n_pre + n_post,
-    n_post) rows, its forward input's and then its own."""
-    sizes = [int(w.shape[1]) for w in weights]
-    n_in = int(weights[0].shape[0]) - (sizes[0] if 0 in recurrent else 0)
+    n_post) rows, its forward input's and then its own; a conv layer
+    (`conv`, {weight layer: ConvGeometry}) holds its geometry's filter
+    and maps its `n_in` inputs to `n_out` neurons."""
+    conv = conv or {}
+    for li, g in conv.items():
+        if tuple(int(d) for d in weights[li].shape) != g.filter_shape:
+            raise ValueError(
+                f"conv weight layer {li}: filter of shape "
+                f"{tuple(weights[li].shape)}, its geometry needs "
+                f"{g.filter_shape}")
+    dims = [(conv[li].n_in, conv[li].n_out) if li in conv
+            else (int(w.shape[0]), int(w.shape[1]))
+            for li, w in enumerate(weights)]
+    sizes = [n for _, n in dims]
+    n_in = dims[0][0] - (sizes[0] if 0 in recurrent else 0)
     sizes = [n_in] + sizes
     for li in recurrent:
         if not 0 <= li < len(weights):
             raise ValueError(f"recurrent layer {li} is not one of the "
                              f"{len(weights)} weight layers")
-    for li, w in enumerate(weights):
+    for li, (rows, _) in enumerate(dims):
         want = sizes[li] + (sizes[li + 1] if li in recurrent else 0)
-        if int(w.shape[0]) != want or n_in <= 0:
-            kind = "recurrent " if li in recurrent else ""
+        if rows != want or n_in <= 0:
+            kind = ("recurrent " if li in recurrent else
+                    "conv " if li in conv else "")
             raise ValueError(
-                f"{kind}weight layer {li} has {int(w.shape[0])} rows; its "
+                f"{kind}weight layer {li} has {rows} rows; its "
                 f"input needs {want} (layer sizes {sizes})")
     return sizes
+
+
+def layer_kind(sim: "ChipSimulator", li: int) -> str:
+    """"conv", "recurrent" or "dense": how weight layer `li` connects."""
+    return ("conv" if li in sim.conv else
+            "recurrent" if li in sim.recurrent else "dense")
+
+
+def refuse_layers(sim: "ChipSimulator", kinds: Sequence[str],
+                  reason: str) -> None:
+    """Raise `NotImplementedError` naming the first weight layer of one
+    of `kinds` and its kind, after `reason` (what does not run it)."""
+    for li in range(len(sim.weights)):
+        kind = layer_kind(sim, li)
+        if kind in kinds:
+            raise NotImplementedError(
+                f"{reason}: weight layer {li} is a {kind} layer")
 
 
 @dataclasses.dataclass
@@ -420,6 +455,16 @@ class ChipSimulator:
     they fire, and count as input spikes, performed SOPs and touches of
     the next step, like any other input spike.
 
+    A weight given as a `conv.ConvLayer` (a `ConvGeometry` and a
+    `QuantizedTensor` of its (k, k, C_in, C_out) filter) is a conv or
+    spiking-pool layer: its cores hold the one shared filter, each core
+    scans the input band its neurons' receptive fields cover, and the
+    spikes of the layer before reach only the cores whose band holds
+    them (core/conv.py defines the per-core counters and cycles).  Its
+    weight in `weights` is then the dequantized filter; `layer_sizes`
+    gives every population's width.  The sharded engine, plasticity,
+    packet drop and fault injection refuse a conv layer.
+
     Three execution engines share one lowered mapping:
 
     * ``engine="compiled"`` (default) — `repro.core.engine.CompiledEngine`:
@@ -468,10 +513,22 @@ class ChipSimulator:
     ):
         from repro.core.neuron import LIFParams  # local import to avoid cycle
         from repro.core import quant as Q
+        from repro.core.conv import ConvLayer
         from repro.telemetry.trace import TraceConfig
         from repro.faults import model as FM
 
         weights = list(weights)
+        # a conv layer: its geometry, and its filter as the layer's weight
+        self.conv = {li: w.geometry for li, w in enumerate(weights)
+                     if isinstance(w, ConvLayer)}
+        for li in self.conv:
+            f = weights[li].filter
+            if not isinstance(f, Q.QuantizedTensor) or f.group_axis_size:
+                raise TypeError(
+                    f"conv weight layer {li}: the filter must be a "
+                    f"QuantizedTensor under one whole-tensor codebook (its "
+                    f"cores share one register table)")
+            weights[li] = f
         n_quant = sum(isinstance(w, Q.QuantizedTensor) for w in weights)
         if 0 < n_quant < len(weights):
             raise TypeError(
@@ -510,11 +567,15 @@ class ChipSimulator:
                 _reject_index_like(w, li, quant_cfg)
             self.weights = [jnp.asarray(w, jnp.float32) for w in weights]
         self.recurrent = tuple(sorted({int(li) for li in recurrent}))
-        sizes = _layer_sizes(self.weights, self.recurrent)
+        refuse_layers(self, ["conv"] if self.recurrent else [],
+                      "conv layers do not run beside recurrent ones")
+        sizes = _layer_sizes(self.weights, self.recurrent, self.conv)
         self.n_in = sizes[0]
+        self.layer_sizes = sizes
         self.mapping = mapping or map_network(
             sizes, strategy=mapping_strategy,
-            recurrent=[li + 1 for li in self.recurrent])
+            recurrent=[li + 1 for li in self.recurrent],
+            conv={li + 1: g for li, g in self.conv.items()})
         self.quant_cfg = quant_cfg or CodebookConfig(n_levels=16, bit_width=8)
         self.geom = geometry or CoreGeometry(freq_hz=freq_hz)
         self.freq_hz = freq_hz
@@ -568,6 +629,9 @@ class ChipSimulator:
         # static faults fold into the weights/tables HERE — before the
         # touch masks, so every engine inherits them with no lowering
         # changes; a null config returns without touching anything
+        if not self.faults.is_null():
+            refuse_layers(self, ["conv"],
+                          "fault injection edits dense weight columns")
         FM.apply_chip_faults(self)
         self.drop_plan = FM.build_drop_plan(self)
         self._dispatch_count = 0
@@ -588,20 +652,32 @@ class ChipSimulator:
         from repro.core.plasticity import NULL_PLASTICITY
         self.plasticity = (plasticity if plasticity is not None
                            else NULL_PLASTICITY)
-        if self.recurrent and (self.plasticity.enabled
-                               or self.drop_plan is not None):
-            raise NotImplementedError(
-                "recurrent layers run without plasticity and without NoC "
-                "packet drop: those scan bodies are chain-only until they "
-                "merge into one layer-step body")
+        if self.plasticity.enabled or self.drop_plan is not None:
+            refuse_layers(
+                self, ["recurrent", "conv"],
+                "recurrent and conv layers run without plasticity and "
+                "without NoC packet drop: those scan bodies are chain-only "
+                "until they merge into one layer-step body")
         self.write_model = E.WeightWriteModel()
         self._plast_tables = None  # lazy lower_plasticity_tables result
         self._ref_learned = None   # reference-engine learned indexes
         self._ref_elig = None      # reference-engine eligibility traces
         self._last_trace = None  # reference-engine ChipTrace
+        self._conv_slices = {}   # conv layer -> conv.SliceTables, lazily
         self._compiled = None    # CompiledEngine, built lazily
         self._fused = None       # FusedEngine, built lazily
         self._sharded = None     # ShardedEngine, built lazily
+
+    def conv_slices(self, li: int):
+        """Conv weight layer `li`'s per-core tables, in
+        `mapping.cores_of_layer` order (`conv.SliceTables`)."""
+        if li not in self._conv_slices:
+            from repro.core.conv import slice_tables
+            self._conv_slices[li] = slice_tables(
+                self.conv[li], self.nonzero_weights[li],
+                [(a.neuron_lo, a.neuron_hi)
+                 for a in self.mapping.cores_of_layer(li + 1)])
+        return self._conv_slices[li]
 
     def compiled_engine(self):
         """The lazily-built batched XLA engine for this mapping."""
@@ -700,17 +776,27 @@ class ChipSimulator:
                                              dict[int, np.ndarray]]:
         """Static routes of every layer that fires into another, keyed by
         the firing layer: the spikes layer `li` fires travel from each of
-        its cores to every core holding layer `li+1` and, for a recurrent
-        layer, to every core holding `li` itself (a core's own delivery
-        crosses no link), one multicast tree per source core.  With them,
+        its cores to every core holding layer `li+1` (to those whose input
+        band meets the core's slice, where `li+1` is a conv layer) and,
+        for a recurrent layer, to every core holding `li` itself (a
+        core's own delivery crosses no link), one multicast tree per
+        source core.  With them,
         per recurrent layer, each tree's back-edge share of hops: those
         it has beyond the tree to layer `li+1` alone."""
         def flows(li: int, dst_layers: list[int]) -> list[NOC.FlowRoute]:
-            srcs = [a.core_id for a in self.mapping.cores_of_layer(li)]
+            srcs = self.mapping.cores_of_layer(li)
+            geom = self.conv.get(li)           # weight layer li: layer li+1
+            if geom is not None:
+                # a conv layer's cores take only their input bands
+                return [NOC.compile_flow(self.routing, s.core_id, sorted(
+                    a.core_id for a in self.mapping.cores_of_layer(li + 1)
+                    if geom.reaches(s.neuron_lo, s.neuron_hi, a.neuron_lo,
+                                    a.neuron_hi)), self._level2)
+                    for s in srcs]
             dsts = sorted({a.core_id for d in dst_layers
                            for a in self.mapping.cores_of_layer(d)})
-            return [NOC.compile_flow(self.routing, s, dsts, self._level2)
-                    for s in srcs]
+            return [NOC.compile_flow(self.routing, s.core_id, dsts,
+                                     self._level2) for s in srcs]
 
         L = len(self.weights)
         routes: dict[int, list[NOC.FlowRoute]] = {}
@@ -804,7 +890,8 @@ class ChipSimulator:
     def run_reference(self, spike_train: jax.Array,
                       learned=None) -> tuple[jax.Array, ChipReport]:
         """The interpretive per-timestep loop (differential-test oracle)."""
-        from repro.core.neuron import init_state, lif_step, touch_mask
+        from repro.core.neuron import (decay_table, init_state, lif_step,
+                                       touch_mask)
 
         plast = self.plasticity
         if learned is not None and not plast.enabled:
@@ -832,11 +919,14 @@ class ChipSimulator:
                             if plast.mode == "reward" else None)
 
         T = int(spike_train.shape[0])
-        states = [init_state(int(w.shape[1])) for w in self.weights]
+        # a network with conv layers takes every leak exact (`decay_table`)
+        decays = (decay_table(self.lif.leak, T)
+                  if self.conv and self.lif.partial_update else None)
+        states = [init_state(n) for n in self.layer_sizes[1:]]
         # last step's spikes of each recurrent layer (s_out[-1] = 0)
         fed_back = {li: jnp.zeros((int(self.weights[li].shape[1]),),
                                   jnp.float32) for li in self.recurrent}
-        out_counts = jnp.zeros((int(self.weights[-1].shape[1]),), jnp.float32)
+        out_counts = jnp.zeros((self.layer_sizes[-1],), jnp.float32)
         acc = StepStats()
         wall = 0.0
         traced = self.trace.enabled
@@ -848,6 +938,10 @@ class ChipSimulator:
         rec_nnz: list[list[float]] = []
         rec_skip: list[list[float]] = []
         rec_writes: list[list[float]] = []
+        # per core slice, of a network with conv layers: input spikes and
+        # performed SOPs (a dense slice's are its layer's nnz, nnz x n)
+        rec_core_nnz: list[list[float]] = []
+        rec_core_sops: list[list[float]] = []
 
         for t in range(T):
             spikes = spike_train[t].astype(jnp.float32)
@@ -859,7 +953,19 @@ class ChipSimulator:
                 rec_nnz.append([])
                 rec_skip.append([])
                 rec_writes.append([])
+                rec_core_nnz.append([])
+                rec_core_sops.append([])
             for li in range(len(self.weights)):
+                if li in self.conv:
+                    spikes = self._reference_conv_step(
+                        li, spikes, states, acc, per_core_cycles,
+                        step_load, decays, traced and dict(
+                            nnz=rec_nnz[-1], touched=rec_touched[-1],
+                            fired=rec_fired[-1], writes=rec_writes[-1],
+                            skip=rec_skip[-1] if trace_skips else None,
+                            core_nnz=rec_core_nnz[-1],
+                            core_sops=rec_core_sops[-1]))
+                    continue
                 learns = plast.enabled and idx[li] is not None
                 if learns:
                     # live weights from the carried indexes — the SAME
@@ -891,7 +997,7 @@ class ChipSimulator:
                 current = jnp.matmul(x, w, precision=Z.CURRENT_PRECISION)
                 st, out, touched = lif_step(
                     states[li], current, self.lif,
-                    touched=touch_mask(x, nzw))
+                    touched=touch_mask(x, nzw), decays=decays)
                 states[li] = st
                 acc.nominal_sops += n_pre * n_post
                 acc.performed_sops += nnz * n_post
@@ -933,6 +1039,8 @@ class ChipSimulator:
                         rec_touched[-1].append(core_touched)
                         rec_fired[-1].append(
                             float(out_np[a.neuron_lo:a.neuron_hi].sum()))
+                        rec_core_nnz[-1].append(nnz)
+                        rec_core_sops[-1].append(nnz * a.n_neurons)
                 # NoC: the spikes each source core fired travel its own
                 # precompiled flow (replay, no BFS here) — source-exact,
                 # so where a spike fires from changes what it costs
@@ -982,8 +1090,77 @@ class ChipSimulator:
                 (np.asarray(rec_skip, np.float64)[None]
                  if trace_skips else None),
                 weight_writes=(np.asarray(rec_writes, np.float64)[None]
-                               if plast.enabled else None))
+                               if plast.enabled else None),
+                core_nnz=(np.asarray(rec_core_nnz, np.float64)[None]
+                          if self.conv else None),
+                core_sops=(np.asarray(rec_core_sops, np.float64)[None]
+                           if self.conv else None))
         return out_counts, self._report(T, acc, wall)
+
+    def _reference_conv_step(self, li, spikes, states, acc, per_core_cycles,
+                             step_load, decays, rec):
+        """Conv weight layer `li` of one reference timestep: its LIF
+        step, counters, per-core cycles (into `per_core_cycles`) and NoC
+        replay (into `step_load`); -> its output spikes.  `decays` is
+        `lif_step`'s; `rec` is False, or the trace's lists of this step
+        by counter (`skip` None without skip words)."""
+        from repro.core.conv import conv_spikes
+        from repro.core.neuron import lif_step
+
+        geom, tabs = self.conv[li], self.conv_slices(li)
+        x = spikes
+        x_np = np.asarray(x) != 0
+        words_np = np.asarray(Z.pack_spike_words(x))
+        nnz = float(x_np.sum())
+        current = conv_spikes(x, self.weights[li], geom)
+        tcnt = np.asarray(conv_spikes(x, self.nonzero_weights[li], geom))
+        st, out, touched = lif_step(states[li], current, self.lif,
+                                    touched=jnp.asarray(tcnt > 0),
+                                    decays=decays)
+        states[li] = st
+        out_np = np.asarray(out)
+        touched_np = np.asarray(touched)
+        acc.spikes_in += nnz
+        acc.nominal_sops += tabs.total_synapses
+        skipped = 0.0
+        asn = self.mapping.cores_of_layer(li + 1)
+        for i, a in enumerate(asn):
+            (b0, b1), (w0, w1) = tabs.band[i], tabs.words[i]
+            core_nnz = float(x_np[b0:b1].sum())
+            core_skip = float((words_np[w0:w1] == 0).sum())
+            core_sops = float(tcnt[a.neuron_lo:a.neuron_hi].sum())
+            core_touched = float(touched_np[a.neuron_lo:a.neuron_hi].sum())
+            skipped += core_skip
+            acc.performed_sops += core_sops
+            acc.neurons_touched += core_touched
+            per_core_cycles[a.core_id] = per_core_cycles.get(
+                a.core_id, 0.0) + self.cycle_model.conv_timestep_cycles(
+                    w1 - w0, core_sops, core_touched,
+                    float(tabs.synapses[i]), a.n_neurons,
+                    self.zero_skip, self.partial_update)
+            if rec:
+                rec["touched"].append(core_touched)
+                rec["fired"].append(
+                    float(out_np[a.neuron_lo:a.neuron_hi].sum()))
+                rec["core_nnz"].append(core_nnz)
+                rec["core_sops"].append(core_sops)
+        if rec:
+            rec["nnz"].append(nnz)
+            if rec["skip"] is not None:
+                rec["skip"].append(skipped)
+            rec["writes"].append(0.0)
+        fired_per_src = [int(out_np[a.neuron_lo:a.neuron_hi].sum())
+                         for a in asn]
+        if sum(fired_per_src) > 0 and li + 1 in self._layer_routes:
+            rep = NOC.replay_flows(
+                list(zip(self._layer_routes[li + 1], fired_per_src)),
+                self.router, n_nodes=self.adj.shape[0],
+                interconnect=self.interconnect)
+            acc.noc_hops += rep.total_hops
+            acc.noc_energy_pj += rep.energy_pj
+            acc.spikes_routed += float(sum(fired_per_src))
+            step_load += rep.router_load
+        return out
 
     def _report(self, steps: int, acc: StepStats, wall: float) -> ChipReport:
         # one pricing implementation for both engines (energy.price_batched;

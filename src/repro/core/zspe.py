@@ -196,6 +196,31 @@ class CycleModel:
             crit = jnp.maximum(crit, jnp.ceil(writes / self.geom.write_lanes))
         return crit + self.geom.pipeline_depth
 
+    def conv_timestep_cycles(self, band_words: int, sops: float,
+                             touched: float, synapses: float, n_post: int,
+                             zero_skip: bool = True,
+                             partial_update: bool = True) -> float:
+        """One core-step of a conv layer (core/conv.py): the ZSPE scans
+        the `band_words` words of its input band, the SPEs perform its
+        `sops` (every one of its `synapses` without zero-skip), the
+        updater its touched neurons."""
+        g = self.geom
+        syn = math.ceil((sops if zero_skip else synapses) / g.spe_lanes)
+        upd = math.ceil(touched) if partial_update else n_post
+        return max(band_words, syn, upd) + g.pipeline_depth
+
+    def conv_timestep_cycles_array(self, band_words, sops, touched,
+                                   synapses, n_post, zero_skip: bool = True,
+                                   partial_update: bool = True):
+        """Array-native `conv_timestep_cycles`: one entry per core slice
+        (`band_words`, `synapses`, `n_post` (A,) arrays) over any leading
+        axes of the integer-exact `sops` and `touched`."""
+        g = self.geom
+        syn = jnp.ceil((sops if zero_skip else synapses) / g.spe_lanes)
+        upd = jnp.ceil(touched) if partial_update else n_post
+        crit = jnp.maximum(jnp.maximum(band_words, syn), upd)
+        return crit + g.pipeline_depth
+
     def sop_count(self, n_pre: int, n_post: int, nnz: float,
                   zero_skip: bool = True) -> float:
         """SOPs actually *performed*.  With zero-skip only valid-spike

@@ -164,17 +164,25 @@ def _dequant_columns(idx: jax.Array, cbw: jax.Array,
     return w
 
 
-def _lif_tile(v, el, cur, tcnt, *, threshold, leak, reset, partial_update):
+def _lif_tile(v, el, cur, tcnt, *, threshold, leak, reset, partial_update,
+              decays=None):
     """The neuron-updater stage on one (bm, bn) tile.
 
     Expression-for-expression the same float program as
     `core.neuron.lif_step` (hard reset), so a jitted caller sees
-    bit-identical v / elapsed / spikes.
+    bit-identical v / elapsed / spikes.  `decays` (`neuron.decay_table`)
+    gives `leak**pending` exactly, by selects over its entries.
     """
     if partial_update:
         touched = tcnt > 0
         pending = el + 1
-        decay = jnp.where(touched, leak ** pending.astype(v.dtype), 1.0)
+        if decays is None:
+            leak_n = leak ** pending.astype(v.dtype)
+        else:
+            leak_n = jnp.full(v.shape, decays[-1], v.dtype)
+            for n, d in enumerate(decays[:-1]):
+                leak_n = jnp.where(pending == n, d, leak_n)
+        decay = jnp.where(touched, leak_n, 1.0)
         v_int = v * decay + cur
         v_eff = jnp.where(touched, v_int, -jnp.inf)
         spikes = ((v_eff - threshold) >= 0.0).astype(v.dtype)
@@ -199,7 +207,8 @@ def _widen(words: jax.Array) -> jax.Array:
 def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
             vo_ref, elo_ref, sp_ref, tc_ref, nnz_ref, ew_ref, *,
             layout: str, gather: bool, threshold: float, leak: float,
-            reset: float, partial_update: bool, all_nonzero: bool):
+            reset: float, partial_update: bool, all_nonzero: bool,
+            decays=None):
     j = pl.program_id(1)
     pk = pk_ref[...]                                   # (bm, kw) uint16
     s, nnz_rows = _unpack_words(pk)                    # (bm, K), (bm, 1)
@@ -222,7 +231,7 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
         vo, elo, sp, _ = _lif_tile(
             v, el, jnp.zeros_like(v), jnp.zeros_like(el),
             threshold=threshold, leak=leak, reset=reset,
-            partial_update=partial_update)
+            partial_update=partial_update, decays=decays)
         vo_ref[...] = vo
         elo_ref[...] = elo
         sp_ref[...] = sp
@@ -261,7 +270,7 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
                            preferred_element_type=jnp.float32)
         vo, elo, sp, tc = _lif_tile(
             v, el, cur, tcnt, threshold=threshold, leak=leak, reset=reset,
-            partial_update=partial_update)
+            partial_update=partial_update, decays=decays)
         vo_ref[...] = vo
         elo_ref[...] = elo
         sp_ref[...] = sp
@@ -269,7 +278,8 @@ def _kernel(pk_ref, w0_ref, w1_ref, v_ref, el_ref,
 
 
 def _call(pk, w0, w1, v, elapsed, *, layout, gather, threshold, leak,
-          reset, partial_update, all_nonzero, block, interpret, name):
+          reset, partial_update, all_nonzero, block, interpret, name,
+          decays=None):
     m, kw = pk.shape
     k = kw * SPIKE_WORD_BITS
     n = v.shape[-1]
@@ -280,7 +290,7 @@ def _call(pk, w0, w1, v, elapsed, *, layout, gather, threshold, leak,
     kern = functools.partial(
         _kernel, layout=layout, gather=gather, threshold=threshold,
         leak=leak, reset=reset, partial_update=partial_update,
-        all_nonzero=all_nonzero)
+        all_nonzero=all_nonzero, decays=decays)
     state_spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
     row_spec = pl.BlockSpec((bm, 1), lambda i, j: (i, 0))
     in_specs = [
@@ -328,7 +338,7 @@ def _drop_w1(kern):
 
 @functools.partial(jax.jit, static_argnames=(
     "threshold", "leak", "reset", "partial_update", "gather",
-    "all_nonzero", "block", "interpret", "name"))
+    "all_nonzero", "block", "interpret", "name", "decays"))
 def fused_timestep_codebook(
     packed: jax.Array,        # (M, Kw) uint16 spike words
     idx: jax.Array,           # (Kw*16, N) int8 codebook indexes
@@ -345,6 +355,7 @@ def fused_timestep_codebook(
     block: tuple[int, int] | None = None,
     interpret: bool = True,
     name: str | None = None,
+    decays: tuple | None = None,
 ):
     """One fused layer-timestep, codebook-compressed weights.
 
@@ -355,17 +366,18 @@ def fused_timestep_codebook(
     Returns (v', elapsed', spikes, touched, nnz_rows, empty_words).
     `block=None` runs a single (M, N) tile — the engine's bit-exact
     configuration; pass (bm, bn) divisors to tile for TPU VMEM.  `name`
-    names the kernel in the compiled program and its profile.
+    names the kernel in the compiled program and its profile; `decays`
+    (`neuron.decay_table`) makes the leak exact.
     """
     return _call(packed, idx, cbw, v, elapsed, layout="codebook",
                  gather=gather, threshold=threshold, leak=leak, reset=reset,
                  partial_update=partial_update, all_nonzero=all_nonzero,
-                 block=block, interpret=interpret, name=name)
+                 block=block, interpret=interpret, name=name, decays=decays)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "threshold", "leak", "reset", "partial_update", "all_nonzero", "block",
-    "interpret", "name"))
+    "interpret", "name", "decays"))
 def fused_timestep_words(
     packed: jax.Array,        # (M, Kw) uint16 spike words
     words: jax.Array,         # (Kw*16, N) int8 integer weight words
@@ -381,6 +393,7 @@ def fused_timestep_words(
     block: tuple[int, int] | None = None,
     interpret: bool = True,
     name: str | None = None,
+    decays: tuple | None = None,
 ):
     """One fused layer-timestep, weights as integer words x a per-column
     step: the current is `(spikes @ words) * step`.
@@ -390,17 +403,18 @@ def fused_timestep_words(
     integers in any order and any (bm, bn) tiling, and the current is
     one correctly rounded f32 product.  Where the levels `word * step`
     make an exact f32 current in the codebook layout, the two layouts
-    agree bit for bit.  Returns what `fused_timestep_codebook` returns.
+    agree bit for bit.  `decays` (`neuron.decay_table`) makes the leak
+    exact.  Returns what `fused_timestep_codebook` returns.
     """
     return _call(packed, words, step, v, elapsed, layout="words",
                  gather=False, threshold=threshold, leak=leak, reset=reset,
                  partial_update=partial_update, all_nonzero=all_nonzero,
-                 block=block, interpret=interpret, name=name)
+                 block=block, interpret=interpret, name=name, decays=decays)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "threshold", "leak", "reset", "partial_update", "all_nonzero", "block",
-    "interpret", "name"))
+    "interpret", "name", "decays"))
 def fused_timestep_dense(
     packed: jax.Array,        # (M, Kw) uint16 spike words
     weights: jax.Array,       # (Kw*16, N) f32 dense weights
@@ -415,6 +429,7 @@ def fused_timestep_dense(
     block: tuple[int, int] | None = None,
     interpret: bool = True,
     name: str | None = None,
+    decays: tuple | None = None,
 ):
     """Dense-weight variant (float simulators): same ZSPE/LIF fusion.
 
@@ -424,4 +439,4 @@ def fused_timestep_dense(
     return _call(packed, weights, None, v, elapsed, layout="dense",
                  gather=False, threshold=threshold, leak=leak, reset=reset,
                  partial_update=partial_update, all_nonzero=all_nonzero,
-                 block=block, interpret=interpret, name=name)
+                 block=block, interpret=interpret, name=name, decays=decays)

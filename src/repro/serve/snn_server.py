@@ -80,6 +80,14 @@ def _input_width(sim) -> int:
     return int(getattr(sim, "n_in", sim.weights[0].shape[0]))
 
 
+def _output_width(sim) -> int:
+    """The output population's width: `ChipSimulator.layer_sizes`' last
+    (a conv layer's weight is its filter), or the last weight's columns
+    for a stand-in."""
+    sizes = getattr(sim, "layer_sizes", None)
+    return int(sizes[-1] if sizes else sim.weights[-1].shape[1])
+
+
 class Tenant:
     """One registered model: a compiled simulator plus residency state."""
 
@@ -91,7 +99,7 @@ class Tenant:
         self.name = name
         self.sim = sim
         self.n_in = _input_width(sim)
-        self.n_out = int(sim.weights[-1].shape[1])
+        self.n_out = _output_width(sim)
         self.core_ids = frozenset(sim.mapping.active_core_ids())
         self.resident = False
         if degraded_sim is not None:
@@ -99,7 +107,7 @@ class Tenant:
                 raise ValueError(
                     "degraded_sim must be an array-engine simulator")
             din = _input_width(degraded_sim)
-            dout = int(degraded_sim.weights[-1].shape[1])
+            dout = _output_width(degraded_sim)
             if (din, dout) != (self.n_in, self.n_out):
                 raise ValueError(
                     f"degraded_sim io ({din}, {dout}) does not match the "

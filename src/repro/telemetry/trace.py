@@ -89,6 +89,11 @@ class ChipTrace:
     back_noc_hops: np.ndarray     # (B, T, S) of noc_hops, the back-edges'
                                   # share of recurrent layers' trees
     recurrent_sops: np.ndarray    # (B, T, L) SOPs of fed-back spikes
+    # a network with conv layers (core/conv.py), per slice: the input
+    # spikes of its input band and its performed SOPs (a dense slice's
+    # are its layer's nnz and nnz x its neurons); None without conv
+    nnz_core: np.ndarray | None = None   # (B, T, S)
+    sops_core: np.ndarray | None = None  # (B, T, S)
 
     @property
     def batch(self) -> int:
@@ -128,6 +133,8 @@ class ChipTrace:
         assert self.contention_cycles.shape == (B, T)
         assert self.noc_pj.shape == (B, T, S)
         assert self.noc_hops.shape == (B, T, S)
+        for extra in (self.nnz_core, self.sops_core):
+            assert extra is None or extra.shape == (B, T, S), extra.shape
         assert self.back_noc_hops.shape == (B, T, S)
         assert self.recurrent_sops.shape == (B, T, L)
         assert len(self.slice_layer) == S and len(self.slice_core) == S
@@ -142,7 +149,8 @@ class ChipTrace:
         cat = {}
         for f in dataclasses.fields(ChipTrace):
             v = getattr(head, f.name)
-            if f.name in ("skip_words", "weight_writes"):
+            if f.name in ("skip_words", "weight_writes", "nnz_core",
+                          "sops_core"):
                 cat[f.name] = (None if v is None else np.concatenate(
                     [getattr(t, f.name) for t in traces], axis=0))
             elif isinstance(v, np.ndarray) and v.ndim >= 2:
@@ -157,8 +165,10 @@ def slice_metadata(sim: "ChipSimulator"):
     """(slice_layer, slice_core, slice_neurons, n_pre_per_layer) in the
     canonical layer-major slice order shared with the engine lowering."""
     slice_layer, slice_core, slice_neurons, n_pres = [], [], [], []
-    for li, w in enumerate(sim.weights):
-        n_pres.append(int(w.shape[0]))
+    for li in range(len(sim.weights)):
+        n_pres.append(int(sim.layer_sizes[li])
+                      + (int(sim.layer_sizes[li + 1]) if li in sim.recurrent
+                         else 0))
         for a in sim.mapping.cores_of_layer(li + 1):
             slice_layer.append(li)
             slice_core.append(a.core_id)
@@ -184,7 +194,8 @@ def _slice_cycles(sim: "ChipSimulator", nnz_layer, slice_n, n_pre):
 
 
 def build_trace(sim: "ChipSimulator", fired, touched, nnz,
-                skip_words=None, weight_writes=None) -> ChipTrace:
+                skip_words=None, weight_writes=None, core_nnz=None,
+                core_sops=None) -> ChipTrace:
     """Assemble a ChipTrace from an engine's raw counters.
 
     fired/touched: (B, T, S) per-slice integer counts in layer-major
@@ -197,7 +208,10 @@ def build_trace(sim: "ChipSimulator", fired, touched, nnz,
     spikes fire (`back_noc_hops`: the hops beyond the tree to the next
     layer alone, inside `noc_hops`), and its spikes are its recurrent
     input one step later (`recurrent_sops`, inside the SOPs that `nnz`
-    prices).
+    prices).  A network with conv layers also gives each slice's input
+    spikes and performed SOPs (`core_nnz`, `core_sops`: (B, T, S)); a
+    conv slice's cycles scan its input band's words and perform its own
+    SOPs (`CycleModel.conv_timestep_cycles`).
     """
     fired = np.asarray(fired, np.float64)
     touched = np.asarray(touched, np.float64)
@@ -225,14 +239,22 @@ def build_trace(sim: "ChipSimulator", fired, touched, nnz,
     for li in range(L):
         sel = np.flatnonzero(slice_layer == li)
         slice_n = slice_neurons[sel].astype(np.float64)
-        load, syn = _slice_cycles(sim, nnz[..., li], slice_n, int(n_pres[li]))
+        if li in sim.conv:
+            tabs = sim.conv_slices(li)
+            load = (tabs.words[:, 1] - tabs.words[:, 0]).astype(np.float64)
+            syn = np.ceil((core_sops[..., sel] if sim.zero_skip
+                           else tabs.synapses.astype(np.float64))
+                          / sim.cycle_model.geom.spe_lanes)
+        else:
+            load, syn = _slice_cycles(sim, nnz[..., li], slice_n,
+                                      int(n_pres[li]))
         upd = (np.ceil(touched[..., sel]) if sim.partial_update
                else np.broadcast_to(slice_n, (B, T, len(sel))))
         cycles[..., sel] = np.maximum(np.maximum(load, syn), upd) + depth
         fired_li = fired[..., sel]                        # (B, T, F)
         if li in sim.recurrent:
             recurrent_sops[:, 1:, li] = (fired_li[:, :-1].sum(axis=-1)
-                                         * sim.weights[li].shape[1])
+                                         * sim.layer_sizes[li + 1])
         if li + 1 in sim._layer_routes:
             ft = NOC.compile_flow_table(
                 sim._layer_routes[li + 1], sim.router, n_nodes=n_nodes,
@@ -261,6 +283,10 @@ def build_trace(sim: "ChipSimulator", fired, touched, nnz,
         cycles=cycles, core_cycles=core_cycles, core_wall=core_wall,
         router_load=router_load, contention_cycles=contention,
         noc_hops=noc_hops, noc_pj=noc_pj, back_noc_hops=back_hops,
-        recurrent_sops=recurrent_sops)
+        recurrent_sops=recurrent_sops,
+        nnz_core=(None if core_nnz is None
+                  else np.asarray(core_nnz, np.float64)),
+        sops_core=(None if core_sops is None
+                   else np.asarray(core_sops, np.float64)))
     trace.validate()
     return trace

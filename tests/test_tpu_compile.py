@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.compiler.ir import parse_conv_spec
 from repro.configs.snn_chip import ARCH
 from repro.core import zspe as Z
 from repro.core.engine import CompiledEngine, _pick_engine_block
@@ -145,4 +146,37 @@ def test_recurrent_word_kernel_compiles_for_v5e(one_chip, batch,
         sds((1, n), jnp.float32), sds((batch, n), jnp.float32),
         sds((batch, n), jnp.int32), all_nonzero=all_nonzero, block=block,
         interpret=False, name="snn_fused_l1")
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+SLAYER_CONVS = [g for g in parse_conv_spec(
+    "34x34x2-12c5-2a-64c5-2a-10o")[1] if not isinstance(g, int)]
+
+
+@pytest.mark.parametrize("batch", [32, 12])
+@pytest.mark.parametrize("geom", SLAYER_CONVS,
+                         ids=["conv1", "pool1", "conv2", "pool2"])
+def test_conv_kernel_compiles_for_v5e(one_chip, geom, batch):
+    """SLAYER's N-MNIST CNN (34x34x2-12c5-2a-64c5-2a-10o): each conv and
+    pool layer-step as the fused engine lowers it, channels padded to 8
+    sublanes, taps to 128 lanes, pixels to the lane tile it picks, the
+    leak exact over T=20 (`decay_table`)."""
+    from repro.core.neuron import decay_table
+    from repro.kernels.conv_timestep import conv_timestep, pick_lane_tile
+
+    c = -(-geom.c_out // 8) * 8
+    k = -(-geom.kernel ** 2 * geom.c_in // 128) * 128
+    m = batch * geom.out_height * geom.out_width
+    bm = pick_lane_tile(c, k, m, interpret=False)
+    m_pad = -(-m // bm) * bm
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = conv_timestep.lower(
+        sds((k, m_pad), jnp.bfloat16), sds((c, k), jnp.int8),
+        sds((c, k), jnp.int8), sds((1, 1), jnp.float32),
+        sds((c, m_pad), jnp.float32), sds((c, m_pad), jnp.int32),
+        block=bm, interpret=False, name="snn_conv_l1",
+        decays=decay_table(0.9, 20))
     assert "tpu_custom_call" in lowered.compile().as_text()
